@@ -134,12 +134,11 @@ class SourceFile:
 def default_targets(root: pathlib.Path) -> list[pathlib.Path]:
     """The analyzer's scope — the same file set the r9 lint grew to
     cover: ALL of onix/ plus the harness code outside the package
-    (bench.py, scripts/*.py). tests/ are deliberately out: they pin
-    envs and poke private tables as part of their job."""
+    (bench.py, chip_smoke.py, scripts/*.py). tests/ are deliberately
+    out: they pin envs and poke private tables as part of their job."""
     files = sorted((root / "onix").rglob("*.py"))
-    bench = root / "bench.py"
-    if bench.exists():
-        files.append(bench)
+    files += [f for f in (root / "bench.py", root / "chip_smoke.py")
+              if f.exists()]
     files += sorted((root / "scripts").glob("*.py"))
     return files
 

@@ -183,13 +183,12 @@ def main(argv: list[str] | None = None) -> int:
     from onix.utils import telemetry
     telemetry.apply_config(cfg.telemetry)
 
-    if args.command in ("score", "stream", "demo"):
+    if args.command in ("score", "stream", "demo", "serve"):
         # Device-touching commands: persist compiled programs so daily
-        # runs never re-pay cold-compile (obs.enable_compile_cache).
+        # runs and server restarts never re-pay cold-compile
+        # (obs.enable_compile_cache).
         from onix.utils.obs import enable_compile_cache
-        import pathlib
-        enable_compile_cache(
-            pathlib.Path(cfg.store.checkpoint_dir) / "jax_cache")
+        enable_compile_cache()
 
     if args.command == "config":
         print(cfg.to_json())

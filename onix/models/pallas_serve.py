@@ -18,9 +18,13 @@ One grid step per token tile. Per tile (all VMEM-resident):
   1. scoring — mode "dot": the gathered theta[d]/phi[w] rows come in as
      [tile, K] blocks (gathered OUTSIDE the kernel, like r8's count
      rows: Mosaic has no gather lowering) and the kernel takes the
-     row-wise product-sum — the exact float ops of
-     `scoring.score_events`, so scores are bit-identical to the XLA
-     arm. Mode "min2": two pre-gathered score columns, pair-min inside
+     row-wise product-sum — the float ops of `scoring.score_events`,
+     but NOT its accumulation order: the K-term sum's association is
+     the compiler's, so this mode's scores sit within a couple of ulp
+     of the XLA arm's rather than on them (2 ulp on the chip in PR 21,
+     1 ulp in the CPU interpreter under jax 0.9.0 — PERF.md). Mode
+     "min2": two
+     pre-gathered score columns, pair-min inside
      (the `table_pair_bottom_k` / streaming flow-tail shape). Mode
      "scores": precomputed scores (the bank gather tail, plain
      bottom_k).
@@ -55,7 +59,9 @@ One grid step per token tile. Per tile (all VMEM-resident):
      to `_scan_bottom_k` (+inf slots get the -1 index sentinel in the
      same finalize step).
 
-Exactness: scoring is the same f32 ops on the same values; membership
+Exactness: modes "min2" and "scores" move scores without arithmetic,
+so they are bit-identical to the XLA scans (asserted compiled, on the
+chip); mode "dot" is within a couple of ulp (item 1). Membership
 is equality against the same tables; rank sums and the scatter are
 int32/select ops (no float accumulation of indices), and the score
 scatter moves values by select, never arithmetic. The only float
@@ -63,15 +69,14 @@ arithmetic beyond scoring is the boost multiply — the same single f32
 op `apply_filter` issues. Interpret mode (the default off-TPU, shared
 `ONIX_PALLAS_INTERPRET` override) lowers to plain XLA ops, so tier-1
 asserts bit-identity on CPU (tests/test_pallas_serve.py) and the same
-code compiles through Mosaic on a real TPU (`tpu`-marked test; queued
-rows `fused_serve_tpu` / `bench_fused_serve_tpu` in
-docs/TPU_QUEUE.json).
+code compiles through Mosaic on a real TPU (the `tpu`-marked tests;
+what the chip said is in PERF.md).
 
 The gate (`select_serve_form`, `serving.serve_form`, ONIX_SERVE_FORM)
 resolves through `config.resolve_form_gate` next to
 `model_bank.select_bank_form`; `_SERVE_FUSED_MIN_EVENTS` is
-DELIBERATELY EMPTY — tpu included — until the queued crossover lands,
-so `auto` resolves to "xla" on every backend today and nothing changes
+DELIBERATELY EMPTY — tpu included: the crossover is not measured on
+the chip, so `auto` resolves to "xla" on every backend today and nothing changes
 behavior without a measurement. VMEM budget math is in docs/PERF.md
 ("fused serving kernel").
 """
@@ -106,10 +111,10 @@ _SCATTER_BLOCK = 256
 # Measured per-backend crossover: events per request above which the
 # fused one-kernel path beats the three-stage XLA path. Same
 # measured-platforms-only policy as `_NWK_PALLAS_MIN_DENSITY` and
-# `_BANK_GATHER_MIN_EVENTS`: DELIBERATELY EMPTY — including "tpu" —
-# until the queued rows land (docs/TPU_QUEUE.json `fused_serve_tpu`,
-# `bench_fused_serve_tpu`), so serve_form="auto" resolves to "xla"
-# everywhere today. CPU gets no entry either way: the interpret-mode
+# `_BANK_GATHER_MIN_EVENTS`: DELIBERATELY EMPTY — including "tpu":
+# the fused-vs-xla crossover is not measured on the chip, so
+# serve_form="auto" resolves to "xla" everywhere today. CPU gets no
+# entry either way: the interpret-mode
 # emulation is a correctness vehicle, never a fast path
 # (docs/FUSED_r15_cpu.json records the measured emulation rate).
 _SERVE_FUSED_MIN_EVENTS: dict[str, float] = {}

@@ -46,8 +46,7 @@ domain"):
 
 jax is imported lazily: a spawned worker must let the coordinator's
 env (JAX_PLATFORMS, XLA_FLAGS device count) reach process start before
-any backend is created, and `mesh.multihost_init` selects gloo CPU
-collectives before initialize.
+any backend is created.
 """
 
 from __future__ import annotations
@@ -89,6 +88,28 @@ class HostPeerDead(FabricError):
     """Raised inside a WORKER when a collective failed past its
     bounded deadline + retry — the peer is presumed dead; the
     coordinator's lease detection owns recovery."""
+
+
+def require_cpu_coordinator() -> None:
+    """Refuse to coordinate from a process that holds an accelerator.
+
+    A chip belongs to one process: once this process has initialised
+    JAX on a TPU, workers that need the chips
+    (ONIX_FABRIC_WORKER_PLATFORM=tpu) fail or hang, and the default CPU
+    workers would fit off-device while the caller's manifest lists TPU
+    devices. Either way the fit would not be what the caller thinks, so
+    raise before anything is spawned."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise FabricError(
+            f"the fit fabric's coordinator is running in a process "
+            f"whose JAX backend is {backend!r}: that process holds the "
+            "accelerator, so fit workers cannot have it, and CPU "
+            "workers would fit off-device unseen. Launch the "
+            "coordinator under JAX_PLATFORMS=cpu (set "
+            "ONIX_FABRIC_WORKER_PLATFORM=tpu to fit on this host's "
+            "chips), or fit in-process (fit_hosts=1)")
 
 
 # ---------------------------------------------------------------------------
@@ -521,12 +542,19 @@ def _write_result(workdir: pathlib.Path, host_id: int, spec: dict,
     doc map, and the ll series (identical on every host)."""
     res = _result_path(workdir, host_id)
     res.parent.mkdir(parents=True, exist_ok=True)
+    import jax
     n_dk, row0 = _local_block(state.n_dk)
     acc_ndk, _ = _local_block(state.acc_ndk)
+    device = jax.local_devices()[0]
     payload = {"n_dk": n_dk, "acc_ndk": acc_ndk,
                "row0": np.int64(row0), "n_acc": np.asarray(state.n_acc),
                "n_hosts": np.int64(spec["n_hosts"]),
                "host": np.int64(host_id),
+               # What this worker actually fitted on — the manifest
+               # records it, so a fit is never attributed to devices
+               # it did not run on.
+               "platform": np.str_(device.platform),
+               "device_kind": np.str_(device.device_kind),
                # This worker's host.* counter snapshot (merge retries,
                # shard saves, ...) — counters live per process, so the
                # coordinator can only surface them in the manifest if
@@ -670,6 +698,7 @@ class FabricCoordinator:
         from onix import checkpoint as ckpt
         from onix.utils import telemetry
 
+        require_cpu_coordinator()
         t0 = time.monotonic()
         self.workdir.mkdir(parents=True, exist_ok=True)
         _save_corpus(self.workdir, self.corpus)
@@ -747,6 +776,9 @@ class FabricCoordinator:
             "restarts": self.restarts,
             "rebalanced": self.rebalanced,
             "resume_sweeps": list(self._resume_sweeps),
+            # The devices the workers report having fitted on.
+            "workers": [{"platform": p, "device_kind": k}
+                        for p, k in self._worker_devices],
             # Coordinator-side host.* counters (death detection,
             # quarantine, restarts) merged with the final generation's
             # worker-side ones (merge retries, shard saves) carried out
@@ -799,9 +831,9 @@ class FabricCoordinator:
             if worker_platform == "tpu":
                 # Operator-gated TPU split: each worker owns
                 # local_devices chips of THIS host via the documented
-                # single-host multi-process envs. The coordinator must
-                # not hold the TPU itself (run it under
-                # JAX_PLATFORMS=cpu) — libtpu chips are exclusive.
+                # single-host multi-process envs. The coordinator does
+                # not hold the TPU itself (require_cpu_coordinator) —
+                # libtpu chips are exclusive.
                 env["JAX_PLATFORMS"] = "tpu"
                 env.update(_tpu_split_env(i, self.n_hosts,
                                           self.local_devices, tpu_port0))
@@ -953,15 +985,19 @@ class FabricCoordinator:
 
     def _assemble(self):
         parts = []
+        devices: set[tuple[str, str]] = set()
         self._worker_counters: dict[str, int] = {}
         for i in range(self.n_hosts):
             with np.load(_result_path(self.workdir, i)) as z:
                 parts.append({k: z[k] for k in z.files})
+            devices.add((str(parts[-1].pop("platform")),
+                         str(parts[-1].pop("device_kind"))))
             raw = parts[-1].pop("host_counters", None)
             if raw is not None:
                 for k, v in json.loads(str(raw)).items():
                     self._worker_counters[k] = \
                         self._worker_counters.get(k, 0) + int(v)
+        self._worker_devices = sorted(devices)
         for i, part in enumerate(parts):
             if int(part["n_hosts"]) != self.n_hosts:
                 raise FabricError(

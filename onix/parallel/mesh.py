@@ -92,43 +92,6 @@ def data_axes_of(mesh: Mesh) -> tuple[str, ...]:
     return tuple(a for a in DATA_AXES if a in mesh.shape)
 
 
-def enable_cpu_collectives() -> bool:
-    """Route cross-process CPU collectives over gloo. Returns True when
-    the gloo implementation was selected.
-
-    jax 0.4.x's CPU backend refuses multi-process computations outright
-    ("Multiprocess computations aren't implemented on the CPU backend")
-    unless `jax_cpu_collectives_implementation` is set BEFORE the CPU
-    client is created — env vars alone don't reach the flag in time, so
-    every process of a CPU fabric (hostfabric workers, the 2-process
-    suite) must call this before its first jax computation. Gated on
-    JAX_PLATFORMS naming cpu: a TPU pod's collectives ride ICI/DCN and
-    must not be redirected. Older jaxlibs without gloo degrade to False
-    (the caller's distributed init then fails loudly, never silently
-    single-process)."""
-    import os
-    if "cpu" not in os.environ.get("JAX_PLATFORMS", ""):
-        return False
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        return False
-    return True
-
-
-def _distributed_is_initialized() -> bool:
-    """Backend-safe "is jax.distributed up" probe. jax >= 0.5 exposes
-    `jax.distributed.is_initialized`; 0.4.x (this container's 0.4.37)
-    does not, so fall back to the distributed global state's client —
-    the same object is_initialized reads — which exists on every 0.4.x
-    and never instantiates the XLA backend."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    from jax._src import distributed as _dist
-    return getattr(_dist.global_state, "client", None) is not None
-
-
 def multihost_init(coordinator: str | None = None,
                    num_processes: int | None = None,
                    process_id: int | None = None,
@@ -152,17 +115,16 @@ def multihost_init(coordinator: str | None = None,
     multi-host environment detected, where running solo is the
     requested behavior.
     """
-    # Probe via distributed.is_initialized, NOT process_count():
+    # Probe via jax.distributed.is_initialized, NOT process_count():
     # process_count() instantiates the XLA backend, after which
     # jax.distributed.initialize refuses to run at all.
-    if _distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     explicit = coordinator is not None
     if explicit:
         # Explicit init is how CPU fabrics launch (hostfabric workers,
-        # the 2-process suite) — those need gloo collectives selected
-        # before the backend exists; on TPU the gate inside is a no-op.
-        enable_cpu_collectives()
+        # the 2-process suite); cross-process CPU collectives ride gloo,
+        # jax's default implementation.
         kw = ({"initialization_timeout": init_timeout_s}
               if init_timeout_s else {})
         jax.distributed.initialize(coordinator_address=coordinator,

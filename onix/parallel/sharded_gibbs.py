@@ -51,28 +51,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                        # newer jax exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:      # older (≤0.4.37): the experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed across jax versions
-# (check_rep in the experimental shard_map, check_vma at the top level).
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep")
-
-if hasattr(jax.lax, "pcast"):
-    _pcast = jax.lax.pcast
-else:
-    # Older jax has no varying-type system: every value inside
-    # shard_map is implicitly device-varying, so the cast is identity.
-    def _pcast(x, axis_name, *, to="varying"):
-        return x
-
 from onix.config import LDAConfig
 from onix.corpus import Corpus
 from onix.models import lda_gibbs
@@ -338,14 +316,14 @@ class ShardedGibbsLDA:
             # the static replication linter has nothing true to check —
             # drop it, exactly as the pallas arm must.
             if use_async:
-                return {_SHARD_MAP_CHECK_KW: False}
+                return {"check_vma": False}
             form = (nwk_form if nwk_form is not None
                     else lda_gibbs.env_nwk_form())
             maybe_pallas = (
                 form == "pallas"
                 or (form is None and lda_gibbs.nwk_pallas_auto_reachable(
                     jax.default_backend())))
-            return {_SHARD_MAP_CHECK_KW: False} if maybe_pallas else {}
+            return {"check_vma": False} if maybe_pallas else {}
 
         def _group_sweep(z_g, n_dk_l, n_wk_l, n_k_l, key_c,
                          d_g, w_g, m_g):
@@ -362,10 +340,10 @@ class ShardedGibbsLDA:
                 # device starts updating them locally — mark them
                 # per group; the psum fold below restores the
                 # replication the carry (and out_specs) demand.
-                nwk_v = _pcast(nwk_r, D, to="varying")
-                ndk_v = (_pcast(ndk_r, M, to="varying")
+                nwk_v = jax.lax.pcast(nwk_r, D, to="varying")
+                ndk_v = (jax.lax.pcast(ndk_r, M, to="varying")
                          if M else ndk_r)
-                nk_v = _pcast(nk_r, both, to="varying")
+                nk_v = jax.lax.pcast(nk_r, both, to="varying")
 
                 def one_chain(zc, ndkc, nwkc, nkc, keyc):
                     return _local_sweep(
@@ -519,7 +497,7 @@ class ShardedGibbsLDA:
                 return (z_full[None, None], ndk_f[None], nwk_f[None],
                         nk_f, key_f[None, None])
 
-            z, n_dk, n_wk, n_k, keys = _shard_map(
+            z, n_dk, n_wk, n_k, keys = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(D, *mp_spec), P(D), P(*mp_spec), P(),
                           P(D, *mp_spec), P(D, *mp_spec), P(D, *mp_spec),
@@ -553,10 +531,10 @@ class ShardedGibbsLDA:
             def shard_fn(z, n_dk, n_wk, n_k, keys, accd, accw, nacc,
                          d, w, m, start_s):
                 d_g, w_g, m_g, z_g, C, nb, B = _grouped(d, w, m, z)
-                zero = _pcast(jnp.float32(0), both, to="varying")
+                zero = jax.lax.pcast(jnp.float32(0), both, to="varying")
                 d0, w0, m0 = d[0, 0], w[0, 0], m[0, 0]
                 if with_initial_ll:
-                    nk0_v = _pcast(n_k, both, to="varying")
+                    nk0_v = jax.lax.pcast(n_k, both, to="varying")
                     sm0, t0 = _chain_ll_local(n_dk[0], n_wk[0], nk0_v,
                                               d0, w0, m0, zero)
                     sm0 = jax.lax.psum(sm0, both)
@@ -579,7 +557,7 @@ class ShardedGibbsLDA:
                 (z_g2, ndk_f, nwk_f, nk_f, key_f, ad, aw, na), _ = \
                     jax.lax.scan(one_sweep, carry0,
                                  jnp.arange(n_steps, dtype=jnp.int32))
-                nk_v = _pcast(nk_f, both, to="varying")
+                nk_v = jax.lax.pcast(nk_f, both, to="varying")
                 sm, t = _chain_ll_local(ndk_f, nwk_f, nk_v,
                                         d0, w0, m0, zero)
                 sm, t = jax.lax.psum(sm, both), jax.lax.psum(t, both)
@@ -594,7 +572,7 @@ class ShardedGibbsLDA:
                          P(), P())
             if with_initial_ll:
                 out_specs = out_specs + (P(), P())
-            outs = _shard_map(
+            outs = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(D, *mp_spec), P(D), P(*mp_spec), P(),
                           P(D, *mp_spec), P(D), P(*mp_spec), P(),
@@ -700,7 +678,7 @@ class ShardedGibbsLDA:
                          P(), P())
             if with_initial_ll:
                 out_specs = out_specs + (P(), P())
-            outs = _shard_map(
+            outs = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(D, *mp_spec), P(D), P(*mp_spec), P(),
                           P(D, *mp_spec), P(D), P(*mp_spec), P(),
@@ -785,13 +763,13 @@ class ShardedGibbsLDA:
             this standalone form serves the initial (pre-sweep) point
             and external callers."""
             def shard_fn(n_dk, n_wk, n_k, d, w, m):
-                n_k_v = _pcast(n_k, both, to="varying")
-                zero = _pcast(jnp.float32(0), both, to="varying")
+                n_k_v = jax.lax.pcast(n_k, both, to="varying")
+                zero = jax.lax.pcast(jnp.float32(0), both, to="varying")
                 s, t = _chain_ll_local(n_dk[0], n_wk[0], n_k_v,
                                        d[0, 0], w[0, 0], m[0, 0], zero)
                 return jax.lax.psum(s, both), jax.lax.psum(t, both)
 
-            s, t = _shard_map(
+            s, t = jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(D), P(*mp_spec), P(),
                           P(D, *mp_spec), P(D, *mp_spec), P(D, *mp_spec)),

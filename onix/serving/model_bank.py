@@ -36,7 +36,7 @@ bit-identical between forms AND against the single-tenant scan; the
 choice is pure performance. `_BANK_GATHER_MIN_EVENTS` is the measured
 per-backend crossover (events per dispatch), `ONIX_BANK_FORM` pins a
 form for experiments, and unmeasured backends keep the vmap default
-(docs/BANK_r12_cpu.json; TPU rows queued in docs/TPU_QUEUE.json).
+(docs/BANK_r12_cpu.json; not measured on the chip).
 
 Residency: each shape class holds a fixed number of resident slots
 (`capacity`). Admission stages ALL newly-needed tenants of a request
@@ -61,9 +61,8 @@ device it runs on changes (the AD-LDA locality argument, arxiv
 0909.4603, applied one level up: placement, not decomposition).
 `select_shard_form` gates single vs sharded through the shared
 `resolve_form_gate` chain; `_BANK_SHARD_MIN_TENANTS` starts EMPTY per
-the r15 discipline, so auto resolves single-device everywhere until
-the queued TPU crossover lands (docs/TPU_QUEUE.json
-`bank_sharded_tpu`).
+the r15 discipline, so auto resolves single-device everywhere (the
+crossover is not measured on the chip).
 
 Residency tiers (r20): three explicit tiers — HBM (shard slots), host
 RAM (`_models`, bounded by `host_capacity`), disk (`bulk_loader` →
@@ -133,10 +132,9 @@ BANK_EVENTS_FLOOR = 64
 # per dispatch, bank sizes 4..64: 1.7-6x over vmap; the vmap lanes
 # batch-gather whole [D_pad, K] table slices where the flat form
 # gathers exactly the 2K-float rows each event touches —
-# docs/BANK_r12_cpu.json `bank_size_ladder`). tpu: ABSENT until the
-# queued crossover lands (docs/TPU_QUEUE.json `model_bank_tpu`) — the
-# vmap default rides XLA's batched gather there, and the CPU result
-# must not be assumed to transfer.
+# docs/BANK_r12_cpu.json `bank_size_ladder`). tpu: ABSENT (not
+# measured on the chip) — the vmap default rides XLA's batched gather
+# there, and the CPU result must not be assumed to transfer.
 _BANK_GATHER_MIN_EVENTS = {
     "cpu": 0,
 }
@@ -170,9 +168,9 @@ def select_bank_form(form: str, n_requests: int, n_pad: int,
 # one device (per-device waves dispatch independently, so the win is
 # parallel occupancy minus the per-device compile + admission
 # duplication). Keyed by backend like `_BANK_GATHER_MIN_EVENTS`;
-# DELIBERATELY EMPTY for every backend — cpu included — until the
-# queued TPU rows land (docs/TPU_QUEUE.json `bank_sharded_tpu`): this
-# 2-core host's virtual devices share the same cores, so a CPU
+# DELIBERATELY EMPTY for every backend — cpu included — because it
+# is not measured on the chip: a CPU host's virtual devices share
+# the same cores, so a CPU
 # "crossover" would be scheduler noise, never a chip decision. Auto
 # therefore resolves single-device everywhere today; the forms are
 # bit-identical, so pinning `sharded` (config or ONIX_BANK_SHARD) is

@@ -594,7 +594,7 @@ def snapshot(full: bool = False) -> dict:
     """The manifest telemetry block: enablement, span/dump tallies, and
     per-histogram quantile summaries (zeros included — an artifact that
     recorded nothing says so explicitly). `full=True` adds the complete
-    counter snapshot and bucket tables (the TPU-queue per-entry
+    counter snapshot and bucket tables (the launcher->child per-entry
     evidence record)."""
     out = {
         "enabled": TRACER.enabled,
@@ -813,9 +813,9 @@ def _counter_observer(name: str, delta: int, total: int) -> None:
 
 
 def _register_exit_snapshot() -> None:
-    # run_tpu_queue.py sets this to a per-entry path; the child process
+    # A launcher sets this to a per-child path; the child process
     # writes a full telemetry snapshot (counters + histograms) there at
-    # exit, so queue entries carry dispatch/compile evidence, not bare
+    # exit, so its record carries dispatch/compile evidence, not bare
     # walls.
     path = os.environ.get("_ONIX_TELEMETRY_SNAPSHOT")
     if not path:

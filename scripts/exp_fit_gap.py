@@ -19,14 +19,14 @@ each isolated here on the real corpus shape:
      (block_size / V colliding row-updates per vocab row): the
      raw_nwk_scatter / raw_nwk_matmul / raw_nwk_pallas rows feed the
      lda_gibbs._NWK_MATMUL_MIN_DENSITY and _NWK_PALLAS_MIN_DENSITY
-     decision tables (docs/PERF.md; queued TPU run: docs/TPU_QUEUE.json
-     `fitgap_tpu`), bit-identity asserted across all three forms.
+     decision tables (docs/PERF.md; not measured on the chip),
+     bit-identity asserted across all three forms.
   F. sampler form (r11) — the dense O(K)-per-token block sampler vs
      the sparse O(K_active) arm (top-A active sets + stale F+-tree
      proposals + MH correction) swept over K (--k-sweep, default
      16,64,256): the `sampler_k_sweep` rows ARE the decision table
      behind lda_gibbs._SAMPLER_SPARSE_MIN_K (docs/SPARSE_r11_*.json;
-     TPU row queued as `sparse_sampler_tpu`). Interleaved best-of
+     not measured on the chip). Interleaved best-of
      timing, per-K perplexity-band parity ASSERTED (the sparse arm is
      a different chain with the same stationary distribution, so the
      gate-arm contract is an ll band, not bit-identity).
@@ -79,7 +79,7 @@ def main(argv: list[str] | None = None) -> int:
     from onix.pipelines.synth import SYNTH_ARRAYS
     from onix.utils.obs import enable_compile_cache
 
-    enable_compile_cache("/tmp/onix-jax-cache")
+    enable_compile_cache()
     dev = jax.devices()[0]
     out = {"device": str(dev), "backend": jax.default_backend(),
            "n_events": n_events, "n_sweeps": n_sweeps}
@@ -254,7 +254,7 @@ def main(argv: list[str] | None = None) -> int:
     # (test_gibbs, test_pallas_gibbs — and re-asserted HERE at
     # experiment scale), and these rows ARE the decision table behind
     # lda_gibbs._NWK_MATMUL_MIN_DENSITY / _NWK_PALLAS_MIN_DENSITY
-    # (docs/PERF.md; TPU rows in docs/TPU_QUEUE.json `fitgap_tpu`).
+    # (docs/PERF.md; not measured on the chip).
     # Off-TPU the pallas arm runs the interpret-mode emulation — its
     # CPU rate is a correctness diagnostic, not a speed claim.
     out["nwk_collision_density"] = round(block / corpus.n_vocab, 1)

@@ -26,7 +26,8 @@ theta/phi table the pipeline uses.
 
     python scripts/exp_flow_recall.py --events 1e8 --train-events 2e7 \
         --out docs/FLOW_RECALL_r04.json
-CPU dev shape: --cpu --events 2e6 --train-events 5e5
+Runs on whatever JAX_PLATFORMS gives it and prints the device.
+CPU dev shape: --events 2e6 --train-events 5e5
 """
 import argparse
 import json
@@ -50,15 +51,10 @@ def main() -> int:
     ap.add_argument("--bg-sample", type=int, default=200_000)
     ap.add_argument("--depths", type=int, nargs="+",
                     default=[3000, 10_000, 30_000, 100_000])
-    ap.add_argument("--cpu", action="store_true")
     ap.add_argument("--out", default="docs/FLOW_RECALL_r04.json")
     args = ap.parse_args()
 
-    import os
     import jax
-    if args.cpu:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from onix.config import LDAConfig
@@ -66,6 +62,8 @@ def main() -> int:
     from onix.parallel.mesh import make_mesh
     from onix.parallel.sharded_gibbs import ShardedGibbsLDA
     from onix.pipelines.corpus_build import build_corpus
+    from onix.utils.obs import print_device
+    print_device()
     from onix.pipelines.scale import (_default_anomalies, _stream_score,
                                       _words_from_cols,
                                       extend_model_for_unseen)

@@ -1,5 +1,6 @@
-"""TPU experiment: Gibbs sweep sampler/scatter variants
-(EXPG_CPU=1 runs a tiny CPU smoke of the same code).
+"""TPU experiment: Gibbs sweep sampler/scatter variants. Runs on
+whatever JAX_PLATFORMS gives it and prints the device; on a CPU backend
+it shrinks to a tiny smoke of the same code.
 Companion to docs/PERF.md "exponential race" — run on a real chip:
 
     python scripts/exp_gibbs_sweep.py
@@ -14,16 +15,11 @@ C: B + within-block word-sorted tokens + indices_are_sorted scatter on
    n_wk (block partition unchanged -> same stationary behavior; order
    within a block is irrelevant to the blocked sampler).
 """
-import os
 import sys
 import time
-if os.environ.get("EXPG_CPU"):
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np
 import jax
-if os.environ.get("EXPG_CPU"):
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 
 sys.path.insert(0, str(__import__("pathlib").Path(__file__).resolve().parent.parent))
@@ -31,8 +27,9 @@ sys.path.insert(0, str(__import__("pathlib").Path(__file__).resolve().parent.par
 from onix.models import lda_gibbs  # noqa: E402
 
 N_DOCS, N_VOCAB, K = 200_000, 4_096, 20
-N_TOKENS = (1 << 18) if os.environ.get("EXPG_CPU") else (1 << 23)
-BLOCK = (1 << 14) if os.environ.get("EXPG_CPU") else (1 << 17)
+_CPU_SMOKE = jax.default_backend() == "cpu"
+N_TOKENS = (1 << 18) if _CPU_SMOKE else (1 << 23)
+BLOCK = (1 << 14) if _CPU_SMOKE else (1 << 17)
 REPS = 4
 
 rng = np.random.default_rng(0)
@@ -121,5 +118,8 @@ def run(variant):
           f"topic-entropy={ent:.3f}/{np.log(K):.3f}", flush=True)
 
 
+from onix.utils.obs import print_device  # noqa: E402
+
+print_device()
 for v in ["gumbel", "race", "race_sorted"]:
     run(v)

@@ -13,8 +13,9 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, str(__import__("pathlib").Path(__file__).resolve().parent.parent))
-from onix.utils.obs import enable_compile_cache  # noqa: E402
-enable_compile_cache(__import__("tempfile").gettempdir() + "/onix-jax-cache")
+from onix.utils.obs import enable_compile_cache, print_device  # noqa: E402
+enable_compile_cache()
+print_device()
 from onix.models.scoring import top_suspicious  # noqa: E402
 
 N_DOCS, N_VOCAB, K = 100_000, 65_536, 20

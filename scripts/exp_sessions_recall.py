@@ -22,18 +22,14 @@ identity cannot see them, and the honest expectation is ~0 recall —
 the artifact records that too, with the reason.
 
     python scripts/exp_sessions_recall.py --out docs/RECALL_r05_sessions.json
+
+Runs on whatever JAX_PLATFORMS gives it and prints the device.
 """
 import argparse
 import json
-import os
 import pathlib
 import sys
 import time
-
-import jax
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
@@ -139,6 +135,8 @@ def main() -> int:
                     default=["flow", "dns", "proxy"])
     ap.add_argument("--out", default="docs/RECALL_r05_sessions.json")
     args = ap.parse_args()
+    from onix.utils.obs import print_device
+    print_device()
 
     doc = {
         "metric": "planted-campaign recall on INDEPENDENT session/"

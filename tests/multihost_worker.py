@@ -18,7 +18,6 @@ jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp  # noqa: E402
 from jax.experimental import multihost_utils  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
@@ -44,9 +43,9 @@ def main() -> None:
     sharding = NamedSharding(mesh, P(DP_AXIS))
     local = np.full((2, 3), float(pid + 1), np.float32)
     arr = jax.make_array_from_process_local_data(sharding, local)
-    out = jax.jit(shard_map(lambda x: jax.lax.psum(x, DP_AXIS),
-                            mesh=mesh, in_specs=P(DP_AXIS),
-                            out_specs=P()))(arr)
+    out = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, DP_AXIS),
+                                mesh=mesh, in_specs=P(DP_AXIS),
+                                out_specs=P()))(arr)
     np.testing.assert_allclose(np.asarray(out.addressable_data(0)), 6.0)
     print("MULTIHOST_OK", flush=True)
 

@@ -138,8 +138,8 @@ def test_scale_chained_ensemble():
 
 @pytest.mark.slow
 def test_scale_resume_matches_uninterrupted(tmp_path):
-    """--resume-dir (VERDICT r04 next #1: severed tunnel windows must
-    extend a run, not restart it). A run resumed mid-stream must
+    """--resume-dir (a killed session must extend a run, not restart
+    it). A run resumed mid-stream must
     produce the SAME winners as an uninterrupted run: the fitted model
     is loaded instead of re-fitted and completed chunks' bottom-k
     survive, so the final merge sees identical inputs."""

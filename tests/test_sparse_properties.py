@@ -72,14 +72,20 @@ def test_mh_corrected_draws_within_tolerance(seed):
 
     rng = np.random.default_rng(seed)
     K, V, D = 6, 10, 4
-    n_dk = jnp.asarray(rng.integers(0, 8, (D, K)).astype(np.int32))
-    n_wk = jnp.asarray(rng.integers(0, 5, (V, K)).astype(np.int32))
-    n_k = n_wk.sum(axis=0)
+    n_dk = rng.integers(0, 8, (D, K)).astype(np.int32)
+    n_wk = rng.integers(0, 5, (V, K)).astype(np.int32)
     alpha, eta = 0.4, 0.05
     v_eta = V * eta
     d0 = int(rng.integers(0, D))
     w0 = int(rng.integers(0, V))
     z0 = int(rng.integers(0, K))
+    # The token's own assignment is counted in every table — a state
+    # without it is not a sampler state (and its exclusion below would
+    # go negative).
+    n_dk[d0, z0] = max(n_dk[d0, z0], 1)
+    n_wk[w0, z0] = max(n_wk[w0, z0], 1)
+    n_dk, n_wk = jnp.asarray(n_dk), jnp.asarray(n_wk)
+    n_k = n_wk.sum(axis=0)
     nd = np.asarray(n_dk)[d0].astype(np.float64)
     nw = np.asarray(n_wk)[w0].astype(np.float64)
     nk = np.asarray(n_k).astype(np.float64)
