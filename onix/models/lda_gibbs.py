@@ -35,6 +35,7 @@ import numpy as np
 
 from onix.config import LDAConfig
 from onix.corpus import Corpus
+from onix.utils.obs import device_scope
 
 
 class GibbsState(NamedTuple):
@@ -753,24 +754,39 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
             # and the n_dk scatter stays here (collision-free).
             from onix.models import pallas_gibbs
             shape = (w.shape[0], k_topics)
-            if use_gumbel:
-                noise = jax.random.gumbel(skey, shape, dtype=jnp.float32)
-            else:
-                noise = jax.random.uniform(skey, shape, dtype=jnp.float32,
-                                           minval=1e-38)
-            z_new, d_wk = pallas_gibbs.sample_count_block(
-                n_dk[d], n_wk[w], n_k, noise, w, z_old, m,
-                alpha=alpha, eta=eta, v_eta=v_eta, k_topics=k_topics,
-                n_rows=n_wk.shape[0], use_gumbel=use_gumbel)
-            delta = _one_hot(z_new, k_topics) - _one_hot(z_old, k_topics)
-            return (n_dk.at[d].add(delta), n_wk + d_wk,
-                    n_k + delta.sum(axis=0, dtype=jnp.int32), key), z_new
-        oh_old = _one_hot(z_old, k_topics)          # zero row for padding
-        ohf = oh_old.astype(jnp.float32)
-        # Counts excluding each token's own current assignment.
-        ndk = n_dk[d].astype(jnp.float32) - ohf
-        nwk = n_wk[w].astype(jnp.float32) - ohf
-        nk = n_k.astype(jnp.float32)[None, :] - ohf
+            with device_scope("onix.sweep.gather"):
+                ndk_rows, nwk_rows = n_dk[d], n_wk[w]
+            # The kernel samples and counts the n_wk delta in one pass:
+            # both are booked to the sampling scope.
+            with device_scope("onix.sweep.sample"):
+                if use_gumbel:
+                    noise = jax.random.gumbel(skey, shape,
+                                              dtype=jnp.float32)
+                else:
+                    noise = jax.random.uniform(skey, shape,
+                                               dtype=jnp.float32,
+                                               minval=1e-38)
+                z_new, d_wk = pallas_gibbs.sample_count_block(
+                    ndk_rows, nwk_rows, n_k, noise, w, z_old, m,
+                    alpha=alpha, eta=eta, v_eta=v_eta, k_topics=k_topics,
+                    n_rows=n_wk.shape[0], use_gumbel=use_gumbel)
+                delta = (_one_hot(z_new, k_topics)
+                         - _one_hot(z_old, k_topics))
+            with device_scope("onix.sweep.scatter"):
+                n_dk = n_dk.at[d].add(delta)
+            with device_scope("onix.sweep.nwk"):
+                n_wk = n_wk + d_wk
+                n_k = n_k + delta.sum(axis=0, dtype=jnp.int32)
+            return (n_dk, n_wk, n_k, key), z_new
+        # Device scopes onix.sweep.* (docs/OBSERVABILITY.md): the
+        # profiler books each op's time to the scope it was traced in.
+        with device_scope("onix.sweep.gather"):
+            oh_old = _one_hot(z_old, k_topics)      # zero row for padding
+            ohf = oh_old.astype(jnp.float32)
+            # Counts excluding each token's own current assignment.
+            ndk = n_dk[d].astype(jnp.float32) - ohf
+            nwk = n_wk[w].astype(jnp.float32) - ohf
+            nk = n_k.astype(jnp.float32)[None, :] - ohf
         # Categorical sampling — two statistically identical forms,
         # chosen per backend at trace time (docs/PERF.md "exponential
         # race", measured both ways on both platforms):
@@ -786,36 +802,40 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
         #     scatter-bound there so extra transcendentals are free,
         #     and log space measured ~5% faster (37.5 vs 35.8 Mtok/s,
         #     scripts/exp_gibbs_sweep.py on v5lite).
-        if use_gumbel:
-            logp = (jnp.log(ndk + alpha)
-                    + jnp.log(jnp.maximum(nwk + eta, 1e-10))
-                    - jnp.log(nk + v_eta))
-            g = jax.random.gumbel(skey, logp.shape, dtype=jnp.float32)
-            z_new = jnp.argmax(logp + g, axis=-1).astype(jnp.int32)
-        else:
-            p = ((ndk + alpha) * jnp.maximum(nwk + eta, 1e-10)
-                 / (nk + v_eta))
-            u = jax.random.uniform(skey, p.shape, dtype=jnp.float32,
-                                   minval=1e-38)
-            z_new = jnp.argmax(p / -jnp.log(u), axis=-1).astype(jnp.int32)
-        z_new = jnp.where(m > 0, z_new, z_old)      # padding keeps sentinel
-        # Dense one-hot delta rows, NOT per-element scalar scatters:
-        # XLA's TPU scatter vectorizes the K lane dimension of row
-        # updates, so the dense [B,K] delta runs ~2x faster than the
-        # "only 2 of K entries change" rank-1 formulation (measured
-        # 35M vs 18M tokens/s at K=20).
-        delta = _one_hot(z_new, k_topics) - oh_old  # int32-exact update
-        n_dk = n_dk.at[d].add(delta)
-        if form == "matmul":
-            oh_w = jax.nn.one_hot(w, n_wk.shape[0], dtype=jnp.bfloat16)
-            d_wk = jax.lax.dot_general(
-                oh_w, delta.astype(jnp.bfloat16),
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            n_wk = n_wk + d_wk.astype(jnp.int32)
-        else:
-            n_wk = n_wk.at[w].add(delta)
-        n_k = n_k + delta.sum(axis=0, dtype=jnp.int32)
+        with device_scope("onix.sweep.sample"):
+            if use_gumbel:
+                logp = (jnp.log(ndk + alpha)
+                        + jnp.log(jnp.maximum(nwk + eta, 1e-10))
+                        - jnp.log(nk + v_eta))
+                g = jax.random.gumbel(skey, logp.shape, dtype=jnp.float32)
+                z_new = jnp.argmax(logp + g, axis=-1).astype(jnp.int32)
+            else:
+                p = ((ndk + alpha) * jnp.maximum(nwk + eta, 1e-10)
+                     / (nk + v_eta))
+                u = jax.random.uniform(skey, p.shape, dtype=jnp.float32,
+                                       minval=1e-38)
+                z_new = jnp.argmax(p / -jnp.log(u),
+                                   axis=-1).astype(jnp.int32)
+            z_new = jnp.where(m > 0, z_new, z_old)  # padding keeps sentinel
+            # Dense one-hot delta rows, NOT per-element scalar scatters:
+            # XLA's TPU scatter vectorizes the K lane dimension of row
+            # updates, so the dense [B,K] delta runs ~2x faster than the
+            # "only 2 of K entries change" rank-1 formulation (measured
+            # 35M vs 18M tokens/s at K=20).
+            delta = _one_hot(z_new, k_topics) - oh_old  # int32-exact
+        with device_scope("onix.sweep.scatter"):
+            n_dk = n_dk.at[d].add(delta)
+        with device_scope("onix.sweep.nwk"):
+            if form == "matmul":
+                oh_w = jax.nn.one_hot(w, n_wk.shape[0], dtype=jnp.bfloat16)
+                d_wk = jax.lax.dot_general(
+                    oh_w, delta.astype(jnp.bfloat16),
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                n_wk = n_wk + d_wk.astype(jnp.int32)
+            else:
+                n_wk = n_wk.at[w].add(delta)
+            n_k = n_k + delta.sum(axis=0, dtype=jnp.int32)
         return (n_dk, n_wk, n_k, key), z_new
 
     return block_step
@@ -935,7 +955,7 @@ def run_fit_segments(state, start: int, segments, *, superstep_fn,
     `notify(sweep, state, ll)` adapts each engine's public callback
     signature. Returns (state, ll_history)."""
     from onix import checkpoint as ckpt
-    from onix.utils import faults
+    from onix.utils import faults, telemetry
 
     ll_history: list[tuple[int, float]] = []
     if not segments:
@@ -943,16 +963,22 @@ def run_fit_segments(state, start: int, segments, *, superstep_fn,
         # in the history.
         ll_history.append((start - 1, float(initial_ll_fn(state))))
     for i, (seg_start, seg_len) in enumerate(segments):
+        # The dispatch returns with the device still running; the host
+        # then blocks in float(ll): two spans, so a trace tells them
+        # apart (docs/OBSERVABILITY.md).
+        with telemetry.TRACER.span("fit.superstep", start=seg_start,
+                                   sweeps=seg_len, with_initial_ll=i == 0):
+            state, *lls = superstep_fn(state, seg_start, seg_len, i == 0)
+        with telemetry.TRACER.span("fit.wait"):
+            lls = [float(ll) for ll in lls]
         if i == 0:
-            state, ll0, ll = superstep_fn(state, seg_start, seg_len, True)
-            ll_history.append((seg_start - 1, float(ll0)))
-        else:
-            state, ll = superstep_fn(state, seg_start, seg_len, False)
+            ll_history.append((seg_start - 1, lls[0]))
         s = seg_start + seg_len - 1
-        ll_history.append((s, float(ll)))
+        ll_history.append((s, lls[-1]))
         if (checkpoint_dir is not None and checkpoint_every > 0
                 and (s + 1) % checkpoint_every == 0):
-            save_fn(state, s)
+            with telemetry.TRACER.span("fit.checkpoint", sweep=s):
+                save_fn(state, s)
         if fault_sweep is not None and s == fault_sweep:
             raise ckpt.SimulatedPreemption(
                 f"fault injected after sweep {s} "
@@ -962,7 +988,8 @@ def run_fit_segments(state, start: int, segments, *, superstep_fn,
         # the generalized form of the legacy ONIX_FAULT_SWEEP hook.
         faults.fire("fit", "sweep", index=s)
         if notify is not None:
-            notify(s, state, ll_history[-1][1])
+            with telemetry.TRACER.span("fit.notify", sweep=s):
+                notify(s, state, ll_history[-1][1])
     return state, ll_history
 
 
@@ -1022,10 +1049,11 @@ def log_likelihood(
         lp = jnp.log(jnp.maximum(p, 1e-30)) * m
         return (carry[0] + lp.sum(), carry[1] + m.sum()), None
 
-    (total, n), _ = jax.lax.scan(
-        block, (jnp.float32(0.0), jnp.float32(0.0)),
-        (doc_blocks, word_blocks, mask_blocks))
-    return total / jnp.maximum(n, 1.0)
+    with device_scope("onix.sweep.loglik"):
+        (total, n), _ = jax.lax.scan(
+            block, (jnp.float32(0.0), jnp.float32(0.0)),
+            (doc_blocks, word_blocks, mask_blocks))
+        return total / jnp.maximum(n, 1.0)
 
 
 # Relative predictive-ll band within which the sparse arm must land on

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from onix.utils.obs import device_scope
+
 
 def score_events(theta: jax.Array, phi_wk: jax.Array,
                  doc_ids: jax.Array, word_ids: jax.Array) -> jax.Array:
@@ -115,34 +117,42 @@ def _scan_bottom_k(arrays: tuple, n: int, score_chunk, *,
     never a lossy cap (PERF.md lever 4)."""
     if n == 0:     # static shape: resolved at trace time, not per-call
         return _empty_topk(max_results)
-    cols, base, n_chunks, chunk = _chunked_cols(arrays, n, chunk)
+    # `onix.select` names everything here but `score_chunk` (device
+    # scopes: docs/OBSERVABILITY.md); the caller names its own scoring.
+    with device_scope("onix.select"):
+        cols, base, n_chunks, chunk = _chunked_cols(arrays, n, chunk)
 
     def step(carry, xs):
         best_s, best_i = carry
         *cs, ci = xs
-        idx = ci * chunk + base
-        s = jnp.where(idx < n, score_chunk(*cs), jnp.inf)
-        if merge_buffer is None or merge_buffer >= chunk:
-            return _merge_bottom_k(best_s, best_i, s, idx, max_results), None
+        scored = score_chunk(*cs)
+        with device_scope("onix.select"):
+            idx = ci * chunk + base
+            s = jnp.where(idx < n, scored, jnp.inf)
+            if merge_buffer is None or merge_buffer >= chunk:
+                return _merge_bottom_k(best_s, best_i, s, idx,
+                                       max_results), None
 
-        def small_merge():
-            # All candidates fit the buffer: the chunk's bottom-B is a
-            # superset of them (anything outside is >= the threshold
-            # and loses to an incumbent at the final top_k's tie rule).
-            neg, pos = jax.lax.top_k(-s, merge_buffer)
-            return _merge_bottom_k(best_s, best_i, -neg, idx[pos],
-                                   max_results)
+            def small_merge():
+                # All candidates fit the buffer: the chunk's bottom-B is
+                # a superset of them (anything outside is >= the
+                # threshold and loses to an incumbent at the final
+                # top_k's tie rule).
+                neg, pos = jax.lax.top_k(-s, merge_buffer)
+                return _merge_bottom_k(best_s, best_i, -neg, idx[pos],
+                                       max_results)
 
-        n_cand = jnp.sum(s < best_s[-1])    # running k-th best
-        return jax.lax.cond(
-            n_cand <= merge_buffer, small_merge,
-            lambda: _merge_bottom_k(best_s, best_i, s, idx, max_results)), \
-            None
+            n_cand = jnp.sum(s < best_s[-1])    # running k-th best
+            return jax.lax.cond(
+                n_cand <= merge_buffer, small_merge,
+                lambda: _merge_bottom_k(best_s, best_i, s, idx,
+                                        max_results)), None
 
     (out_s, out_i), _ = jax.lax.scan(
         step, tuple(_empty_topk(max_results)),
         (*cols, jnp.arange(n_chunks, dtype=jnp.int32)))
-    return _finalize_topk(out_s, out_i)
+    with device_scope("onix.select"):
+        return _finalize_topk(out_s, out_i)
 
 
 @functools.partial(jax.jit,

@@ -55,6 +55,8 @@ from onix.config import LDAConfig
 from onix.corpus import Corpus
 from onix.models import lda_gibbs
 from onix.parallel.mesh import MP_AXIS, data_axes_of, make_mesh
+from onix.utils import telemetry
+from onix.utils.obs import device_scope
 
 
 class ShardedCorpus(NamedTuple):
@@ -474,7 +476,8 @@ class ShardedGibbsLDA:
                                           (d0, w0, m0))
                 return sm, t
 
-            return jax.vmap(one_chain)(ndk_f, nwk_f, nk_v)
+            with device_scope("onix.sweep.loglik"):
+                return jax.vmap(one_chain)(ndk_f, nwk_f, nk_v)
 
         mp_spec = (M,) if M else ()
 
@@ -977,8 +980,14 @@ class ShardedGibbsLDA:
         cfg = self.config
         n_sweeps = cfg.n_sweeps if n_sweeps is None else n_sweeps
         S_step = cfg.superstep or SUPERSTEP_DEFAULT
-        sc = self.prepare(corpus)
-        docs, words, mask = self.device_corpus(sc)
+        with telemetry.TRACER.span("fit.prepare", tokens=corpus.n_tokens,
+                                   docs=corpus.n_docs):
+            sc = self.prepare(corpus)
+        with telemetry.TRACER.span(
+                "fit.device_corpus",
+                bytes=sum(int(a.nbytes) for a in (
+                    sc.doc_blocks, sc.word_blocks, sc.mask_blocks))):
+            docs, words, mask = self.device_corpus(sc)
         # layout=4: the fused-superstep layout — the jitted carry holds
         # the accumulator state, checkpoints land only at superstep
         # boundaries, and the superstep size joins the identity
@@ -1025,13 +1034,20 @@ class ShardedGibbsLDA:
             checkpoint_dir = pathlib.Path(checkpoint_dir) / fp
         start = 0
         state = None
-        if checkpoint_dir is not None and resume:
-            saved = ckpt.load_latest(checkpoint_dir)
-            if saved is not None and saved.meta.get("fingerprint") == fp:
-                state = self.restore_state(saved.arrays)
-                start = saved.sweep + 1
-        if state is None:
-            state = self.init_state(sc, init_phi=init_phi)
+        with telemetry.TRACER.span("fit.init_state") as span:
+            if checkpoint_dir is not None and resume:
+                saved = ckpt.load_latest(checkpoint_dir)
+                if (saved is not None
+                        and saved.meta.get("fingerprint") == fp):
+                    state = self.restore_state(saved.arrays)
+                    start = saved.sweep + 1
+            resumed = state is not None
+            if not resumed:
+                state = self.init_state(sc, init_phi=init_phi)
+            if span is not None:
+                span.attrs.update(
+                    resumed=resumed,
+                    bytes=sum(int(a.nbytes) for a in state))
         from onix.models.lda_gibbs import run_fit_segments
         segments = plan_segments(
             start, n_sweeps, S_step,
@@ -1054,7 +1070,8 @@ class ShardedGibbsLDA:
             fault_sweep=fault_inject_sweep,
             notify=(None if callback is None
                     else lambda s, st, ll: callback(s, st)))
-        theta, phi_wk = self.estimates(state, sc, corpus.n_docs)
+        with telemetry.TRACER.span("fit.estimates"):
+            theta, phi_wk = self.estimates(state, sc, corpus.n_docs)
         return {"state": state, "sharded_corpus": sc,
                 "theta": theta, "phi_wk": phi_wk,
                 "ll_history": ll_history}

@@ -54,6 +54,8 @@ import numpy as np
 from onix.models import scoring
 from onix.pipelines.words import (FLOW_SPEC, _PCLASS_HH, _PROTO_UNK,
                                   N_BINS_DEFAULT)
+from onix.utils import telemetry
+from onix.utils.obs import device_scope
 
 def host_words_forced() -> bool:
     """True when the env pins the HOST word builders. Device-resident
@@ -166,25 +168,28 @@ def _flow_flat_idx(t: FlowDeviceTables, v_x: int, unseen_w: int,
     """Per-chunk device transform: raw columns -> (idx_src, idx_dst)
     flat score-table indices. Mirrors flow_words_from_arrays +
     word_ids_packed/doc_ids_u32 field for field."""
-    sport = sport.astype(jnp.int32)
-    dport = dport.astype(jnp.int32)
-    s_low = sport <= 1024
-    d_low = dport <= 1024
-    pclass = jnp.where(
-        s_low & d_low, jnp.minimum(sport, dport),
-        jnp.where(s_low, sport,
-                  jnp.where(d_low, dport, jnp.int32(_PCLASS_HH))))
-    hbin = jnp.searchsorted(t.hour_edges, hour, side="right")
-    bbin = jnp.searchsorted(t.byt_edges, jnp.log1p(byt), side="right")
-    pbin = jnp.searchsorted(t.pkt_edges, jnp.log1p(pkt), side="right")
-    key = (pclass << _PCLASS_SHIFT
-           | t.proto_remap[proto.astype(jnp.int32)] << _PROTO_SHIFT
-           | hbin.astype(jnp.int32) << (2 * _BIN_BITS)
-           | bbin.astype(jnp.int32) << _BIN_BITS
-           | pbin.astype(jnp.int32))
-    wid = _lookup_sorted(t.word_key_c, t.word_ids, key, unseen_w)
-    did_s = _lookup_sorted(t.doc_u32, t.doc_ids, sip, unseen_d)
-    did_d = _lookup_sorted(t.doc_u32, t.doc_ids, dip, unseen_d)
+    with device_scope("onix.words.bin"):
+        sport = sport.astype(jnp.int32)
+        dport = dport.astype(jnp.int32)
+        s_low = sport <= 1024
+        d_low = dport <= 1024
+        pclass = jnp.where(
+            s_low & d_low, jnp.minimum(sport, dport),
+            jnp.where(s_low, sport,
+                      jnp.where(d_low, dport, jnp.int32(_PCLASS_HH))))
+        hbin = jnp.searchsorted(t.hour_edges, hour, side="right")
+        bbin = jnp.searchsorted(t.byt_edges, jnp.log1p(byt), side="right")
+        pbin = jnp.searchsorted(t.pkt_edges, jnp.log1p(pkt), side="right")
+        key = (pclass << _PCLASS_SHIFT
+               | t.proto_remap[proto.astype(jnp.int32)] << _PROTO_SHIFT
+               | hbin.astype(jnp.int32) << (2 * _BIN_BITS)
+               | bbin.astype(jnp.int32) << _BIN_BITS
+               | pbin.astype(jnp.int32))
+    with device_scope("onix.words.lookup_word"):
+        wid = _lookup_sorted(t.word_key_c, t.word_ids, key, unseen_w)
+    with device_scope("onix.words.lookup_doc"):
+        did_s = _lookup_sorted(t.doc_u32, t.doc_ids, sip, unseen_d)
+        did_d = _lookup_sorted(t.doc_u32, t.doc_ids, dip, unseen_d)
     return did_s * jnp.int32(v_x) + wid, did_d * jnp.int32(v_x) + wid
 
 
@@ -197,8 +202,9 @@ def _flow_stream_scan(tables: FlowDeviceTables, table_flat: jax.Array,
     def score_chunk(s_ip, d_ip, s_p, d_p, pr, hr, by, pk):
         idx_s, idx_d = _flow_flat_idx(tables, v_x, unseen_w, unseen_d,
                                       s_ip, d_ip, s_p, d_p, pr, hr, by, pk)
-        s = jnp.minimum(table_flat[idx_s], table_flat[idx_d])
-        return jnp.where(s < tol, s, jnp.inf)
+        with device_scope("onix.score.gather"):
+            s = jnp.minimum(table_flat[idx_s], table_flat[idx_d])
+            return jnp.where(s < tol, s, jnp.inf)
 
     return scoring._scan_bottom_k(
         (sip, dip, sport, dport, proto, hour, byt, pkt), sip.shape[0],
@@ -325,20 +331,25 @@ def _dns_stream_scan(tables: DnsDeviceTables, table_flat: jax.Array,
                      tol: float, max_results: int,
                      chunk: int) -> scoring.TopK:
     def score_chunk(cl, co, qt, rc, fl, hr):
-        flbin = jnp.searchsorted(tables.flen_edges, fl, side="right")
-        hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
-        key = (partial_u[co]
-               | flbin.astype(jnp.int32)
-               | hbin.astype(jnp.int32) << _DNS_HBIN_SHIFT
-               | qt << _DNS_QTYPE_SHIFT
-               | rc << _DNS_RCODE_SHIFT)
-        valid = ((qt >= 0) & (qt < 256) & (rc >= 0) & (rc < 16))
-        key = jnp.where(valid, key, jnp.int32(-1))
-        wid = _lookup_sorted(tables.word_key_c, tables.word_ids, key,
-                             unseen_w)
-        did = _lookup_sorted(tables.doc_u32, tables.doc_ids, cl, unseen_d)
-        s = table_flat[did * jnp.int32(v_x) + wid]
-        return jnp.where(s < tol, s, jnp.inf)
+        with device_scope("onix.words.bin"):
+            flbin = jnp.searchsorted(tables.flen_edges, fl, side="right")
+            hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
+            key = (partial_u[co]
+                   | flbin.astype(jnp.int32)
+                   | hbin.astype(jnp.int32) << _DNS_HBIN_SHIFT
+                   | qt << _DNS_QTYPE_SHIFT
+                   | rc << _DNS_RCODE_SHIFT)
+            valid = ((qt >= 0) & (qt < 256) & (rc >= 0) & (rc < 16))
+            key = jnp.where(valid, key, jnp.int32(-1))
+        with device_scope("onix.words.lookup_word"):
+            wid = _lookup_sorted(tables.word_key_c, tables.word_ids, key,
+                                 unseen_w)
+        with device_scope("onix.words.lookup_doc"):
+            did = _lookup_sorted(tables.doc_u32, tables.doc_ids, cl,
+                                 unseen_d)
+        with device_scope("onix.score.gather"):
+            s = table_flat[did * jnp.int32(v_x) + wid]
+            return jnp.where(s < tol, s, jnp.inf)
 
     return scoring._scan_bottom_k(
         (client, codes, qtype, rcode, flen, hour), client.shape[0],
@@ -363,56 +374,63 @@ def _dns_stream_scan(tables: DnsDeviceTables, table_flat: jax.Array,
 
 
 def _put(a) -> jax.Array:
-    return jax.device_put(a)
+    with telemetry.TRACER.span("scan.h2d_put", bytes=int(a.nbytes)):
+        return jax.device_put(a)
 
 
 def stage_flow_cols(cols: dict) -> dict:
     """Cast + async-transfer one flow chunk's raw columns (~25 B/event)."""
-    return {
-        "_staged": True,
-        "sip_u32": _put(np.asarray(cols["sip_u32"], np.uint32)),
-        "dip_u32": _put(np.asarray(cols["dip_u32"], np.uint32)),
-        "sport": _put(np.asarray(cols["sport"], np.int32)),
-        "dport": _put(np.asarray(cols["dport"], np.int32)),
-        "proto_id": _put(np.asarray(cols["proto_id"], np.int32)),
-        "hour": _put(np.asarray(cols["hour"], np.float32)),
-        "ibyt": _put(np.asarray(cols["ibyt"], np.float32)),
-        "ipkt": _put(np.asarray(cols["ipkt"], np.float32)),
-        "proto_classes": list(cols["proto_classes"]),
-    }
+    with telemetry.TRACER.span("scan.stage",
+                               events=len(cols["sip_u32"])):
+        return {
+            "_staged": True,
+            "sip_u32": _put(np.asarray(cols["sip_u32"], np.uint32)),
+            "dip_u32": _put(np.asarray(cols["dip_u32"], np.uint32)),
+            "sport": _put(np.asarray(cols["sport"], np.int32)),
+            "dport": _put(np.asarray(cols["dport"], np.int32)),
+            "proto_id": _put(np.asarray(cols["proto_id"], np.int32)),
+            "hour": _put(np.asarray(cols["hour"], np.float32)),
+            "ibyt": _put(np.asarray(cols["ibyt"], np.float32)),
+            "ipkt": _put(np.asarray(cols["ipkt"], np.float32)),
+            "proto_classes": list(cols["proto_classes"]),
+        }
 
 
 def stage_dns_cols(cols: dict, edges: dict) -> dict:
     """Host string features per UNIQUE qname, then async-transfer."""
-    return {
-        "_staged": True,
-        "partial_u": _put(_pad_pow2(dns_partial_keys(cols["qnames"],
-                                                     edges))),
-        "client_u32": _put(np.asarray(cols["client_u32"], np.uint32)),
-        "qname_codes": _put(np.asarray(cols["qname_codes"], np.int32)),
-        "qtype": _put(np.asarray(cols["qtype"], np.int32)),
-        "rcode": _put(np.asarray(cols["rcode"], np.int32)),
-        "frame_len": _put(np.asarray(cols["frame_len"], np.float32)),
-        "hour": _put(np.asarray(cols["hour"], np.float32)),
-    }
+    with telemetry.TRACER.span("scan.stage",
+                               events=len(cols["client_u32"])):
+        return {
+            "_staged": True,
+            "partial_u": _put(_pad_pow2(dns_partial_keys(cols["qnames"],
+                                                         edges))),
+            "client_u32": _put(np.asarray(cols["client_u32"], np.uint32)),
+            "qname_codes": _put(np.asarray(cols["qname_codes"], np.int32)),
+            "qtype": _put(np.asarray(cols["qtype"], np.int32)),
+            "rcode": _put(np.asarray(cols["rcode"], np.int32)),
+            "frame_len": _put(np.asarray(cols["frame_len"], np.float32)),
+            "hour": _put(np.asarray(cols["hour"], np.float32)),
+        }
 
 
 def stage_proxy_cols(cols: dict, edges: dict) -> dict:
     """Host string features per UNIQUE uri/host/agent, then transfer."""
-    uri_p, host_p, ua_p = proxy_partial_keys(
-        cols["uris"], cols["hosts"], cols["agents"], edges)
-    return {
-        "_staged": True,
-        "uri_p": _put(_pad_pow2(uri_p)),
-        "host_p": _put(_pad_pow2(host_p)),
-        "ua_p": _put(_pad_pow2(ua_p)),
-        "client_u32": _put(np.asarray(cols["client_u32"], np.uint32)),
-        "uri_codes": _put(np.asarray(cols["uri_codes"], np.int32)),
-        "host_codes": _put(np.asarray(cols["host_codes"], np.int32)),
-        "ua_codes": _put(np.asarray(cols["ua_codes"], np.int32)),
-        "respcode": _put(np.asarray(cols["respcode"], np.int32)),
-        "hour": _put(np.asarray(cols["hour"], np.float32)),
-    }
+    with telemetry.TRACER.span("scan.stage",
+                               events=len(cols["client_u32"])):
+        uri_p, host_p, ua_p = proxy_partial_keys(
+            cols["uris"], cols["hosts"], cols["agents"], edges)
+        return {
+            "_staged": True,
+            "uri_p": _put(_pad_pow2(uri_p)),
+            "host_p": _put(_pad_pow2(host_p)),
+            "ua_p": _put(_pad_pow2(ua_p)),
+            "client_u32": _put(np.asarray(cols["client_u32"], np.uint32)),
+            "uri_codes": _put(np.asarray(cols["uri_codes"], np.int32)),
+            "host_codes": _put(np.asarray(cols["host_codes"], np.int32)),
+            "ua_codes": _put(np.asarray(cols["ua_codes"], np.int32)),
+            "respcode": _put(np.asarray(cols["respcode"], np.int32)),
+            "hour": _put(np.asarray(cols["hour"], np.float32)),
+        }
 
 
 STAGE_FNS = {"flow": lambda cols, edges: stage_flow_cols(cols),
@@ -430,12 +448,14 @@ def dns_stream_bottom_k(tables: DnsDeviceTables, table_flat: jax.Array,
     dict (double-buffered callers stage the next chunk early)."""
     if not cols.get("_staged"):
         cols = stage_dns_cols(cols, edges)
-    return _dns_stream_scan(
-        tables, table_flat, cols["partial_u"], cols["client_u32"],
-        cols["qname_codes"], cols["qtype"], cols["rcode"],
-        cols["frame_len"], cols["hour"],
-        v_x=v_x, unseen_w=unseen_w, unseen_d=unseen_d, tol=tol,
-        max_results=max_results, chunk=chunk)
+    with telemetry.TRACER.span("scan.dispatch",
+                               events=cols["client_u32"].shape[0]):
+        return _dns_stream_scan(
+            tables, table_flat, cols["partial_u"], cols["client_u32"],
+            cols["qname_codes"], cols["qtype"], cols["rcode"],
+            cols["frame_len"], cols["hour"],
+            v_x=v_x, unseen_w=unseen_w, unseen_d=unseen_d, tol=tol,
+            max_results=max_results, chunk=chunk)
 
 
 class ProxyDeviceTables(NamedTuple):
@@ -510,18 +530,23 @@ def _proxy_stream_scan(tables: ProxyDeviceTables, table_flat: jax.Array,
                        v_x: int, unseen_w: int, unseen_d: int, tol: float,
                        max_results: int, chunk: int) -> scoring.TopK:
     def score_chunk(cl, uc, hc, ac, rc, hr):
-        hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
-        cclass = rc // 100
-        key = (uri_p[uc] | host_p[hc] | ua_p[ac]
-               | cclass
-               | hbin.astype(jnp.int32) << _PROXY_HBIN_SHIFT)
-        valid = (rc >= 0) & (cclass < 8)
-        key = jnp.where(valid, key, jnp.int32(-1))
-        wid = _lookup_sorted(tables.word_key_c, tables.word_ids, key,
-                             unseen_w)
-        did = _lookup_sorted(tables.doc_u32, tables.doc_ids, cl, unseen_d)
-        s = table_flat[did * jnp.int32(v_x) + wid]
-        return jnp.where(s < tol, s, jnp.inf)
+        with device_scope("onix.words.bin"):
+            hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
+            cclass = rc // 100
+            key = (uri_p[uc] | host_p[hc] | ua_p[ac]
+                   | cclass
+                   | hbin.astype(jnp.int32) << _PROXY_HBIN_SHIFT)
+            valid = (rc >= 0) & (cclass < 8)
+            key = jnp.where(valid, key, jnp.int32(-1))
+        with device_scope("onix.words.lookup_word"):
+            wid = _lookup_sorted(tables.word_key_c, tables.word_ids, key,
+                                 unseen_w)
+        with device_scope("onix.words.lookup_doc"):
+            did = _lookup_sorted(tables.doc_u32, tables.doc_ids, cl,
+                                 unseen_d)
+        with device_scope("onix.score.gather"):
+            s = table_flat[did * jnp.int32(v_x) + wid]
+            return jnp.where(s < tol, s, jnp.inf)
 
     return scoring._scan_bottom_k(
         (client, uri_c, host_c, ua_c, respcode, hour), client.shape[0],
@@ -538,12 +563,14 @@ def proxy_stream_bottom_k(tables: ProxyDeviceTables, table_flat: jax.Array,
     `cols` may be raw numpy columns or a stage_proxy_cols dict."""
     if not cols.get("_staged"):
         cols = stage_proxy_cols(cols, edges)
-    return _proxy_stream_scan(
-        tables, table_flat, cols["uri_p"], cols["host_p"], cols["ua_p"],
-        cols["client_u32"], cols["uri_codes"], cols["host_codes"],
-        cols["ua_codes"], cols["respcode"], cols["hour"],
-        v_x=v_x, unseen_w=unseen_w, unseen_d=unseen_d, tol=tol,
-        max_results=max_results, chunk=chunk)
+    with telemetry.TRACER.span("scan.dispatch",
+                               events=cols["client_u32"].shape[0]):
+        return _proxy_stream_scan(
+            tables, table_flat, cols["uri_p"], cols["host_p"], cols["ua_p"],
+            cols["client_u32"], cols["uri_codes"], cols["host_codes"],
+            cols["ua_codes"], cols["respcode"], cols["hour"],
+            v_x=v_x, unseen_w=unseen_w, unseen_d=unseen_d, tol=tol,
+            max_results=max_results, chunk=chunk)
 
 
 # ---------------------------------------------------------------------------
@@ -834,9 +861,11 @@ def flow_stream_bottom_k(
     may be raw numpy columns or a stage_flow_cols dict."""
     if not cols.get("_staged"):
         cols = stage_flow_cols(cols)
-    return _flow_stream_scan(
-        tables, table_flat,
-        cols["sip_u32"], cols["dip_u32"], cols["sport"], cols["dport"],
-        cols["proto_id"], cols["hour"], cols["ibyt"], cols["ipkt"],
-        v_x=v_x, unseen_w=unseen_w, unseen_d=unseen_d, tol=tol,
-        max_results=max_results, chunk=chunk)
+    with telemetry.TRACER.span("scan.dispatch",
+                               events=cols["sip_u32"].shape[0]):
+        return _flow_stream_scan(
+            tables, table_flat,
+            cols["sip_u32"], cols["dip_u32"], cols["sport"], cols["dport"],
+            cols["proto_id"], cols["hour"], cols["ibyt"], cols["ipkt"],
+            v_x=v_x, unseen_w=unseen_w, unseen_d=unseen_d, tol=tol,
+            max_results=max_results, chunk=chunk)

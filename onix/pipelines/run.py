@@ -22,7 +22,8 @@ from onix.models.scoring import score_all, select_suspicious
 from onix.pipelines.corpus_build import CorpusBundle, build_corpus, event_scores
 from onix.pipelines.words import WORD_FNS
 from onix.store import Store, feedback_path, results_path
-from onix.utils.obs import Meter, RunLog, maybe_trace, trace_scope
+from onix.utils import telemetry
+from onix.utils.obs import Meter, RunLog, maybe_trace
 
 
 BENIGN_LABEL = 3   # the reference's severity scale: 1/2 = threat, 3 = benign
@@ -206,7 +207,7 @@ def run_scoring(cfg: OnixConfig, engine: str = "gibbs",
 
     with maybe_trace(), log.stage(
             "lda_fit", n_tokens=int(bundle.corpus.n_tokens)), \
-            trace_scope(f"onix.fit.{engine}"):
+            telemetry.TRACER.span("run.fit", engine=engine):
         fit = fit_engine(cfg, bundle, engine)
     for s, ll in fit["ll_history"]:
         log.emit("likelihood", sweep=int(s), ll=float(ll))
@@ -235,7 +236,7 @@ def run_scoring(cfg: OnixConfig, engine: str = "gibbs",
 
     # Score REAL tokens only (feedback duplicates are training-only).
     meter = Meter()
-    with log.stage("scoring"), trace_scope("onix.score"):
+    with log.stage("scoring"), telemetry.TRACER.span("run.score"):
         tok_scores = score_all(
             fit["theta"], fit["phi_wk"],
             bundle.corpus.doc_ids[:bundle.n_real_tokens],

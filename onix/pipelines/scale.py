@@ -34,6 +34,8 @@ from onix.pipelines.synth import SYNTH_ARRAYS
 from onix.pipelines.words import (dns_words_from_arrays,
                                   flow_words_from_arrays,
                                   proxy_words_from_arrays)
+from onix.utils import telemetry
+from onix.utils.obs import OccupancyClock
 
 _FLOW_COLS = ("sip_u32", "dip_u32", "sport", "dport", "proto_id", "hour",
               "ibyt", "ipkt")
@@ -376,7 +378,6 @@ def run_scale(n_events: int, n_hosts: int | None = None,
     # Resilience events this run tallied (retries, salvage skips,
     # injected faults, checkpoint digest mismatches) — empty on a clean
     # run, and the chaos harness's evidence on a faulted one.
-    from onix.utils import telemetry
     from onix.utils.obs import counters
     # r18: the telemetry view (span histograms + recorder tallies,
     # zeros included) — every scale manifest says what was observed
@@ -536,21 +537,35 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
     # remap must key on (the fitted table is sorted — a different
     # beast; build_flow_tables' contract).
     dev_tables = None
-    walls.setdefault("stream_words_map", 0.0)
+    # One clock holds the three stream walls: the spans below feed it
+    # (and so do the plain busy scopes around callees that open spans
+    # of their own), telemetry on or off; `_set_walls` writes its sums,
+    # on top of what earlier sessions paid, into `walls`.
+    clock = OccupancyClock()
+    stream_keys = ("stream_synth", "stream_words_map", "stream_score")
+    carried = {k: 0.0 for k in stream_keys}
+    carried["stream_words_map"] = walls.get("stream_words_map", 0.0)
+
+    def _set_walls():
+        for k in stream_keys:
+            walls[k] = carried[k] + clock.busy_s.get(k, 0.0)
+
+    _set_walls()
     if device_words and datatype != "flow":
         from onix.pipelines import device_words as dw
         # Timed into stream_words_map like the flow build: the O(V+D)
         # re-encode is pipeline work, identical accounting across
         # datatypes.
-        t_build = time.monotonic()
-        try:
-            dev_tables = (dw.build_dns_tables(bundle, fitted_edges)
-                          if datatype == "dns"
-                          else dw.build_proxy_tables(bundle, fitted_edges))
-        except ValueError as e:
-            print(f"device words unavailable ({e}); using the host path")
-            device_words = False
-        walls["stream_words_map"] += time.monotonic() - t_build
+        with clock.busy("stream_words_map"):
+            try:
+                dev_tables = (
+                    dw.build_dns_tables(bundle, fitted_edges)
+                    if datatype == "dns"
+                    else dw.build_proxy_tables(bundle, fitted_edges))
+            except ValueError as e:
+                print(f"device words unavailable ({e}); "
+                      "using the host path")
+                device_words = False
     info["words_mode"] = "device" if device_words else "host"
     # Streamed chunks plant a day-proportional share of anomalies, not
     # a full day's worth per chunk: the streamed part of the run plants
@@ -566,8 +581,6 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
     # two (VERDICT weak #3). stream_synth times the generator alone;
     # stream_words_map is the real pipeline work (word creation +
     # trained-id mapping) and joins the pipeline-only rate.
-    walls["stream_synth"] = 0.0
-    walls["stream_score"] = 0.0
     offset = 0
     c = 0
     prog = ckpt.load("stream") if ckpt is not None else None
@@ -580,35 +593,36 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
         all_idx.append(prog["idx"].astype(np.int64))
         all_scores.append(prog["scores"].astype(np.float32))
         planted.update(prog["planted"].tolist())
-        for k in ("stream_synth", "stream_words_map", "stream_score"):
-            walls[k] += float(prog[f"wall_{k}"])
+        for k in stream_keys:
+            carried[k] += float(prog[f"wall_{k}"])
         info["resumed_at_chunk"] = c
 
     def _save_progress():
+        _set_walls()
         if ckpt is None:
             return
-        ckpt.save(
-            "stream", c=np.int64(c),
-            idx=(np.concatenate(all_idx) if all_idx
-                 else np.zeros(0, np.int64)),
-            scores=(np.concatenate(all_scores) if all_scores
-                    else np.zeros(0, np.float32)),
-            planted=np.asarray(sorted(planted), np.int64),
-            **{f"wall_{k}": np.float64(walls[k]) for k in
-               ("stream_synth", "stream_words_map", "stream_score")})
-        if save_meta is not None:
-            save_meta()
+        with telemetry.TRACER.span("scan.checkpoint", chunk=c):
+            ckpt.save(
+                "stream", c=np.int64(c),
+                idx=(np.concatenate(all_idx) if all_idx
+                     else np.zeros(0, np.int64)),
+                scores=(np.concatenate(all_scores) if all_scores
+                        else np.zeros(0, np.float32)),
+                planted=np.asarray(sorted(planted), np.int64),
+                **{f"wall_{k}": np.float64(walls[k])
+                   for k in stream_keys})
+            if save_meta is not None:
+                save_meta()
 
     if device_words:
         from onix.pipelines import device_words as dw
 
     def _synth_chunk(ci: int, mi: int) -> dict:
-        t0 = time.monotonic()
-        cc = gen_arrays[datatype](mi, n_hosts=n_hosts,
-                                  n_anomalies=anomalies_per_chunk,
-                                  seed=seed + 1000 * ci)
-        walls["stream_synth"] += time.monotonic() - t0
-        return cc
+        with telemetry.TRACER.span("scan.synth", clock=clock,
+                                   clock_name="stream_synth", chunk=ci):
+            return gen_arrays[datatype](mi, n_hosts=n_hosts,
+                                        n_anomalies=anomalies_per_chunk,
+                                        seed=seed + 1000 * ci)
 
     def _stage_cols(cc: dict):
         """START one synthesized chunk's host→device transfer
@@ -616,13 +630,12 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
         staging block comment). Raises ValueError when the trained
         bundle cannot ride the compact keys (flow table build gates)."""
         nonlocal dev_tables
-        t0 = time.monotonic()
-        if dev_tables is None:      # flow: keyed on the caller proto order
-            dev_tables = dw.build_flow_tables(
-                bundle, fitted_edges, list(cc["proto_classes"]))
-        staged = dw.STAGE_FNS[datatype](cc, fitted_edges)
-        walls["stream_words_map"] += time.monotonic() - t0
-        return staged
+        # STAGE_FNS opens `scan.stage` (and `scan.h2d_put` per column).
+        with clock.busy("stream_words_map"):
+            if dev_tables is None:  # flow: keyed on the caller proto order
+                dev_tables = dw.build_flow_tables(
+                    bundle, fitted_edges, list(cc["proto_classes"]))
+            return dw.STAGE_FNS[datatype](cc, fitted_edges)
 
     def _stage_chunk(ci: int, mi: int):
         """Synthesize chunk ci and stage it. Returns (staged cols,
@@ -639,13 +652,11 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
         rows. No per-chunk unique sort: at 2x10^8 tokens/chunk the old
         unique-then-map path spent most of the 1B run's wall in these
         sorts (docs/SCALE_1B_r02.json)."""
-        t0 = time.monotonic()
-        wt = _words_from_cols(datatype, cols, edges=fitted_edges)
-        wid = bundle.word_ids_packed(wt.word_key, fill=unseen_w)
-        did = bundle.doc_ids_u32(wt.ip_u32, fill=unseen_d)
-        out = did * np.int32(v_x) + wid
-        walls["stream_words_map"] += time.monotonic() - t0
-        return out
+        with clock.busy("stream_words_map"):
+            wt = _words_from_cols(datatype, cols, edges=fitted_edges)
+            wid = bundle.word_ids_packed(wt.word_key, fill=unseen_w)
+            did = bundle.doc_ids_u32(wt.ip_u32, fill=unseen_d)
+            return did * np.int32(v_x) + wid
 
     def _fused_bottom_k(staged):
         if datatype == "flow":
@@ -672,12 +683,11 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
             # int32 throughout: the extended table is capped at 2^27
             # elements, so every flat index fits with room to spare —
             # int64 temporaries would double the chunk's memory.
-            t = time.monotonic()
-            d_ids = bundle.corpus.doc_ids[:bundle.n_real_tokens]
-            w_ids = bundle.corpus.word_ids[:bundle.n_real_tokens]
-            idx = (d_ids.astype(np.int32) * np.int32(v_x)
-                   + w_ids.astype(np.int32))
-            walls["stream_words_map"] += time.monotonic() - t
+            with clock.busy("stream_words_map"):
+                d_ids = bundle.corpus.doc_ids[:bundle.n_real_tokens]
+                w_ids = bundle.corpus.word_ids[:bundle.n_real_tokens]
+                idx = (d_ids.astype(np.int32) * np.int32(v_x)
+                       + w_ids.astype(np.int32))
         elif device_words:
             # Double-buffered device path: the raw columns ARE the
             # input — words+map+score+select run as one fused program
@@ -711,9 +721,9 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
             prefetched = None
             planted.update(planted_c)
             if staged is not None:
-                t = time.monotonic()
-                top = _fused_bottom_k(staged)     # async dispatch
-                walls["stream_score"] += time.monotonic() - t
+                # Async dispatch; *_stream_bottom_k opens `scan.dispatch`.
+                with clock.busy("stream_score"):
+                    top = _fused_bottom_k(staged)
                 del staged
                 if offset + m < n_events:
                     prefetched = (c + 1, *_stage_chunk(
@@ -727,27 +737,28 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
             idx = _host_idx(cols)
             del cols
 
-        t = time.monotonic()
-        if top is None:
-            if datatype == "flow":  # [src|dst] halves: fused pair-min path
-                top = scoring.table_pair_bottom_k_fast(
-                    table, jnp.asarray(idx[:m]), jnp.asarray(idx[m:]),
-                    table_b, tol=1.0, max_results=max_results)
-            else:                   # one client-IP token per event
-                top = scoring.table_bottom_k_fast(
-                    table, jnp.asarray(idx), table_b,
-                    tol=1.0, max_results=max_results)
-            idx = None
-        ti = np.asarray(top.indices)       # blocks on the fused scan
-        ts = np.asarray(top.scores)
-        keep = ti >= 0
-        all_idx.append(ti[keep] + offset)
-        all_scores.append(ts[keep])
-        walls["stream_score"] += time.monotonic() - t
+        with telemetry.TRACER.span("scan.fetch", clock=clock,
+                                   clock_name="stream_score", chunk=c):
+            if top is None:
+                if datatype == "flow":  # [src|dst] halves: pair-min path
+                    top = scoring.table_pair_bottom_k_fast(
+                        table, jnp.asarray(idx[:m]), jnp.asarray(idx[m:]),
+                        table_b, tol=1.0, max_results=max_results)
+                else:                   # one client-IP token per event
+                    top = scoring.table_bottom_k_fast(
+                        table, jnp.asarray(idx), table_b,
+                        tol=1.0, max_results=max_results)
+                idx = None
+            ti = np.asarray(top.indices)       # blocks on the fused scan
+            ts = np.asarray(top.scores)
+            keep = ti >= 0
+            all_idx.append(ti[keep] + offset)
+            all_scores.append(ts[keep])
         offset += m
         c += 1
         _save_progress()
 
+    _set_walls()
     scores = np.concatenate(all_scores)
     idxs = np.concatenate(all_idx)
     order = np.argsort(scores, kind="stable")[:max_results]

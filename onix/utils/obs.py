@@ -1,13 +1,13 @@
-"""Observability: profiler trace scopes, run event log, throughput meters.
+"""Observability: profiler trace collection, run event log, throughput meters.
 
 The reference has no purpose-built tracing or metrics (SURVEY.md §5.1,
 §5.5 — it leaned on the Spark web UI, YARN logs, and lda-c's stdout
 likelihood prints). onix makes the three judged observables first-class:
 
-- `trace_scope(name)` — jax.profiler annotation around the hot loops so
-  a TensorBoard/Perfetto trace of a scoring run shows named Gibbs-sweep
-  and scoring-scan spans; `start_trace(dir)` dumps a full trace when
-  ONIX_PROFILE_DIR (or the call) asks for one.
+- `maybe_trace(dir)` — dumps a full profiler trace when
+  ONIX_PROFILE_DIR (or the call) asks for one; every `telemetry.TRACER`
+  span lies in it as `onix.<name>` and every device op carries its
+  `onix.*` scope (docs/OBSERVABILITY.md).
 - `RunLog` — append-only JSONL event stream per run (stage boundaries,
   per-sweep likelihood, checkpoint saves, faults) next to the results.
 - `Meter` — wall-clock + items/sec for the events-scored/sec/chip
@@ -20,6 +20,7 @@ import contextlib
 import json
 import os
 import pathlib
+import re
 import threading
 import time
 
@@ -254,6 +255,30 @@ def enable_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
+def device_scope(name: str):
+    """`jax.named_scope(name)` for code under `jit`: the `onix.*` names
+    the profiler's trace shows on each device op (the scope path is the
+    op's `op_name`; the table of scopes is in docs/OBSERVABILITY.md).
+
+    A scope is metadata, and JAX's persistent compile cache leaves
+    metadata out of its key by default - a process then loads whatever
+    executable the cache holds for the same arithmetic, compiled from
+    other source, and its trace shows that source's names or none (seen
+    on the chip in PR 26: a run that followed the parent commit's on
+    one cache traced every op unscoped). So the first scope a process
+    traces also makes the metadata part of the key: the executable that
+    runs is the one compiled from this source. The price is a compile
+    where only a line number moved; file names enter the key relative
+    to the checkout, so a checkout that moves still hits."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if not jax.config.jax_hlo_source_file_canonicalization_regex:
+        root = pathlib.Path(__file__).resolve().parents[2]
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          re.escape(str(root)) + "/")
+    return jax.named_scope(name)
+
+
 def device_summary() -> dict:
     """The default devices as JAX reports them — what every script,
     bench.py and chip_smoke.py print and stamp beside their numbers."""
@@ -279,15 +304,6 @@ def device_peak_bytes_in_use() -> list[int | None]:
     import jax
     return [(d.memory_stats() or {}).get("peak_bytes_in_use")
             for d in jax.devices()]
-
-
-@contextlib.contextmanager
-def trace_scope(name: str):
-    """Named span in the device profile; near-zero cost when no trace is
-    being collected."""
-    import jax.profiler
-    with jax.profiler.TraceAnnotation(name):
-        yield
 
 
 @contextlib.contextmanager

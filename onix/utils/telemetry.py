@@ -21,10 +21,13 @@ bit-identical in tier-1, tests/test_telemetry.py):
   histogram observe. Spans FEED `OccupancyClock` accounting when given
   a clock (`span(..., clock=, clock_name=)` enters `clock.busy`
   unconditionally — occupancy numbers never depend on telemetry being
-  on) instead of duplicating it. Literal span names are a declared
-  contract: `SPAN_REGISTRY` below, machine-checked by the `spans`
-  analysis pass (python -m onix.analysis) exactly like counter
-  namespaces and env vars.
+  on) instead of duplicating it. Every recorded span is also written
+  into the profiler's trace as `onix.<name>`
+  (`jax.profiler.TraceAnnotation`), so program spans and device ops
+  share one clock in a collected xplane (ONIX_PROFILE_DIR). Literal
+  span names are a declared contract: `SPAN_REGISTRY` below,
+  machine-checked by the `spans` analysis pass (python -m
+  onix.analysis) exactly like counter namespaces and env vars.
 
 * **Histograms** (`Histogram`, `HistogramRegistry`) — log-bucketed
   (geometric buckets, growth `Histogram.GROWTH`): `observe(v)` lands v
@@ -77,6 +80,7 @@ import math
 import os
 import pathlib
 import re
+import sys
 import threading
 import time
 import zlib
@@ -101,10 +105,26 @@ SPAN_REGISTRY: dict[str, str] = {
     "campaign.score": "campaign orchestrator: one datatype's scoring stage",
     "daily.day": "daily supervisor: one simulated day end-to-end (campaign + model save + ledger write)",
     "daily.refit": "daily supervisor: one datatype's warm/cold refit decision — warm fit, drift check, and any drift-forced cold refit",
+    "fit.checkpoint": "run_fit_segments: one checkpoint save at a superstep boundary (state to the host, then to disk)",
+    "fit.device_corpus": "ShardedGibbsLDA.fit: the blocked corpus to the device(s) (device_corpus)",
+    "fit.estimates": "ShardedGibbsLDA.fit: final counts to the host and theta/phi in global order (estimates)",
+    "fit.init_state": "ShardedGibbsLDA.fit: the chain's first state, drawn on the host or restored from a checkpoint, and its transfer",
+    "fit.notify": "run_fit_segments: the caller's per-boundary callback",
+    "fit.prepare": "ShardedGibbsLDA.fit: host layout of the corpus into shard blocks (prepare)",
+    "fit.superstep": "run_fit_segments: the dispatch of one fused superstep program (returns with the device still running)",
+    "fit.wait": "run_fit_segments: float(ll) at a superstep boundary, the host blocked on the device",
     "fleet.day": "fleet supervisor: one simulated day across every executing tenant (prepare, fleet refit, per-tenant accepts)",
     "fleet.refit": "fleet supervisor: the day's fused fleet refit — stacked warm/cold class dispatches plus the drift-gated cold second pass",
     "host.fit": "hostfabric coordinator: one multi-host fit end-to-end (spawn, monitor, deaths + restarts, result assembly)",
     "host.superstep": "hostfabric worker: one fused superstep segment dispatch, collective deadline + retry wrapper included",
+    "run.fit": "pipelines/run.py: the day's model fit, whichever engine",
+    "run.score": "pipelines/run.py: scoring and selection of the day's events",
+    "scan.checkpoint": "scale._stream_score: one chunk's progress checkpoint (_save_progress)",
+    "scan.dispatch": "device_words.*_stream_bottom_k: the call of the jitted words+score+select scan for one chunk (async dispatch)",
+    "scan.fetch": "scale._stream_score: one chunk's winners to the host, blocked on the scan",
+    "scan.h2d_put": "device_words._put: jax.device_put of one staged column (the host's side of the copy; child of scan.stage)",
+    "scan.stage": "device_words.stage_*_cols: one chunk's host casts, per-unique string features and the start of its copies",
+    "scan.synth": "scale._stream_score: the synthetic generator for one streamed chunk",
     "serve.queue_wait": "BankService.submit: admitted-to-scoring-start wall (the admission queue wait)",
     "serve.request": "oa/serve.py /score: one HTTP request, receipt to response",
     "serve.score": "BankService.score body: cache lookups + bank dispatch for one batch",
@@ -425,6 +445,19 @@ def current_trace_id() -> str | None:
     return ctx.trace_id if ctx is not None else None
 
 
+def _annotation(name: str, **meta):
+    """The span as `onix.<name>` in the profiler's trace, on the clock
+    the device ops are on (`jax.profiler.TraceAnnotation`; near-free
+    while no trace is being collected). A process that never imported
+    jax has no profiler to write into and gets a null context - this
+    module must not be what imports jax (the analyzer and the fabric
+    coordinator run without it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation("onix." + name, **meta)
+
+
 class Tracer:
     """The span collector. `enabled=False` or `sample=0.0` turns every
     span into a context manager that only runs its optional clock —
@@ -506,7 +539,8 @@ class Tracer:
                          dur_s=0.0, attrs=attrs)
         err: str | None = None
         try:
-            yield rec
+            with _annotation(name):
+                yield rec
         except BaseException as e:
             err = repr(e)
             raise
@@ -527,6 +561,10 @@ class Tracer:
         ctx = _TRACE.get()
         if ctx is None or not ctx.sampled:
             return
+        # The wall was measured before this call, so the trace gets a
+        # mark at the close that carries the duration.
+        with _annotation(name, dur_s=dur_s):
+            pass
         self._close(SpanRecord(
             name=name, trace_id=ctx.trace_id, span_id=next(_span_seq),
             parent_id=_PARENT.get(None), t0=time.perf_counter() - dur_s,

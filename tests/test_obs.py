@@ -116,13 +116,21 @@ def test_manifest_reports_throughput_and_runlog(tmp_path):
 def test_maybe_trace_collects_profile(tmp_path):
     import jax.numpy as jnp
 
-    from onix.utils.obs import maybe_trace, trace_scope
+    from jax.profiler import ProfileData
+
+    from onix.utils import telemetry
+    from onix.utils.obs import maybe_trace
     with maybe_trace(str(tmp_path / "prof")) as target:
         assert target is not None
-        with trace_scope("onix.test"):
+        with telemetry.TRACER.span("run.score"):
             jnp.ones((8, 8)).sum().block_until_ready()
-    # a trace dump appeared
-    assert any((tmp_path / "prof").rglob("*"))
+    # a trace dump appeared, and the span lies in its host plane under
+    # the profiler's clock as `onix.<name>`
+    xplane, = (tmp_path / "prof").rglob("*.xplane.pb")
+    host = [p for p in ProfileData.from_file(str(xplane)).planes
+            if p.name.startswith("/host:CPU")]
+    assert any(e.name == "onix.run.score" and e.duration_ns > 0
+               for p in host for line in p.lines for e in line.events)
 
 
 def test_roofline_math_and_cpu_peak():
