@@ -271,6 +271,14 @@ def phase3_scale(out_dir: pathlib.Path, n_events: int = SCALE_EVENTS,
           f"mesh {manifest['mesh']} on {len(jax.devices())} device(s)")
     check(manifest["words_mode"] == "device",
           f"words_mode {manifest['words_mode']!r}")
+    # The form each sorted look-up took, as `build_flow_tables` said it
+    # at the build (docs/OBSERVABILITY.md): none may be a search.
+    from onix.utils import telemetry
+    forms = [{k: s.attrs[k] for k in ("datatype", "word", "doc")}
+             for s in telemetry.TRACER.spans() if s.name == "scan.tables"]
+    check(bool(forms) and all(f[k] in ("compare", "join")
+                              for f in forms for k in ("word", "doc")),
+          f"look-up forms {forms}")
     rng = manifest["selected_score_range"]
     check(rng is not None and all(np.isfinite(rng)),
           f"selected_score_range {rng}")
@@ -282,7 +290,7 @@ def phase3_scale(out_dir: pathlib.Path, n_events: int = SCALE_EVENTS,
             "planted_anomalies", "planted_in_bottom_k",
             "selected_score_range", "walls_seconds", "selection",
             "device_peak_bytes", "dp1_fast_path")
-    return {k: manifest[k] for k in keep}
+    return {**{k: manifest[k] for k in keep}, "lookup_forms": forms}
 
 
 def run(out_dir: pathlib.Path, *, require_tpu: bool = True) -> dict:

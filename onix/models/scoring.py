@@ -66,18 +66,19 @@ def _finalize_topk(scores: jax.Array, indices: jax.Array) -> TopK:
     return TopK(scores=scores, indices=indices)
 
 
-def _chunked_cols(arrays: tuple, n: int, chunk: int):
-    """Pad arrays to a chunk multiple and reshape to [n_chunks, chunk]
-    scan columns. Shapes are static under jit, so the pad amount is
-    compile-time."""
+def _padded_cols(arrays: tuple, n: int, chunk: int):
+    """Pad arrays to a chunk multiple; the scan slices chunk `ci` out
+    of each at `ci * chunk`. Shapes are static under jit, so the pad
+    amount is compile-time. (Not reshaped to [n_chunks, chunk] scan
+    columns: on the TPU that is a tiled relayout of every column, done
+    by loops the compiler makes and names nothing - 2% of a fused-scan
+    chunk once the look-ups were cheap; PERF.md section 6, PR 27.)"""
     chunk = min(chunk, max(n, 1))
     pad = (-n) % chunk
     if pad:
         arrays = tuple(jnp.pad(a, (0, pad)) for a in arrays)
-    n_chunks = (n + pad) // chunk
-    cols = tuple(a.reshape(n_chunks, -1) for a in arrays)
     base = jnp.arange(chunk, dtype=jnp.int32)
-    return cols, base, n_chunks, chunk
+    return arrays, base, (n + pad) // chunk, chunk
 
 
 def _empty_topk(max_results: int) -> TopK:
@@ -120,11 +121,13 @@ def _scan_bottom_k(arrays: tuple, n: int, score_chunk, *,
     # `onix.select` names everything here but `score_chunk` (device
     # scopes: docs/OBSERVABILITY.md); the caller names its own scoring.
     with device_scope("onix.select"):
-        cols, base, n_chunks, chunk = _chunked_cols(arrays, n, chunk)
+        cols, base, n_chunks, chunk = _padded_cols(arrays, n, chunk)
 
-    def step(carry, xs):
+    def step(carry, ci):
         best_s, best_i = carry
-        *cs, ci = xs
+        with device_scope("onix.select"):
+            cs = [jax.lax.dynamic_slice_in_dim(a, ci * chunk, chunk)
+                  for a in cols]
         scored = score_chunk(*cs)
         with device_scope("onix.select"):
             idx = ci * chunk + base
@@ -150,7 +153,7 @@ def _scan_bottom_k(arrays: tuple, n: int, score_chunk, *,
 
     (out_s, out_i), _ = jax.lax.scan(
         step, tuple(_empty_topk(max_results)),
-        (*cols, jnp.arange(n_chunks, dtype=jnp.int32)))
+        jnp.arange(n_chunks, dtype=jnp.int32))
     with device_scope("onix.select"):
         return _finalize_topk(out_s, out_i)
 

@@ -27,6 +27,12 @@ compare winners against). Two supporting pieces live here too:
   splitmix64 bucketing (32-bit-limb arithmetic, bit-identical to the
   host hash) instead of a vocab lookup.
 
+How ids are found: `_lookup_sorted` compares a short table (the words)
+whole against every key, and joins a long one (the addresses) to the
+keys by sorting both together; neither gathers. Not by binary search: on
+a v5e a gather costs 7-9 ns per element whatever it fetches, and
+`jnp.searchsorted` pays one per step, 18 to an address (PERF.md).
+
 Why a compact key: the host path packs words into 43-bit int64 keys
 (words.FLOW_SPEC). JAX runs x64-disabled, so the device path re-encodes
 the TRAINED vocabulary once on the host into an equivalent <=31-bit
@@ -97,6 +103,17 @@ class FlowDeviceTables(NamedTuple):
     proto_remap: jax.Array    # int32 [n_proto_classes] caller id -> compact
 
 
+def _tables_span(datatype: str, bundle):
+    """The `scan.tables` span around one `build_*_tables`: how long the
+    re-encoding and its copies took, and which form each of the two
+    look-ups takes for this model (`lookup_form`), so that an operator
+    and chip_smoke.py can see that none is a binary search."""
+    n_w, n_d = len(bundle.word_key_sorted), len(bundle.doc_u32_sorted)
+    return telemetry.TRACER.span(
+        "scan.tables", datatype=datatype, words=n_w, docs=n_d,
+        word=lookup_form(n_w), doc=lookup_form(n_d))
+
+
 def build_flow_tables(bundle, edges: dict,
                       proto_classes: list[str]) -> FlowDeviceTables:
     """Re-encode the trained bundle once per run (host side, O(V+D)).
@@ -104,39 +121,43 @@ def build_flow_tables(bundle, edges: dict,
     `edges` are the FITTED bin edges/proto table archived by the
     training corpus build; `proto_classes` is the caller's proto id
     order for the streamed columns (synth/ingest contract)."""
-    fields = FLOW_SPEC.unpack(np.asarray(bundle.word_key_sorted))
-    for name in ("pbin", "bbin", "hbin"):
-        if fields[name].max(initial=0) >= (1 << _BIN_BITS):
+    with _tables_span("flow", bundle):
+        fields = FLOW_SPEC.unpack(np.asarray(bundle.word_key_sorted))
+        for name in ("pbin", "bbin", "hbin"):
+            if fields[name].max(initial=0) >= (1 << _BIN_BITS):
+                raise ValueError(
+                    "n_bins too large for the compact key; "
+                    "raise _BIN_BITS")
+        table = np.asarray(edges["proto_classes"], dtype=object)
+        if len(table) >= _COMPACT_UNK:
             raise ValueError(
-                "n_bins too large for the compact key; raise _BIN_BITS")
-    table = np.asarray(edges["proto_classes"], dtype=object)
-    if len(table) >= _COMPACT_UNK:
-        raise ValueError("too many protocol classes for the compact key")
-    proto = np.where(fields["proto"] == _PROTO_UNK, _COMPACT_UNK,
-                     np.minimum(fields["proto"], _COMPACT_UNK))
-    key_c = (fields["pclass"] << _PCLASS_SHIFT
-             | proto << _PROTO_SHIFT
-             | fields["hbin"] << (2 * _BIN_BITS)
-             | fields["bbin"] << _BIN_BITS
-             | fields["pbin"]).astype(np.int64)
-    assert key_c.max(initial=0) < 2 ** 31, "compact key overflows int32"
-    order = np.argsort(key_c, kind="stable")
-    # Caller proto id -> compact code (the shared remap rule: absent
-    # from the fitted table -> UNK).
-    from onix.pipelines.words import proto_remap_codes
-    remap = proto_remap_codes(table, proto_classes,
-                              _COMPACT_UNK).astype(np.int32)
-    return FlowDeviceTables(
-        word_key_c=jnp.asarray(key_c[order].astype(np.int32)),
-        word_ids=jnp.asarray(
-            np.asarray(bundle.word_key_ids)[order].astype(np.int32)),
-        doc_u32=jnp.asarray(np.asarray(bundle.doc_u32_sorted)),
-        doc_ids=jnp.asarray(np.asarray(bundle.doc_u32_ids).astype(np.int32)),
-        hour_edges=_edges1d(edges, "hour"),
-        byt_edges=_edges1d(edges, "log_ibyt"),
-        pkt_edges=_edges1d(edges, "log_ipkt"),
-        proto_remap=jnp.asarray(remap),
-    )
+                "too many protocol classes for the compact key")
+        proto = np.where(fields["proto"] == _PROTO_UNK, _COMPACT_UNK,
+                         np.minimum(fields["proto"], _COMPACT_UNK))
+        key_c = (fields["pclass"] << _PCLASS_SHIFT
+                 | proto << _PROTO_SHIFT
+                 | fields["hbin"] << (2 * _BIN_BITS)
+                 | fields["bbin"] << _BIN_BITS
+                 | fields["pbin"]).astype(np.int64)
+        assert key_c.max(initial=0) < 2 ** 31, "compact key overflows int32"
+        order = np.argsort(key_c, kind="stable")
+        # Caller proto id -> compact code (the shared remap rule: absent
+        # from the fitted table -> UNK).
+        from onix.pipelines.words import proto_remap_codes
+        remap = proto_remap_codes(table, proto_classes,
+                                  _COMPACT_UNK).astype(np.int32)
+        return FlowDeviceTables(
+            word_key_c=jnp.asarray(key_c[order].astype(np.int32)),
+            word_ids=jnp.asarray(
+                np.asarray(bundle.word_key_ids)[order].astype(np.int32)),
+            doc_u32=jnp.asarray(np.asarray(bundle.doc_u32_sorted)),
+            doc_ids=jnp.asarray(
+                np.asarray(bundle.doc_u32_ids).astype(np.int32)),
+            hour_edges=_edges1d(edges, "hour"),
+            byt_edges=_edges1d(edges, "log_ibyt"),
+            pkt_edges=_edges1d(edges, "log_ipkt"),
+            proto_remap=jnp.asarray(remap),
+        )
 
 
 def _edges1d(edges: dict, name: str) -> "jnp.ndarray":
@@ -152,14 +173,74 @@ def _edges1d(edges: dict, name: str) -> "jnp.ndarray":
     return jnp.asarray(e)
 
 
+# A table of at most this many entries is compared whole against every
+# key; a longer one is joined to the keys by sorting. On a v5e, inside
+# the fused scan, the compare of 2^21 keys takes 1.6 ms at 349 entries
+# and grows with the table; the join takes 9.3 ms whatever the table
+# holds, 13.5 ms with the word table joined too (PERF.md section 6,
+# PR 27).
+_COMPARE_MAX = 1024
+
+
+def lookup_form(n_table: int) -> str:
+    """How `_lookup_sorted` answers for a table of `n_table` entries:
+    "compare" or "join". The table's length decides, which is static
+    under jit; there is no binary search to fall back to."""
+    return "compare" if n_table <= _COMPARE_MAX else "join"
+
+
+def _running_sum(x: jax.Array) -> jax.Array:
+    """`jnp.cumsum` of a long 1-D int32 array, in rows of 128 lanes:
+    sums within the rows, the rows' totals summed the same way, added
+    back. These are the ops the TPU compiler makes of `jnp.cumsum`
+    itself; written out because JAX lowers `cumsum` there through a
+    shared function whose ops carry no caller's scope, so the trace
+    booked a look-up's sum to no one (seen on the chip, PR 27)."""
+    n = x.shape[0]
+    if n <= 128:
+        return jax.lax.reduce_window(x, 0, jax.lax.add, (n,), (1,),
+                                     ((n - 1, 0),))
+    rows = -(-n // 128)
+    within = jax.lax.reduce_window(
+        jnp.pad(x, (0, rows * 128 - n)).reshape(rows, 128), 0, jax.lax.add,
+        (1, 128), (1, 1), ((0, 0), (127, 0)))
+    total = within[:, -1]
+    return (within + (_running_sum(total) - total)[:, None]).reshape(-1)[:n]
+
+
 def _lookup_sorted(table: jax.Array, ids: jax.Array, keys: jax.Array,
                    fill: int) -> jax.Array:
-    """ids[searchsorted(table, keys)] where the hit is exact, else fill
-    — the device rendering of CorpusBundle's sorted-table lookups."""
-    pos = jnp.searchsorted(table, keys)
-    pos_c = jnp.clip(pos, 0, table.shape[0] - 1)
-    hit = table[pos_c] == keys
-    return jnp.where(hit, ids[pos_c], jnp.int32(fill))
+    """ids[p] where table[p] == keys, else fill — the device rendering
+    of CorpusBundle's sorted-table lookups. `table` ascends; of equal
+    table keys the first answers, as `searchsorted(side="left")` has
+    it. A constant number of passes and no gather of the block (module
+    docstring)."""
+    assert table.dtype == keys.dtype, (table.dtype, keys.dtype)
+    d, n = table.shape[0], keys.shape[0]
+    fill = jnp.int32(fill)
+    first = jnp.concatenate([jnp.ones_like(table[:1], bool),
+                             table[1:] != table[:-1]])
+    # What a hit adds to `fill`. int32 wraps, and wraps back.
+    gain = jnp.where(first, ids - fill, 0)
+    if lookup_form(d) == "compare":
+        eq = keys[:, None] == table[None, :]        # at most one gains
+        return fill + jnp.sum(jnp.where(eq, gain[None, :], 0), axis=1)
+    # Sort-merge join. Every table key k stands twice: at k it brings
+    # its gain, at k + 1 it takes it back, so the running sum over the
+    # entries at or below a key is that key's answer less `fill`. One
+    # sort of entries and keys together (entries first where they tie),
+    # one running sum, one sort back into the keys' order.
+    top = table == jnp.array(jnp.iinfo(table.dtype).max, table.dtype)
+    k = jnp.concatenate([jnp.where(top, table, table + 1), table, keys])
+    change = jnp.concatenate([jnp.where(top, 0, -gain), gain,
+                              jnp.zeros((n,), jnp.int32)])
+    pos = jax.lax.iota(jnp.int32, 2 * d + n)
+    # `pos` makes either order total: no stable sort's extra operand.
+    _, pos, change = jax.lax.sort((k, pos, change), num_keys=2,
+                                  is_stable=False)
+    _, out = jax.lax.sort((pos, fill + _running_sum(change)), num_keys=1,
+                          is_stable=False)
+    return out[2 * d:]
 
 
 def _flow_flat_idx(t: FlowDeviceTables, v_x: int, unseen_w: int,
@@ -259,32 +340,35 @@ class DnsDeviceTables(NamedTuple):
 def build_dns_tables(bundle, edges: dict) -> DnsDeviceTables:
     from onix.pipelines.words import DNS_SPEC
 
-    fields = DNS_SPEC.unpack(np.asarray(bundle.word_key_sorted))
-    if fields["qtype"].max(initial=0) >= 256:
-        raise ValueError("trained qtype exceeds the compact key range")
-    if fields["rcode"].max(initial=0) >= 16:
-        raise ValueError("trained rcode exceeds the compact key range")
-    for name in ("flbin", "hbin", "ebin", "slbin", "nlabels"):
-        if fields[name].max(initial=0) >= 8:
-            raise ValueError(f"trained {name} exceeds the compact key range")
-    key_c = (fields["flbin"]
-             | fields["hbin"] << _DNS_HBIN_SHIFT
-             | fields["ebin"] << _DNS_EBIN_SHIFT
-             | fields["slbin"] << _DNS_SLBIN_SHIFT
-             | fields["nlabels"] << _DNS_NLABELS_SHIFT
-             | fields["qtype"] << _DNS_QTYPE_SHIFT
-             | fields["rcode"] << _DNS_RCODE_SHIFT
-             | fields["tld"] << _DNS_TLD_SHIFT).astype(np.int64)
-    order = np.argsort(key_c, kind="stable")
-    return DnsDeviceTables(
-        word_key_c=jnp.asarray(key_c[order].astype(np.int32)),
-        word_ids=jnp.asarray(
-            np.asarray(bundle.word_key_ids)[order].astype(np.int32)),
-        doc_u32=jnp.asarray(np.asarray(bundle.doc_u32_sorted)),
-        doc_ids=jnp.asarray(np.asarray(bundle.doc_u32_ids).astype(np.int32)),
-        hour_edges=_edges1d(edges, "hour"),
-        flen_edges=_edges1d(edges, "frame_len"),
-    )
+    with _tables_span("dns", bundle):
+        fields = DNS_SPEC.unpack(np.asarray(bundle.word_key_sorted))
+        if fields["qtype"].max(initial=0) >= 256:
+            raise ValueError("trained qtype exceeds the compact key range")
+        if fields["rcode"].max(initial=0) >= 16:
+            raise ValueError("trained rcode exceeds the compact key range")
+        for name in ("flbin", "hbin", "ebin", "slbin", "nlabels"):
+            if fields[name].max(initial=0) >= 8:
+                raise ValueError(
+                    f"trained {name} exceeds the compact key range")
+        key_c = (fields["flbin"]
+                 | fields["hbin"] << _DNS_HBIN_SHIFT
+                 | fields["ebin"] << _DNS_EBIN_SHIFT
+                 | fields["slbin"] << _DNS_SLBIN_SHIFT
+                 | fields["nlabels"] << _DNS_NLABELS_SHIFT
+                 | fields["qtype"] << _DNS_QTYPE_SHIFT
+                 | fields["rcode"] << _DNS_RCODE_SHIFT
+                 | fields["tld"] << _DNS_TLD_SHIFT).astype(np.int64)
+        order = np.argsort(key_c, kind="stable")
+        return DnsDeviceTables(
+            word_key_c=jnp.asarray(key_c[order].astype(np.int32)),
+            word_ids=jnp.asarray(
+                np.asarray(bundle.word_key_ids)[order].astype(np.int32)),
+            doc_u32=jnp.asarray(np.asarray(bundle.doc_u32_sorted)),
+            doc_ids=jnp.asarray(
+                np.asarray(bundle.doc_u32_ids).astype(np.int32)),
+            hour_edges=_edges1d(edges, "hour"),
+            flen_edges=_edges1d(edges, "frame_len"),
+        )
 
 
 def _pad_pow2(a: np.ndarray) -> np.ndarray:
@@ -469,34 +553,37 @@ class ProxyDeviceTables(NamedTuple):
 def build_proxy_tables(bundle, edges: dict) -> ProxyDeviceTables:
     from onix.pipelines.words import _UA_RARE, PROXY_SPEC
 
-    fields = PROXY_SPEC.unpack(np.asarray(bundle.word_key_sorted))
-    if len(edges.get("ua_common", ())) >= _PROXY_UA_RARE_C:
-        raise ValueError("too many common user agents for the compact key")
-    ua = fields["ua"]
-    bad_ua = (ua >= len(edges.get("ua_common", ()))) & (ua != _UA_RARE)
-    if bad_ua.any():
-        raise ValueError("trained ua code outside the fitted common table")
-    ua_c = np.where(ua == _UA_RARE, _PROXY_UA_RARE_C, ua)
-    if fields["cclass"].max(initial=0) >= 8:
-        raise ValueError("trained cclass exceeds the compact key range")
-    for name in ("hbin", "uebin", "ulbin"):
-        if fields[name].max(initial=0) >= 8:
-            raise ValueError(f"trained {name} exceeds the compact key range")
-    key_c = (fields["cclass"]
-             | fields["hbin"] << _PROXY_HBIN_SHIFT
-             | fields["uebin"] << _PROXY_UEBIN_SHIFT
-             | fields["ulbin"] << _PROXY_ULBIN_SHIFT
-             | fields["hostip"] << _PROXY_HOSTIP_SHIFT
-             | ua_c << _PROXY_UA_SHIFT).astype(np.int64)
-    order = np.argsort(key_c, kind="stable")
-    return ProxyDeviceTables(
-        word_key_c=jnp.asarray(key_c[order].astype(np.int32)),
-        word_ids=jnp.asarray(
-            np.asarray(bundle.word_key_ids)[order].astype(np.int32)),
-        doc_u32=jnp.asarray(np.asarray(bundle.doc_u32_sorted)),
-        doc_ids=jnp.asarray(np.asarray(bundle.doc_u32_ids).astype(np.int32)),
-        hour_edges=_edges1d(edges, "hour"),
-    )
+    with _tables_span("proxy", bundle):
+        fields = PROXY_SPEC.unpack(np.asarray(bundle.word_key_sorted))
+        if len(edges.get("ua_common", ())) >= _PROXY_UA_RARE_C:
+            raise ValueError("too many common user agents for the compact key")
+        ua = fields["ua"]
+        bad_ua = (ua >= len(edges.get("ua_common", ()))) & (ua != _UA_RARE)
+        if bad_ua.any():
+            raise ValueError("trained ua code outside the fitted common table")
+        ua_c = np.where(ua == _UA_RARE, _PROXY_UA_RARE_C, ua)
+        if fields["cclass"].max(initial=0) >= 8:
+            raise ValueError("trained cclass exceeds the compact key range")
+        for name in ("hbin", "uebin", "ulbin"):
+            if fields[name].max(initial=0) >= 8:
+                raise ValueError(
+                    f"trained {name} exceeds the compact key range")
+        key_c = (fields["cclass"]
+                 | fields["hbin"] << _PROXY_HBIN_SHIFT
+                 | fields["uebin"] << _PROXY_UEBIN_SHIFT
+                 | fields["ulbin"] << _PROXY_ULBIN_SHIFT
+                 | fields["hostip"] << _PROXY_HOSTIP_SHIFT
+                 | ua_c << _PROXY_UA_SHIFT).astype(np.int64)
+        order = np.argsort(key_c, kind="stable")
+        return ProxyDeviceTables(
+            word_key_c=jnp.asarray(key_c[order].astype(np.int32)),
+            word_ids=jnp.asarray(
+                np.asarray(bundle.word_key_ids)[order].astype(np.int32)),
+            doc_u32=jnp.asarray(np.asarray(bundle.doc_u32_sorted)),
+            doc_ids=jnp.asarray(
+                np.asarray(bundle.doc_u32_ids).astype(np.int32)),
+            hour_edges=_edges1d(edges, "hour"),
+        )
 
 
 def proxy_partial_keys(uris: np.ndarray, hosts: np.ndarray,

@@ -125,6 +125,7 @@ SPAN_REGISTRY: dict[str, str] = {
     "scan.h2d_put": "device_words._put: jax.device_put of one staged column (the host's side of the copy; child of scan.stage)",
     "scan.stage": "device_words.stage_*_cols: one chunk's host casts, per-unique string features and the start of its copies",
     "scan.synth": "scale._stream_score: the synthetic generator for one streamed chunk",
+    "scan.tables": "device_words.build_*_tables: the trained tables re-encoded and copied for the device; its attributes say which form each look-up takes for them (word, doc: compare or join)",
     "serve.queue_wait": "BankService.submit: admitted-to-scoring-start wall (the admission queue wait)",
     "serve.request": "oa/serve.py /score: one HTTP request, receipt to response",
     "serve.score": "BankService.score body: cache lookups + bank dispatch for one batch",

@@ -9,13 +9,14 @@ stream selection returns the same winners as the host-mapped scan.
 
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from onix.models import scoring
 from onix.pipelines import device_words as dw
-from onix.pipelines.corpus_build import build_corpus
+from onix.pipelines.corpus_build import _sorted_table_lookup, build_corpus
 from onix.pipelines.scale import _words_from_cols
 from onix.pipelines.synth import SYNTH_ARRAYS
 
@@ -355,3 +356,136 @@ def test_scale_flow_table_build_failure_degrades_to_host(monkeypatch):
     m = scale.run_scale(20_000, train_events=10_000, n_sweeps=6, seed=5)
     assert m["words_mode"] == "host"
     assert m["planted_in_bottom_k"] > 0
+
+
+# ---------------------------------------------------------------------------
+# _lookup_sorted (ISSUE 27): exact against numpy's search in every
+# corner, in both of its forms, and no loop under the look-up scopes.
+# ---------------------------------------------------------------------------
+
+
+def _lookup_case(dtype, n_table, scenario):
+    """(table, ids, keys, fill) of one corner. Tables ascend; ids are
+    any int32 at all, so a right answer is no accident of small ids."""
+    rng = np.random.default_rng([n_table, len(scenario)])
+    lo, hi = int(np.iinfo(dtype).min), int(np.iinfo(dtype).max)
+    n_keys = n_table // 2 if scenario == "short_block" else 3000
+
+    def draw(low, high, size):
+        return rng.integers(low, high, size=size, dtype=np.int64,
+                            endpoint=True)
+
+    if scenario == "extremes":      # the type's greatest and least key
+        ends = [hi, lo, -1 if lo < 0 else 0][:n_table]
+        table = np.unique(np.concatenate(
+            [ends, draw(lo, hi, n_table - len(ends))]))
+    else:                           # room below the first, above the last
+        table = np.unique(draw(lo + 1000, hi - 1000, n_table + n_table // 8
+                               + 1))[:n_table]
+    if scenario == "dup_table":     # pairs of equals
+        table = np.repeat(table[::2], 2)[:len(table)]
+    ids = draw(-2 ** 31, 2 ** 31 - 1, len(table)).astype(np.int32)
+    present = table[draw(0, len(table) - 1, n_keys)]
+    absent = np.setdiff1d(draw(lo, hi, 2 * n_keys + 8), table)[:n_keys]
+    if scenario == "all_present":
+        keys = present
+    elif scenario == "all_absent":
+        keys = absent
+    else:
+        corners = np.array(
+            [lo, hi, 0, -1 if lo < 0 else hi, table[0], table[-1],
+             max(table[0] - 1, lo), min(table[-1] + 1, hi),
+             table[len(table) // 2], table[len(table) // 2]] * 3)
+        keys = np.where(rng.random(n_keys) < 0.6, present, absent)
+        keys[:len(corners)] = corners[:n_keys]     # repeated keys too
+    fill = {"mixed": len(table), "extremes": -1, "all_absent": -2 ** 31,
+            "all_present": 2 ** 31 - 1, "short_block": 0,
+            "dup_table": -7}[scenario]
+    return table.astype(dtype), ids, keys.astype(dtype), fill
+
+
+@pytest.mark.parametrize("scenario", ["mixed", "extremes", "all_absent",
+                                      "all_present", "short_block",
+                                      "dup_table"])
+@pytest.mark.parametrize("n_table,form", [
+    (1, "compare"), (1, "join"), (2, "compare"), (2, "join"),
+    (349, "compare"), (349, "join"), (200_023, "join")])
+@pytest.mark.parametrize("dtype", [np.uint32, np.int32])
+def test_lookup_sorted_is_exact(dtype, n_table, form, scenario,
+                                monkeypatch):
+    # The product's lengths take their own form; the small tables are
+    # also driven through the other one.
+    if dw.lookup_form(n_table) != form:
+        monkeypatch.setattr(dw, "_COMPARE_MAX",
+                            0 if form == "join" else 1 << 30)
+    table, ids, keys, fill = _lookup_case(dtype, n_table, scenario)
+    assert dw.lookup_form(len(table)) == form
+    # The host twin: np.searchsorted and an equality test.
+    want = _sorted_table_lookup(table, keys, ids, fill)[0]
+    if scenario == "all_absent":
+        assert (want == fill).all()
+    if scenario == "all_present":
+        assert np.isin(keys, table).all()
+    got = jax.jit(dw._lookup_sorted, static_argnums=3)(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(keys), fill)
+    assert got.dtype == jnp.int32 and got.shape == keys.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("n", [1, 127, 128, 129, 16_385, 250_001])
+def test_running_sum_is_cumsum_mod_2_to_32(n):
+    x = np.random.default_rng(n).integers(-2 ** 31, 2 ** 31, size=n,
+                                          dtype=np.int64).astype(np.int32)
+    got = jax.jit(dw._running_sum)(jnp.asarray(x))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.cumsum(x, dtype=np.int32))
+
+
+def _eqns_under(jaxpr, scope, inside=False):
+    """Every equation of `jaxpr` (sub-programs included) that was
+    traced under a name scope starting with `scope`."""
+    for eqn in jaxpr.eqns:
+        under = inside or scope in str(eqn.source_info.name_stack)
+        if under:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_under(sub, scope, under)
+
+
+@pytest.mark.parametrize("datatype", ["flow", "dns", "proxy"])
+def test_no_loop_under_the_lookup_scopes(datatype):
+    """No binary search came back: under `onix.words.lookup_word` and
+    `onix.words.lookup_doc` the traced program holds no loop, and the
+    compiled one still carries all five scan scopes."""
+    import functools
+
+    from tests.test_trace_scopes import SCAN_SCOPES, _scan_call, _scopes_in
+
+    fn, args, kw = _scan_call(datatype)
+    jaxpr = jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr
+    for scope in ("onix.words.lookup_word", "onix.words.lookup_doc"):
+        prims = {e.primitive.name for e in _eqns_under(jaxpr, scope)}
+        assert prims, scope                      # the scope is there
+        assert not prims & {"while", "scan", "cond"}, (scope, prims)
+    # ... and the walk does see a loop where there is one.
+    assert "scan" in {e.primitive.name
+                      for e in _eqns_under(jaxpr, "", inside=True)}
+    assert _scopes_in(fn.lower(*args, **kw).compile().as_text()) \
+        == SCAN_SCOPES
+
+
+@pytest.mark.parametrize("datatype", ["flow", "dns", "proxy"])
+def test_table_build_records_the_lookup_forms(datatype):
+    from onix.utils import telemetry
+
+    telemetry.reset_for_tests()
+    cols, wt, bundle = _trained_dt(datatype)
+    extra = [list(cols["proto_classes"])] if datatype == "flow" else []
+    getattr(dw, f"build_{datatype}_tables")(bundle, wt.edges, *extra)
+    (span,) = [s for s in telemetry.TRACER.spans()
+               if s.name == "scan.tables"]
+    n_w, n_d = bundle.corpus.n_vocab, bundle.corpus.n_docs
+    assert span.attrs == {"datatype": datatype, "words": n_w, "docs": n_d,
+                          "word": dw.lookup_form(n_w),
+                          "doc": dw.lookup_form(n_d)}
+    assert {span.attrs["word"], span.attrs["doc"]} <= {"compare", "join"}
