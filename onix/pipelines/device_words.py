@@ -17,11 +17,11 @@ the scale runner's streaming stage and the SVI streaming scorer
 host reference builders, kept as the cross-check arm the parity tests
 compare winners against). Two supporting pieces live here too:
 
-* **Double-buffered chunk staging** (`stage_*_cols` / STAGE_FNS):
+* **Double-buffered chunk staging** (`stage_*_cols` / STAGE_FNS; with
+  TABLE_FNS and SCAN_FNS the datatype-keyed entry of the day scan):
   `jax.device_put` returns with the H2D copy in flight, so the scale
   runner stages chunk i+1's columns while chunk i's fused scan occupies
-  the compute units — transfer overlaps compute instead of serializing
-  with it.
+  the compute units — the transfer overlaps compute, not serializes.
 * **Hashed-vocabulary streaming buckets** (`*_stream_buckets`): the SVI
   stream has no trained vocabulary, so the fused program ends in
   splitmix64 bucketing (32-bit-limb arithmetic, bit-identical to the
@@ -418,7 +418,11 @@ def _dns_stream_scan(tables: DnsDeviceTables, table_flat: jax.Array,
         with device_scope("onix.words.bin"):
             flbin = jnp.searchsorted(tables.flen_edges, fl, side="right")
             hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
-            key = (partial_u[co]
+            # The innermost scope is an op's own: the gather is booked
+            # to dict_gather, the searches and the packing to bin.
+            with device_scope("onix.words.dict_gather"):
+                partial = partial_u[co]
+            key = (partial
                    | flbin.astype(jnp.int32)
                    | hbin.astype(jnp.int32) << _DNS_HBIN_SHIFT
                    | qt << _DNS_QTYPE_SHIFT
@@ -484,10 +488,12 @@ def stage_dns_cols(cols: dict, edges: dict) -> dict:
     """Host string features per UNIQUE qname, then async-transfer."""
     with telemetry.TRACER.span("scan.stage",
                                events=len(cols["client_u32"])):
+        with telemetry.TRACER.span("scan.partials",
+                                   names=len(cols["qnames"])):
+            partial_u = dns_partial_keys(cols["qnames"], edges)
         return {
             "_staged": True,
-            "partial_u": _put(_pad_pow2(dns_partial_keys(cols["qnames"],
-                                                         edges))),
+            "partial_u": _put(_pad_pow2(partial_u)),
             "client_u32": _put(np.asarray(cols["client_u32"], np.uint32)),
             "qname_codes": _put(np.asarray(cols["qname_codes"], np.int32)),
             "qtype": _put(np.asarray(cols["qtype"], np.int32)),
@@ -501,8 +507,11 @@ def stage_proxy_cols(cols: dict, edges: dict) -> dict:
     """Host string features per UNIQUE uri/host/agent, then transfer."""
     with telemetry.TRACER.span("scan.stage",
                                events=len(cols["client_u32"])):
-        uri_p, host_p, ua_p = proxy_partial_keys(
-            cols["uris"], cols["hosts"], cols["agents"], edges)
+        with telemetry.TRACER.span(
+                "scan.partials", uris=len(cols["uris"]),
+                hosts=len(cols["hosts"]), agents=len(cols["agents"])):
+            uri_p, host_p, ua_p = proxy_partial_keys(
+                cols["uris"], cols["hosts"], cols["agents"], edges)
         return {
             "_staged": True,
             "uri_p": _put(_pad_pow2(uri_p)),
@@ -520,6 +529,32 @@ def stage_proxy_cols(cols: dict, edges: dict) -> dict:
 STAGE_FNS = {"flow": lambda cols, edges: stage_flow_cols(cols),
              "dns": stage_dns_cols,
              "proxy": stage_proxy_cols}
+
+# The scan's other two steps, keyed the same way, so that a driver of
+# the day scan (scale._stream_score, benchmark/drivers/dayscan.py) names
+# no datatype: TABLE_FNS[dt](bundle, edges, cols) builds the device
+# tables (`cols` is one raw chunk; only the datatypes of
+# TABLES_FROM_CHUNK read it, so the others can be built before any chunk
+# exists, with None), SCAN_FNS[dt](tables, table_flat, staged, edges,
+# **kw) runs the fused scan of one chunk. The named functions are looked
+# up when called.
+TABLE_FNS = {
+    "flow": lambda bundle, edges, cols: build_flow_tables(
+        bundle, edges, list(cols["proto_classes"])),
+    "dns": lambda bundle, edges, cols: build_dns_tables(bundle, edges),
+    "proxy": lambda bundle, edges, cols: build_proxy_tables(bundle, edges),
+}
+# Flow's remap is keyed on the caller's protocol order, which only a
+# chunk carries (build_flow_tables' contract).
+TABLES_FROM_CHUNK = frozenset({"flow"})
+SCAN_FNS = {
+    "flow": lambda tables, table_flat, staged, edges, **kw:
+        flow_stream_bottom_k(tables, table_flat, staged, **kw),
+    "dns": lambda tables, table_flat, staged, edges, **kw:
+        dns_stream_bottom_k(tables, table_flat, staged, edges, **kw),
+    "proxy": lambda tables, table_flat, staged, edges, **kw:
+        proxy_stream_bottom_k(tables, table_flat, staged, edges, **kw),
+}
 
 
 def dns_stream_bottom_k(tables: DnsDeviceTables, table_flat: jax.Array,
@@ -620,7 +655,9 @@ def _proxy_stream_scan(tables: ProxyDeviceTables, table_flat: jax.Array,
         with device_scope("onix.words.bin"):
             hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
             cclass = rc // 100
-            key = (uri_p[uc] | host_p[hc] | ua_p[ac]
+            with device_scope("onix.words.dict_gather"):    # as in dns
+                partial = uri_p[uc] | host_p[hc] | ua_p[ac]
+            key = (partial
                    | cclass
                    | hbin.astype(jnp.int32) << _PROXY_HBIN_SHIFT)
             valid = (rc >= 0) & (cclass < 8)

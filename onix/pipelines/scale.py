@@ -498,6 +498,7 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
     import jax.numpy as jnp
 
     from onix.models import scoring
+    from onix.pipelines import device_words as dw
 
     info = {} if info is None else info
     # Direct callers (exp_flow_recall.py and any embedder predating the
@@ -532,10 +533,12 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
     # caveat and the compact-key range gates (a trained vocab outside
     # the ranges raises at table build → host path, announced).
     device_words = not host_words_forced()
-    # Flow tables are built lazily from the FIRST streamed chunk, whose
-    # cols["proto_classes"] is the caller proto-id order the device
-    # remap must key on (the fitted table is sorted — a different
-    # beast; build_flow_tables' contract).
+    # Every datatype decision of the device arm is dw's, by its three
+    # tables (TABLE_FNS, STAGE_FNS, SCAN_FNS). The tables of a datatype
+    # in dw.TABLES_FROM_CHUNK (flow) are built lazily from the FIRST
+    # streamed chunk, whose cols["proto_classes"] is the caller proto-id
+    # order the device remap must key on (the fitted table is sorted — a
+    # different beast; build_flow_tables' contract).
     dev_tables = None
     # One clock holds the three stream walls: the spans below feed it
     # (and so do the plain busy scopes around callees that open spans
@@ -551,17 +554,14 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
             walls[k] = carried[k] + clock.busy_s.get(k, 0.0)
 
     _set_walls()
-    if device_words and datatype != "flow":
-        from onix.pipelines import device_words as dw
+    if device_words and datatype not in dw.TABLES_FROM_CHUNK:
         # Timed into stream_words_map like the flow build: the O(V+D)
         # re-encode is pipeline work, identical accounting across
         # datatypes.
         with clock.busy("stream_words_map"):
             try:
-                dev_tables = (
-                    dw.build_dns_tables(bundle, fitted_edges)
-                    if datatype == "dns"
-                    else dw.build_proxy_tables(bundle, fitted_edges))
+                dev_tables = dw.TABLE_FNS[datatype](bundle, fitted_edges,
+                                                    None)
             except ValueError as e:
                 print(f"device words unavailable ({e}); "
                       "using the host path")
@@ -614,9 +614,6 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
             if save_meta is not None:
                 save_meta()
 
-    if device_words:
-        from onix.pipelines import device_words as dw
-
     def _synth_chunk(ci: int, mi: int) -> dict:
         with telemetry.TRACER.span("scan.synth", clock=clock,
                                    clock_name="stream_synth", chunk=ci):
@@ -632,9 +629,8 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
         nonlocal dev_tables
         # STAGE_FNS opens `scan.stage` (and `scan.h2d_put` per column).
         with clock.busy("stream_words_map"):
-            if dev_tables is None:  # flow: keyed on the caller proto order
-                dev_tables = dw.build_flow_tables(
-                    bundle, fitted_edges, list(cc["proto_classes"]))
+            if dev_tables is None:  # dw.TABLES_FROM_CHUNK: the first one
+                dev_tables = dw.TABLE_FNS[datatype](bundle, fitted_edges, cc)
             return dw.STAGE_FNS[datatype](cc, fitted_edges)
 
     def _stage_chunk(ci: int, mi: int):
@@ -659,16 +655,7 @@ def _stream_score(bundle, fitted_edges, theta, phi_wk, *, n_events: int,
             return did * np.int32(v_x) + wid
 
     def _fused_bottom_k(staged):
-        if datatype == "flow":
-            return dw.flow_stream_bottom_k(
-                dev_tables, table, staged, v_x=v_x, unseen_w=unseen_w,
-                unseen_d=unseen_d, tol=1.0, max_results=max_results)
-        if datatype == "dns":
-            return dw.dns_stream_bottom_k(
-                dev_tables, table, staged, fitted_edges, v_x=v_x,
-                unseen_w=unseen_w, unseen_d=unseen_d, tol=1.0,
-                max_results=max_results)
-        return dw.proxy_stream_bottom_k(
+        return dw.SCAN_FNS[datatype](
             dev_tables, table, staged, fitted_edges, v_x=v_x,
             unseen_w=unseen_w, unseen_d=unseen_d, tol=1.0,
             max_results=max_results)

@@ -123,6 +123,7 @@ SPAN_REGISTRY: dict[str, str] = {
     "scan.dispatch": "device_words.*_stream_bottom_k: the call of the jitted words+score+select scan for one chunk (async dispatch)",
     "scan.fetch": "scale._stream_score: one chunk's winners to the host, blocked on the scan",
     "scan.h2d_put": "device_words._put: jax.device_put of one staged column (the host's side of the copy; child of scan.stage)",
+    "scan.partials": "device_words.stage_dns_cols, stage_proxy_cols: the per-unique string features of one chunk's dictionaries packed into partial keys (dns_partial_keys, proxy_partial_keys; child of scan.stage); its attributes count the dictionaries (names; uris, hosts, agents)",
     "scan.stage": "device_words.stage_*_cols: one chunk's host casts, per-unique string features and the start of its copies",
     "scan.synth": "scale._stream_score: the synthetic generator for one streamed chunk",
     "scan.tables": "device_words.build_*_tables: the trained tables re-encoded and copied for the device; its attributes say which form each look-up takes for them (word, doc: compare or join)",

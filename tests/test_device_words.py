@@ -456,10 +456,10 @@ def _eqns_under(jaxpr, scope, inside=False):
 def test_no_loop_under_the_lookup_scopes(datatype):
     """No binary search came back: under `onix.words.lookup_word` and
     `onix.words.lookup_doc` the traced program holds no loop, and the
-    compiled one still carries all five scan scopes."""
+    compiled one still carries every scan scope of its datatype."""
     import functools
 
-    from tests.test_trace_scopes import SCAN_SCOPES, _scan_call, _scopes_in
+    from tests.test_trace_scopes import SCOPES_OF, _scan_call, _scopes_in
 
     fn, args, kw = _scan_call(datatype)
     jaxpr = jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr
@@ -471,7 +471,7 @@ def test_no_loop_under_the_lookup_scopes(datatype):
     assert "scan" in {e.primitive.name
                       for e in _eqns_under(jaxpr, "", inside=True)}
     assert _scopes_in(fn.lower(*args, **kw).compile().as_text()) \
-        == SCAN_SCOPES
+        == SCOPES_OF[datatype]
 
 
 @pytest.mark.parametrize("datatype", ["flow", "dns", "proxy"])
@@ -489,3 +489,40 @@ def test_table_build_records_the_lookup_forms(datatype):
                           "word": dw.lookup_form(n_w),
                           "doc": dw.lookup_form(n_d)}
     assert {span.attrs["word"], span.attrs["doc"]} <= {"compare", "join"}
+
+
+# ---------------------------------------------------------------------------
+# The datatype-keyed entry (ISSUE 29): TABLE_FNS / STAGE_FNS / SCAN_FNS
+# are the named functions, reached without naming a datatype.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("datatype", ["flow", "dns", "proxy"])
+def test_keyed_entry_is_bit_equal_to_the_named_functions(datatype):
+    cols, wt, bundle = _trained_dt(datatype, n=9_000)
+    d, v = bundle.corpus.n_docs, bundle.corpus.n_vocab
+    table = jnp.asarray(np.random.default_rng(17).random(
+        (d + 1) * (v + 1)).astype(np.float32))
+    kw = dict(v_x=v + 1, unseen_w=v, unseen_d=d, tol=1.0, max_results=120)
+    cols2 = SYNTH_ARRAYS[datatype](7_000, n_hosts=300, n_anomalies=20,
+                                   seed=41)
+    build = getattr(dw, f"build_{datatype}_tables")
+    fused = getattr(dw, f"{datatype}_stream_bottom_k")
+    if datatype == "flow":
+        want = fused(build(bundle, wt.edges, list(cols2["proto_classes"])),
+                     table, cols2, **kw)
+    else:
+        want = fused(build(bundle, wt.edges), table, cols2, wt.edges, **kw)
+    # As a driver spells it: no datatype outside the three look-ups; the
+    # tables that need no chunk are built without one.
+    chunk = cols2 if datatype in dw.TABLES_FROM_CHUNK else None
+    tables = dw.TABLE_FNS[datatype](bundle, wt.edges, chunk)
+    got = dw.SCAN_FNS[datatype](tables, table,
+                                dw.STAGE_FNS[datatype](cols2, wt.edges),
+                                wt.edges, **kw)
+    np.testing.assert_array_equal(np.asarray(got.indices),
+                                  np.asarray(want.indices))
+    np.testing.assert_array_equal(np.asarray(got.scores),
+                                  np.asarray(want.scores))
+    assert np.isfinite(np.asarray(got.scores)).sum() == 120
+    assert set(dw.TABLE_FNS) == set(dw.STAGE_FNS) == set(dw.SCAN_FNS)

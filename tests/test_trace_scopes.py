@@ -30,6 +30,10 @@ from onix.utils import obs, telemetry
 
 SCAN_SCOPES = {"onix.words.bin", "onix.words.lookup_word",
                "onix.words.lookup_doc", "onix.score.gather", "onix.select"}
+# The datatypes with dictionary-coded string columns gather their
+# per-unique partial keys under a scope of their own (ISSUE 29).
+DICT_SCOPES = SCAN_SCOPES | {"onix.words.dict_gather"}
+SCOPES_OF = {"flow": SCAN_SCOPES, "dns": DICT_SCOPES, "proxy": DICT_SCOPES}
 SWEEP_SCOPES = {"onix.sweep.gather", "onix.sweep.sample",
                 "onix.sweep.scatter", "onix.sweep.nwk", "onix.sweep.loglik"}
 
@@ -100,7 +104,7 @@ def _scopes_in(text: str) -> set[str]:
 
 
 @pytest.mark.parametrize("program,want", [
-    ("flow", SCAN_SCOPES), ("dns", SCAN_SCOPES), ("proxy", SCAN_SCOPES),
+    ("flow", SCAN_SCOPES), ("dns", DICT_SCOPES), ("proxy", DICT_SCOPES),
     ("superstep", SWEEP_SCOPES)])
 def test_every_scope_is_in_the_compiled_program(program, want):
     fn, args, kw = (_superstep_call() if program == "superstep"
@@ -271,3 +275,24 @@ def test_stream_walls_filled_and_one_dispatch_per_chunk(off, telemetry_off,
     assert len(puts) == 2 * 8 and {p.parent_id for p in puts} == stage_ids
     assert sum(p.attrs["bytes"] for p in puts) == 2 * 30_000 * 8 * 4
     assert "scan.checkpoint" not in by_name       # no resume dir given
+
+
+@pytest.mark.parametrize("datatype,sizes", [
+    ("dns", ("names",)), ("proxy", ("uris", "hosts", "agents"))])
+def test_partials_span_is_a_child_of_stage_and_counts_the_dictionaries(
+        datatype, sizes):
+    """`scan.partials` holds the per-unique string work alone, inside
+    `scan.stage` and beside the `scan.h2d_put` children, with the
+    dictionary sizes as attributes."""
+    telemetry.reset_for_tests()
+    cols, wt, *_ = _toy(datatype)
+    dw.STAGE_FNS[datatype](cols, wt.edges)
+    by_name = {}
+    for s in telemetry.TRACER.spans():
+        by_name.setdefault(s.name, []).append(s)
+    (stage,), (partials,) = by_name["scan.stage"], by_name["scan.partials"]
+    assert partials.parent_id == stage.span_id
+    assert {p.parent_id for p in by_name["scan.h2d_put"]} == {stage.span_id}
+    dicts = {"names": "qnames"}
+    assert partials.attrs == {k: len(cols[dicts.get(k, k)]) for k in sizes}
+    assert 0 <= partials.dur_s <= stage.dur_s
