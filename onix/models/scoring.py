@@ -264,10 +264,14 @@ def score_table(theta: jax.Array, phi_wk: jax.Array) -> jax.Array:
     Product vocabularies are small by construction (packed words, coarse
     bins — V is hundreds to a few thousand), so D×V usually fits HBM
     comfortably; a single MXU matmul replaces per-event gather-dot pairs
-    and per-event scoring degrades to a flat 4-byte gather (docs/PERF.md:
-    the gather runs ~250 GB/s while the gathered-operand dot wastes
-    108/128 lanes). Multi-chain inputs combine with the geometric mean,
-    matching score_events."""
+    and per-event scoring becomes the read of one 4-byte entry (the
+    gathered-operand dot wastes 108/128 lanes). What that read costs on
+    a v5e is measured, not the 250 GB/s once written here: XLA's gather
+    of one scalar per index takes 13.4 ns an element (0.3 GB/s), and the
+    fused day scan reads the table by rows of 128 lanes instead
+    (`device_words._take`, 9.2 ns; PERF.md section 6, PR 30).
+    Multi-chain inputs combine with the geometric mean, matching
+    score_events."""
     if theta.ndim == 2:
         return theta @ phi_wk.T
     per_chain = jnp.einsum("cdk,cvk->cdv", theta, phi_wk)

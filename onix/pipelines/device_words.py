@@ -33,6 +33,14 @@ keys by sorting both together; neither gathers. Not by binary search: on
 a v5e a gather costs 7-9 ns per element whatever it fetches, and
 `jnp.searchsorted` pays one per step, 18 to an address (PERF.md).
 
+How `table[idx]` is read (the score table, the dictionaries' partial
+keys): through `_take`, never by XLA's gather of one scalar per index,
+which costs 13.4 ns an element from the 280 MB score table and 5.5-8.3
+ns from a dictionary of 2 KB. A short table is compared whole against
+every index (1.0-1.7 ns); a long one is gathered by rows of 128 lanes
+and the lane picked by a compare (9.2 ns: one DMA a row, whatever the
+row holds and wherever it lies). PERF.md section 6, PR 30.
+
 Why a compact key: the host path packs words into 43-bit int64 keys
 (words.FLOW_SPEC). JAX runs x64-disabled, so the device path re-encodes
 the TRAINED vocabulary once on the host into an equivalent <=31-bit
@@ -178,7 +186,8 @@ def _edges1d(edges: dict, name: str) -> "jnp.ndarray":
 # the fused scan, the compare of 2^21 keys takes 1.6 ms at 349 entries
 # and grows with the table; the join takes 9.3 ms whatever the table
 # holds, 13.5 ms with the word table joined too (PERF.md section 6,
-# PR 27).
+# PR 27). `_lookup_sorted`'s threshold only: what reads `table[idx]`
+# has its own (`_TAKE_COMPARE_MAX`).
 _COMPARE_MAX = 1024
 
 
@@ -187,6 +196,12 @@ def lookup_form(n_table: int) -> str:
     "compare" or "join". The table's length decides, which is static
     under jit; there is no binary search to fall back to."""
     return "compare" if n_table <= _COMPARE_MAX else "join"
+
+
+def _rows_of_128(x: jax.Array) -> jax.Array:
+    """1-D `x` as rows of 128 lanes, the last one padded with zeros."""
+    rows = -(-x.shape[0] // 128)
+    return jnp.pad(x, (0, rows * 128 - x.shape[0])).reshape(rows, 128)
 
 
 def _running_sum(x: jax.Array) -> jax.Array:
@@ -200,10 +215,9 @@ def _running_sum(x: jax.Array) -> jax.Array:
     if n <= 128:
         return jax.lax.reduce_window(x, 0, jax.lax.add, (n,), (1,),
                                      ((n - 1, 0),))
-    rows = -(-n // 128)
     within = jax.lax.reduce_window(
-        jnp.pad(x, (0, rows * 128 - n)).reshape(rows, 128), 0, jax.lax.add,
-        (1, 128), (1, 1), ((0, 0), (127, 0)))
+        _rows_of_128(x), 0, jax.lax.add, (1, 128), (1, 1),
+        ((0, 0), (127, 0)))
     total = within[:, -1]
     return (within + (_running_sum(total) - total)[:, None]).reshape(-1)[:n]
 
@@ -243,6 +257,70 @@ def _lookup_sorted(table: jax.Array, ids: jax.Array, keys: jax.Array,
     return out[2 * d:]
 
 
+# `_take` compares a table of at most this many entries whole against
+# every index and gathers a longer one by rows. On a v5e a block of 2^21
+# indices takes 2.1, 2.3 and 3.5 ms against 512, 1024 and 2048 entries
+# compared, 5.3 ms by rows from any of them, and 19.2 ms by rows from
+# the 70 M-entry score table (PERF.md section 6, PR 30); the
+# dictionaries are 512 to 2048 entries, past that not measured.
+_TAKE_COMPARE_MAX = 2048
+# The row form works through a block in runs of this many indices, so
+# that a run's rows ([2^15, 128], 16 MiB) stay in the chip's fast memory
+# between the gather and the pick; a whole block's (1 GiB) go through
+# HBM: 11.0 ns an element, not 9.2.
+_TAKE_RUN = 1 << 15
+
+
+def take_form(n_table: int) -> str:
+    """How `_take` reads a table of `n_table` entries: "compare" or
+    "rows". The table's length decides, which is static under jit."""
+    return "compare" if n_table <= _TAKE_COMPARE_MAX else "rows"
+
+
+def _take(table: jax.Array):
+    """`read`, where `read(idx)` is `table[idx]` bit for bit, for a 1-D
+    table of 4-byte entries and int32 indices (negative ones count from
+    the end and the rest are clamped, as `table[idx]` has it), without a
+    gather of one scalar per index (module docstring). Call `_take` once
+    a program, outside its loop over blocks: the long table's view as
+    rows is made here, `read` only reads. Entries travel as their bits
+    and are selected, never multiplied: `inf`, `-0.0` and a NaN's
+    payload come back as they are."""
+    n, dtype = table.shape[0], table.dtype
+    bits = jax.lax.bitcast_convert_type(table, jnp.int32)
+
+    def in_range(idx):
+        return jnp.clip(jnp.where(idx < 0, idx + n, idx), 0, n - 1)
+
+    def pick(rows, lane):
+        # rows[i, lane[i]]: all other lanes of a row are zeroed, so the
+        # sum is the one left.
+        hit = lane[:, None] == jax.lax.iota(jnp.int32, rows.shape[1])[None, :]
+        return jnp.sum(jnp.where(hit, rows, 0), axis=1)
+
+    if take_form(n) == "compare":
+        def read_bits(idx):
+            return pick(bits[None, :], idx)
+    else:
+        rows = _rows_of_128(bits)
+
+        def read_run(idx):
+            return pick(rows[idx >> 7], idx & 127)
+
+        def read_bits(idx):
+            m = idx.shape[0]
+            if m <= _TAKE_RUN or m % _TAKE_RUN:
+                return read_run(idx)
+            _, picked = jax.lax.scan(
+                lambda _, run: (None, read_run(run)), None,
+                idx.reshape(m // _TAKE_RUN, _TAKE_RUN))
+            return picked.reshape(m)
+
+    def read(idx):
+        return jax.lax.bitcast_convert_type(read_bits(in_range(idx)), dtype)
+    return read
+
+
 def _flow_flat_idx(t: FlowDeviceTables, v_x: int, unseen_w: int,
                    unseen_d: int, sip, dip, sport, dport, proto, hour,
                    byt, pkt):
@@ -280,11 +358,14 @@ def _flow_stream_scan(tables: FlowDeviceTables, table_flat: jax.Array,
                       sip, dip, sport, dport, proto, hour, byt, pkt, *,
                       v_x: int, unseen_w: int, unseen_d: int, tol: float,
                       max_results: int, chunk: int) -> scoring.TopK:
+    with device_scope("onix.score.gather"):
+        score_of = _take(table_flat)
+
     def score_chunk(s_ip, d_ip, s_p, d_p, pr, hr, by, pk):
         idx_s, idx_d = _flow_flat_idx(tables, v_x, unseen_w, unseen_d,
                                       s_ip, d_ip, s_p, d_p, pr, hr, by, pk)
         with device_scope("onix.score.gather"):
-            s = jnp.minimum(table_flat[idx_s], table_flat[idx_d])
+            s = jnp.minimum(score_of(idx_s), score_of(idx_d))
             return jnp.where(s < tol, s, jnp.inf)
 
     return scoring._scan_bottom_k(
@@ -414,14 +495,20 @@ def _dns_stream_scan(tables: DnsDeviceTables, table_flat: jax.Array,
                      flen, hour, *, v_x: int, unseen_w: int, unseen_d: int,
                      tol: float, max_results: int,
                      chunk: int) -> scoring.TopK:
+    with device_scope("onix.words.dict_gather"):
+        partial_of = _take(partial_u)
+    with device_scope("onix.score.gather"):
+        score_of = _take(table_flat)
+
     def score_chunk(cl, co, qt, rc, fl, hr):
         with device_scope("onix.words.bin"):
             flbin = jnp.searchsorted(tables.flen_edges, fl, side="right")
             hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
-            # The innermost scope is an op's own: the gather is booked
-            # to dict_gather, the searches and the packing to bin.
+            # The innermost scope is an op's own: the dictionary's read
+            # is booked to dict_gather, the searches and the packing to
+            # bin.
             with device_scope("onix.words.dict_gather"):
-                partial = partial_u[co]
+                partial = partial_of(co)
             key = (partial
                    | flbin.astype(jnp.int32)
                    | hbin.astype(jnp.int32) << _DNS_HBIN_SHIFT
@@ -436,7 +523,7 @@ def _dns_stream_scan(tables: DnsDeviceTables, table_flat: jax.Array,
             did = _lookup_sorted(tables.doc_u32, tables.doc_ids, cl,
                                  unseen_d)
         with device_scope("onix.score.gather"):
-            s = table_flat[did * jnp.int32(v_x) + wid]
+            s = score_of(did * jnp.int32(v_x) + wid)
             return jnp.where(s < tol, s, jnp.inf)
 
     return scoring._scan_bottom_k(
@@ -651,12 +738,17 @@ def _proxy_stream_scan(tables: ProxyDeviceTables, table_flat: jax.Array,
                        client, uri_c, host_c, ua_c, respcode, hour, *,
                        v_x: int, unseen_w: int, unseen_d: int, tol: float,
                        max_results: int, chunk: int) -> scoring.TopK:
+    with device_scope("onix.words.dict_gather"):
+        uri_of, host_of, ua_of = _take(uri_p), _take(host_p), _take(ua_p)
+    with device_scope("onix.score.gather"):
+        score_of = _take(table_flat)
+
     def score_chunk(cl, uc, hc, ac, rc, hr):
         with device_scope("onix.words.bin"):
             hbin = jnp.searchsorted(tables.hour_edges, hr, side="right")
             cclass = rc // 100
             with device_scope("onix.words.dict_gather"):    # as in dns
-                partial = uri_p[uc] | host_p[hc] | ua_p[ac]
+                partial = uri_of(uc) | host_of(hc) | ua_of(ac)
             key = (partial
                    | cclass
                    | hbin.astype(jnp.int32) << _PROXY_HBIN_SHIFT)
@@ -669,7 +761,7 @@ def _proxy_stream_scan(tables: ProxyDeviceTables, table_flat: jax.Array,
             did = _lookup_sorted(tables.doc_u32, tables.doc_ids, cl,
                                  unseen_d)
         with device_scope("onix.score.gather"):
-            s = table_flat[did * jnp.int32(v_x) + wid]
+            s = score_of(did * jnp.int32(v_x) + wid)
             return jnp.where(s < tol, s, jnp.inf)
 
     return scoring._scan_bottom_k(

@@ -441,6 +441,102 @@ def test_running_sum_is_cumsum_mod_2_to_32(n):
                                   np.cumsum(x, dtype=np.int32))
 
 
+def _take_case(dtype, n_table, n_idx):
+    """A table of `n_table` 4-byte entries with the values a select
+    keeps and a product would not, and indices into it: both ends,
+    repeats, and ones only `table[idx]`'s own rules put in range."""
+    rng = np.random.default_rng(n_table + n_idx)
+    if dtype == np.float32:
+        table = rng.standard_normal(n_table).astype(np.float32)
+        odd = np.array([np.inf, -0.0, 1e-40, np.nan, -np.inf],
+                       np.float32).view(np.int32)
+        odd[3] |= 0x1234                          # a NaN with a payload
+    else:
+        table = rng.integers(-2 ** 31, 2 ** 31, n_table).astype(np.int32)
+        odd = np.array([-1, -2 ** 31, 2 ** 31 - 1, 0x40000000, -7],
+                       np.int32)
+    where = rng.permutation(n_table)[:len(odd)]
+    table.view(np.int32)[where] = odd[:len(where)]
+    idx = rng.integers(0, n_table, n_idx)
+    corners = np.array([0, n_table - 1, 0, n_table - 1, *where, *where,
+                        n_table // 2, -1, -n_table, n_table,
+                        -n_table - 5, 2 ** 31 - 1, -2 ** 31])
+    idx[:len(corners)] = corners[:n_idx]
+    return table, idx.astype(np.int32)
+
+
+@pytest.mark.parametrize("n_idx", [1, 1000, 1024])
+@pytest.mark.parametrize("n_table,form", [
+    (1, "compare"), (1, "rows"), (127, "compare"), (127, "rows"),
+    (128, "compare"), (128, "rows"), (129, "compare"), (129, "rows"),
+    (512, "compare"), (512, "rows"), (2048, "compare"), (2048, "rows"),
+    (70_001, "rows"), ((1 << 24) + 129, "rows")])
+@pytest.mark.parametrize("dtype", [np.float32, np.int32])
+def test_take_is_table_of_idx_bit_for_bit(dtype, n_table, form, n_idx,
+                                          monkeypatch):
+    # The product's lengths take their own form; the short tables are
+    # also driven through the other one. Runs of 256: a block of 1024
+    # indices goes through the row form's inner scan, the others whole.
+    if dw.take_form(n_table) != form:
+        monkeypatch.setattr(dw, "_TAKE_COMPARE_MAX", 0)
+    monkeypatch.setattr(dw, "_TAKE_RUN", 256)
+    assert dw.take_form(n_table) == form
+    table, idx = _take_case(dtype, n_table, n_idx)
+    got = jax.jit(lambda t, i: dw._take(t)(i))(jnp.asarray(table),
+                                               jnp.asarray(idx))
+    assert got.dtype == table.dtype and got.shape == idx.shape
+    # What `table[idx]` gives, by its own rules for the indices ...
+    want = np.asarray(jnp.asarray(table)[jnp.asarray(idx)])
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  want.view(np.int32))
+    # ... and, where numpy has the same rules, what numpy gives.
+    ok = (idx >= -n_table) & (idx < n_table)
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.int32)[ok], table[idx[ok]].view(np.int32))
+
+
+def test_take_runs_are_the_products_own_length():
+    """A block of 2^21 indices, the scan's own, splits into whole runs:
+    the row form's inner scan is what the product runs."""
+    assert (1 << 21) % dw._TAKE_RUN == 0 and (1 << 21) > dw._TAKE_RUN
+    table, idx = _take_case(np.float32, 70_001, 2 * dw._TAKE_RUN)
+    jaxpr = jax.make_jaxpr(lambda t, i: dw._take(t)(i))(table, idx)
+    assert [e.params["length"] for e in jaxpr.jaxpr.eqns
+            if e.primitive.name == "scan"] == [2]
+    got = jax.jit(lambda t, i: dw._take(t)(i))(table, idx)
+    ok = (idx >= 0) & (idx < len(table))
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32)[ok],
+                                  table[idx[ok]].view(np.int32))
+
+
+@pytest.mark.parametrize("datatype", ["flow", "dns", "proxy"])
+def test_stream_scan_is_bit_equal_to_the_flat_gather_scan(datatype,
+                                                          monkeypatch):
+    """Each `*_stream_scan` returns the winners and the scores of the
+    same program with `table[idx]` read by XLA's own gather."""
+    from tests.test_trace_scopes import _scan_call
+    fn, args, kw = _scan_call(datatype)
+
+    # A function of its own: jit's trace cache is keyed by the function,
+    # and the product's must not be filled from the patched source.
+    def flat_scan(*a, **k):
+        return fn.__wrapped__(*a, **k)
+
+    flat = jax.jit(flat_scan, static_argnames=tuple(kw))
+    with monkeypatch.context() as m:
+        m.setattr(dw, "_take", lambda table: lambda idx: table[idx])
+        want = flat(*args, **kw)
+        per_element = f"(tensor<{args[1].shape[0]}xf32>, tensor<2048x1xi32>)"
+        assert per_element in flat.lower(*args, **kw).as_text()
+    assert per_element not in fn.lower(*args, **kw).as_text()
+    got = fn(*args, **kw)
+    np.testing.assert_array_equal(np.asarray(got.indices),
+                                  np.asarray(want.indices))
+    np.testing.assert_array_equal(np.asarray(got.scores).view(np.int32),
+                                  np.asarray(want.scores).view(np.int32))
+    assert np.isfinite(np.asarray(got.scores)).sum() == kw["max_results"]
+
+
 def _eqns_under(jaxpr, scope, inside=False):
     """Every equation of `jaxpr` (sub-programs included) that was
     traced under a name scope starting with `scope`."""

@@ -113,6 +113,42 @@ def test_every_scope_is_in_the_compiled_program(program, want):
     assert _scopes_in(text) == want
 
 
+@pytest.mark.parametrize("datatype", ["flow", "dns", "proxy"])
+def test_no_per_element_gather_under_the_read_scopes(datatype):
+    """ISSUE 30: the score table and the dictionaries are read through
+    `device_words._take`. Under `onix.score.gather` and
+    `onix.words.dict_gather` the program still has ops, none of them a
+    gather of one scalar per index; and the lowered text holds no such
+    gather from the score table's operand, whatever scope it is in."""
+    import functools
+
+    from tests.test_device_words import _eqns_under
+    fn, args, kw = _scan_call(datatype)
+    jaxpr = jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr
+    reads = {"onix.score.gather"} | (SCOPES_OF[datatype]
+                                     & {"onix.words.dict_gather"})
+    for scope in reads:
+        eqns = list(_eqns_under(jaxpr, scope))
+        assert eqns, scope
+        slices = [tuple(e.params["slice_sizes"]) for e in eqns
+                  if e.primitive.name == "gather"]
+        assert (1,) not in slices, (scope, slices)
+        # The toy score table is long enough for the row form, the toy
+        # dictionaries are compared whole.
+        assert slices == ([(1, 128)] * (2 if datatype == "flow" else 1)
+                          if scope == "onix.score.gather" else []), slices
+    table = args[1]
+    assert dw.take_form(table.shape[0]) == "rows"
+    text = fn.lower(*args, **kw).as_text()
+    gathers = re.findall(r'"stablehlo\.gather"\(.*', text)
+    assert gathers                          # the winners' are still there
+    per_element = [g for g in gathers
+                   if re.search(r"slice_sizes = array<i64: 1>", g)]
+    from_table = f"(tensor<{table.shape[0]}xf32>"
+    assert not [g for g in per_element if from_table in g]
+    assert any("slice_sizes = array<i64: 1, 128>" in g for g in gathers)
+
+
 def test_a_scope_makes_metadata_part_of_the_compile_cache_key():
     """The persistent compile cache leaves `op_name` out of its key by
     default, so a process could load an executable compiled from
