@@ -91,7 +91,7 @@ def phase0_device(require_tpu: bool = True) -> dict:
     table knows, and whose Pallas kernels compile, the smoke stops."""
     import jax
 
-    from onix.models.pallas_gibbs import pallas_mode
+    from onix.models.pallas_serve import pallas_mode
     from onix.utils.obs import (device_peak_bytes_per_s, device_summary,
                                 enable_compile_cache)
 

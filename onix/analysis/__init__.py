@@ -57,9 +57,9 @@ from onix.analysis.core import (  # noqa: F401
 
 
 def lint_status(root=None) -> dict:
-    """One-call summary for artifact stamping (bench detail.resilience):
-    the analyzer version and the finding count over the default scope.
-    A lint-clean tree stamps {"version": N, "findings": 0}."""
+    """One-call summary for artifact stamping: the analyzer version
+    and the finding count over the default scope. A lint-clean tree
+    stamps {"version": N, "findings": 0}."""
     ctx = AnalysisContext.from_root(root)
     found = run_passes(ctx)
     return {"version": ANALYSIS_VERSION, "findings": len(found)}

@@ -22,7 +22,7 @@ def main(argv: list[str] | None = None) -> int:
                     "AST static analysis)")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to analyze (default: onix/, "
-                         "bench.py, scripts/)")
+                         "chip_smoke.py, scripts/)")
     ap.add_argument("--root", default=None,
                     help="repo root (default: inferred from the package)")
     ap.add_argument("--passes", default=None,

@@ -30,9 +30,9 @@ import json
 import pathlib
 import re
 
-#: Bumped whenever a pass's rules change materially — stamped into
-#: bench artifacts (detail.resilience.lint) so an artifact records
-#: which contract set the tree was clean under.
+#: Bumped whenever a pass's rules change materially, so a baseline
+#: file or a stamp (`lint_status`) records which contract set the tree
+#: was clean under.
 ANALYSIS_VERSION = 1
 
 _EXEMPT_RE = re.compile(
@@ -134,11 +134,11 @@ class SourceFile:
 def default_targets(root: pathlib.Path) -> list[pathlib.Path]:
     """The analyzer's scope — the same file set the r9 lint grew to
     cover: ALL of onix/ plus the harness code outside the package
-    (bench.py, chip_smoke.py, scripts/*.py). tests/ are deliberately
-    out: they pin envs and poke private tables as part of their job."""
+    (chip_smoke.py, scripts/*.py). tests/ are deliberately out: they
+    pin envs and poke private tables as part of their job."""
     files = sorted((root / "onix").rglob("*.py"))
-    files += [f for f in (root / "bench.py", root / "chip_smoke.py")
-              if f.exists()]
+    if (root / "chip_smoke.py").exists():
+        files.append(root / "chip_smoke.py")
     files += sorted((root / "scripts").glob("*.py"))
     return files
 
@@ -156,7 +156,7 @@ class AnalysisContext:
         if root is None:
             # onix/analysis/core.py -> repo root two levels up from the
             # package dir — UNLESS the package is pip-installed into
-            # site-packages (no docs/, bench.py, or scripts/ siblings
+            # site-packages (no docs/ or scripts/ siblings
             # there), in which case `onix-lint` run from a repo
             # checkout must lint the CHECKOUT, not the installed copy:
             # fall back to cwd when it looks like the repo and the

@@ -507,9 +507,6 @@ FINGERPRINT_EXEMPT: dict[str, str] = {
                         "ll entries land denser-never-sparser and the "
                         "async τ>0 segmentation-dependence is the "
                         "documented in-band contract (ROBUSTNESS.md)",
-    "nwk_form": "all three count-update forms are bit-identical "
-                "(tested) — pure performance, documented as NOT part "
-                "of the fingerprint in config.py",
     "svi_batch_size": "batch SVI minibatch slicing; the batch engine "
                       "has no checkpoint/resume path and the streaming "
                       "scorer's minibatches are the file feed",

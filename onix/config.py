@@ -28,10 +28,11 @@ def resolve_form_gate(*, gate: str, choices: tuple[str, ...],
                       env_var: str | None = None,
                       measured: Callable[[], str | None] | None = None,
                       default: str) -> str:
-    """The ONE precedence chain behind every measured performance gate —
-    `lda_gibbs.select_nwk_form`, `model_bank.select_bank_form`, and
-    `pallas_serve.select_serve_form` each resolve through this helper so
-    the three tables cannot drift in precedence order:
+    """The ONE precedence chain behind the option-bearing performance
+    gates — `model_bank.select_bank_form` and `select_shard_form`,
+    `pallas_serve.select_serve_form`, `lda_gibbs.select_sampler_form` —
+    each resolves through this helper so their tables cannot drift in
+    precedence order:
 
         env override  >  explicit form  >  measured table  >  default
 
@@ -39,11 +40,12 @@ def resolve_form_gate(*, gate: str, choices: tuple[str, ...],
     empty and "auto" both mean "no override" — exporting FOO=auto resets
     an inherited override instead of crashing. Any other value outside
     `choices` raises, for env and explicit alike: a typo'd override must
-    fail loudly, never silently mislabel an experiment's arms. The nwk
-    gate passes no env — its engines resolve ONIX_NWK_FORM themselves,
-    where an explicit test-arm pin must outrank an exported override
-    (make_block_step's documented contract), and hand the result in as
-    `explicit`. `measured` is the per-backend crossover-table lookup;
+    fail loudly, never silently mislabel an experiment's arms. The
+    sampler gate passes no env — its engines resolve ONIX_SAMPLER_FORM
+    themselves, behind the config field, and hand the result in as
+    `explicit`. (`lda_gibbs.select_nwk_form` has no option to order and
+    does not come here.) `measured` is the per-backend crossover-table
+    lookup;
     None (unmeasured platform, or below the crossover) falls to
     `default` — never an unmeasured guess."""
     if env is None and env_var is not None:
@@ -64,7 +66,7 @@ def resolve_form_gate(*, gate: str, choices: tuple[str, ...],
 
 
 #: The central registry of every `ONIX_*` environment variable the
-#: linted tree (onix/, bench.py, scripts/) reads: name -> (type, doc).
+#: linted tree (onix/, chip_smoke.py, scripts/) reads: name -> (type, doc).
 #: Machine-checked by `python -m onix.analysis` (the `envs` pass): a
 #: literal ONIX_* read of an undeclared name is a finding, and so is a
 #: declaration nothing reads — this table can neither lag nor rot. The
@@ -80,9 +82,6 @@ ENV_REGISTRY: dict[str, tuple[str, str]] = {
     "ONIX_BANK_SHARD": (
         "choice: auto|single|sharded",
         "model-bank mesh placement override (model_bank.select_shard_form)"),
-    "ONIX_BENCH_COMPONENTS": (
-        "csv of component names",
-        "bench.py: run only these components (debugging a single arm)"),
     # lint: exempt[envs] -- read inside the generated notebook-cell SOURCE templates (oa/notebooks.py) and exported to kernels by oa/serve.py; no AST-visible read exists
     "ONIX_CONFIG": (
         "path",
@@ -96,9 +95,6 @@ ENV_REGISTRY: dict[str, tuple[str, str]] = {
         "daily supervisor drill override: ignore yesterday's model and "
         "fit every day cold (pipelines/daily.py) — daily.force_cold is "
         "the durable knob"),
-    "ONIX_DEVICE_WORDS": (
-        "flag: 0=host words",
-        "legacy spelling of ONIX_HOST_WORDS=1 (device_words gate)"),
     "ONIX_DP1_FAST": (
         "flag: 0=pin wrapped arm",
         "sharded engine dp=1/mp=1 shard_map-bypass fast path override"),
@@ -126,15 +122,9 @@ ENV_REGISTRY: dict[str, tuple[str, str]] = {
     "ONIX_HOST_WORDS": (
         "flag: 1=host builders",
         "force the host word-build cross-check arm (device_words gate)"),
-    "ONIX_NWK_FORM": (
-        "choice: auto|scatter|matmul|pallas",
-        "n_wk count-update form override (lda_gibbs.select_nwk_form)"),
-    "ONIX_NWK_MATMUL": (
-        "legacy flag: 1=matmul, 0=scatter",
-        "pre-r8 spelling of ONIX_NWK_FORM (make_block_step only)"),
     "ONIX_PALLAS_INTERPRET": (
         "flag: 1=interpret, 0=compiled",
-        "Pallas kernels: force interpret/compiled mode (pallas_gibbs)"),
+        "Pallas kernels: force interpret/compiled mode (pallas_serve)"),
     "ONIX_PREFETCH_DEPTH": (
         "int >= 1",
         "streaming ingest pipeline depth override (ColumnPrefetcher)"),
@@ -221,8 +211,8 @@ class LDAConfig:
     # one more K x Vc collective per sweep — cheap on ICI.
     sync_splits: int = 1
     # Gibbs fit superstep: sweeps chained inside ONE jitted program per
-    # dispatch (docs/PERF.md "the gibbs_fit vs sweep-microbench gap" —
-    # the old loop paid one dispatch per sweep plus separate likelihood
+    # dispatch (one dispatch and one host sync per superstep, where a
+    # sweep-at-a-time loop pays one per sweep plus separate likelihood
     # programs; the price of a dispatch on the chip is not measured).
     # The burn-in accumulate fold and the boundary log-likelihood run
     # on device inside the superstep; results are bit-identical to the
@@ -235,17 +225,8 @@ class LDAConfig:
     # Part of the checkpoint fingerprint: resuming under a different
     # superstep is refused, not silently different.
     superstep: int = 0
-    # n_wk count-update form inside the Gibbs block step: "auto" picks
-    # per backend + collision density at trace time (the measured gate,
-    # lda_gibbs.select_nwk_form — scatter on CPU, MXU one-hot matmul on
-    # TPU at density >= 32, the Pallas fused sample+count kernel once
-    # its TPU crossover lands in _NWK_PALLAS_MIN_DENSITY). Explicit
-    # values pin one form; all three are bit-identical (tested), so
-    # this knob is pure performance — it is NOT part of the checkpoint
-    # fingerprint and may change across a resume.
-    nwk_form: str = "auto"
     # Gibbs sampler form: "dense" keeps the O(K)-per-token block
-    # sampler (every arm of the nwk gate); "sparse" engages the r11
+    # sampler; "sparse" engages the r11
     # O(K_active) arm — per-document top-A active-topic sets compacted
     # into a static pow2 block, the dense-phi remainder proposed from
     # stale F+-tree-style CDF tables rebuilt each sweep, corrected by
@@ -254,8 +235,8 @@ class LDAConfig:
     # make_sparse_sweep). "auto" defers to the measured per-backend
     # _SAMPLER_SPARSE_MIN_K crossover tables (empty entries keep dense,
     # so defaults are unchanged until a platform is measured);
-    # ONIX_SAMPLER_FORM overrides for experiments. UNLIKE nwk_form the
-    # sparse arm is a different MCMC chain (same stationary
+    # ONIX_SAMPLER_FORM overrides for experiments. The sparse arm is a
+    # different MCMC chain (same stationary
     # distribution, different draws), so the RESOLVED form is part of
     # the checkpoint fingerprint: a resume across an arm change is
     # refused, never silently different.
@@ -331,10 +312,6 @@ class LDAConfig:
             raise ValueError("sync_splits must be >= 1")
         if self.superstep < 0:
             raise ValueError("superstep must be >= 0 (0 = auto)")
-        if self.nwk_form not in ("auto", "scatter", "matmul", "pallas"):
-            raise ValueError(
-                "lda.nwk_form must be auto|scatter|matmul|pallas, "
-                f"got {self.nwk_form!r}")
         if self.sampler_form not in ("auto", "dense", "sparse"):
             raise ValueError(
                 "lda.sampler_form must be auto|dense|sparse, "
@@ -495,9 +472,8 @@ class StoreConfig:
 class ServingConfig:
     """Model-bank serving (r12, `onix/serving/`): many tenants'
     (θ, φ) tables resident on device as stacked bank arrays, scored
-    through one batched program per request batch (docs/PERF.md
-    "model bank"). Consumed by the `/score` endpoint on `onix serve`
-    and by the load harness."""
+    through one batched program per request batch. Consumed by the
+    `/score` endpoint on `onix serve` and by the load harness."""
 
     # Empty means "derive from store.root" (<root>/models) at
     # validate() time — where run_scoring persists fitted models
@@ -727,8 +703,8 @@ class DailyConfig:
     # Sweep budget for a warm-started fit (φ̂-as-prior z-init, the
     # Streaming Gibbs treatment of arxiv 1601.01142). 0 = auto: half
     # the cold budget, floor 2 — the chain starts near the posterior,
-    # so the wall the daily loop pays is roughly halved (measured in
-    # docs/DAILY_r19_cpu.json; bench `daily_loop` tracks it per run).
+    # so the wall the daily loop pays is roughly halved (on a CPU:
+    # docs/DAILY_r19_cpu.json).
     warm_sweeps: int = 0
     # Burn-in for a warm-started fit. 0 = auto: 1 sweep — the warm
     # chain needs settling, not re-convergence, so posterior averaging

@@ -28,8 +28,7 @@ pair is all-ones) to a pow2 length, so an EMPTY filter is an
 all-sentinel table whose membership mask is constant False, and
 `jnp.where(False, ·, s)` returns s unchanged — the filtered scan with
 a filter of zero entries is bit-identical to the unfiltered scan
-(tested, and asserted per run by bench.py's `feedback_rescore`
-component).
+(tested).
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ SAME `_scan_bottom_k` machinery (chunking, pad masking, running
 bottom-k merge, tie rule, -1 sentinel), so a fix to selection logic
 still lands in exactly one place and a filtered scan with an empty
 filter is bit-identical to the unfiltered scan (filter.py exactness
-contract; asserted per run by bench.py's `feedback_rescore`).
+contract; tests/test_feedback.py).
 
 Key streams ride the scan as extra chunked columns: the event's word
 id (its word key — hi half is an implicit 0) and the packed pair

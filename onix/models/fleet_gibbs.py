@@ -233,9 +233,8 @@ def stack_tenants(tenants: list[TenantDay], *, k_topics: int, seed: int,
 
 
 def padding_stats(classes: list[ShapeClass]) -> dict:
-    """Shape-class padding waste accounting (docs/PERF.md "fleet
-    refit"): how much of the stacked token/table volume is pow2
-    padding rather than real tenant mass."""
+    """Shape-class padding waste accounting: how much of the stacked
+    token/table volume is pow2 padding rather than real tenant mass."""
     real = sum(c.tokens_real for c in classes)
     padded = sum(c.tokens_padded for c in classes)
     return {
@@ -300,7 +299,7 @@ def nudge_digest(t: TenantDay) -> str | None:
 
 
 def _make_refit_body(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
-                     nwk_form: str | None, sampler_form: str | None,
+                     sampler_form: str | None,
                      sparse_active: int, sampler: str | None):
     """One tenant lane's refit, sweep kernel shared with every other
     engine: count build -> nudge -> S sweeps (burn-in-gated posterior
@@ -308,8 +307,7 @@ def _make_refit_body(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
     alpha, eta, k = cfg.alpha, cfg.eta, cfg.n_topics
     n_sweeps, burn_in = cfg.n_sweeps, cfg.burn_in
     kernel = make_sweep_kernel(alpha=alpha, eta=eta, n_vocab=n_vocab,
-                               k_topics=k, nwk_form=nwk_form,
-                               sampler_form=sampler_form,
+                               k_topics=k, sampler_form=sampler_form,
                                sparse_active=sparse_active,
                                sampler=sampler)
 
@@ -373,7 +371,6 @@ def _make_refit_body(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
 
 
 def make_fleet_refit(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
-                     nwk_form: str | None = None,
                      sampler_form: str | None = None,
                      sparse_active: int = 0,
                      sampler: str | None = None):
@@ -383,7 +380,7 @@ def make_fleet_refit(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
     keys) -> (theta [T,D,K], phi_wk [T,V,K], ll0 [T], ll_final [T]);
     `keys` is the uint32 [T, 2] lane-key array from stack_tenants."""
     body = _make_refit_body(cfg, n_docs=n_docs, n_vocab=n_vocab,
-                            nwk_form=nwk_form, sampler_form=sampler_form,
+                            sampler_form=sampler_form,
                             sparse_active=sparse_active, sampler=sampler)
 
     def fleet(z0, docs, words, mask, fb_d, fb_w, fb_wt, keys):
@@ -393,15 +390,14 @@ def make_fleet_refit(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
 
 
 def make_tenant_refit(cfg: LDAConfig, *, n_docs: int, n_vocab: int,
-                      nwk_form: str | None = None,
                       sampler_form: str | None = None,
                       sparse_active: int = 0,
                       sampler: str | None = None):
     """The SAME refit body without the tenant vmap — the sequential
     supervisor arm (one dispatch per tenant), and the per-lane parity
-    reference the bench asserts against every run."""
+    reference (tests/test_fleet.py)."""
     body = _make_refit_body(cfg, n_docs=n_docs, n_vocab=n_vocab,
-                            nwk_form=nwk_form, sampler_form=sampler_form,
+                            sampler_form=sampler_form,
                             sparse_active=sparse_active, sampler=sampler)
 
     def one(z0, docs, words, mask, fb_d, fb_w, fb_wt, key):

@@ -131,139 +131,56 @@ def init_chains(
                                    n_docs, n_vocab, n_topics))(keys)
 
 
-# Table width up to which the n_wk delta goes through an MXU one-hot
-# matmul instead of a scatter-add on TPU. Rationale: the sweep is
-# scatter-bound (docs/PERF.md), and with product vocabularies (V in the
-# hundreds) the n_wk scatter is COLLISION-dense — a 2^17-token block
-# lands ~B/V ~ 250 colliding row-updates per word. The matmul form
-# computes the same [V, K] delta as onehot(w)^T @ delta on the MXU:
-# B*V*K MACs (~1.4e9 at the cap — microseconds) plus one [B, V] bf16
-# one-hot materialization, with NO serialized collisions. Exact by
-# construction: operands are {-1, 0, 1} (exact in bf16), accumulation
-# is f32, and each output magnitude is <= B = 2^17 << 2^24. The n_dk
-# scatter keeps its scatter form — documents are nearly collision-free
-# within a block and D is far too large to one-hot.
+# The n_wk count update has two bit-identical forms (the tests compare
+# them, tests/test_nwk_form.py): a scatter-add of the [B, K] delta rows,
+# and onehot(w)^T @ delta as one bf16 matmul with f32 accumulation,
+# which has no serialized collisions where a block lands hundreds of
+# row updates on each word of a product vocabulary. The n_dk update
+# stays a scatter: documents hardly collide within a block and D is far
+# too large to one-hot. select_nwk_form picks from the backend and two
+# static shapes, under three caps:
+#   * exactness - operands are {-1, 0, 1} (exact in bf16) and every
+#     output is a sum of block_size of them, so block_size < 2^24 keeps
+#     the f32 accumulation an exact integer;
+#   * _NWK_MATMUL_MAX_V - the widest table worth a one-hot;
+#   * _NWK_MATMUL_MAX_ELEMS - the [B, V] bf16 one-hot temporary, 2^27
+#     elements = 256 MB (x n_chains under vmap); block 2^17 at V 4096
+#     would otherwise grow a 1 GiB temporary the scatter never had.
+# _NWK_MATMUL_MIN_DENSITY: block_size / V from which a backend takes
+# the matmul form. cpu has no entry: tier-1 runs there, on the scatter.
+# The tpu threshold is a placement, not a measured crossover; the one
+# chip reading is flow-fit's, on the matmul side of it: onix.sweep.nwk
+# 0.323 s of a 5.742 s sweep at V 537-555, B 2^17 (ledger PR 30;
+# PERF.md section 5). A backend without an entry keeps the scatter.
 _NWK_MATMUL_MAX_V = 4096
-# Auto-enable also bounds the [B, V] one-hot temporary (bf16 elements):
-# 2^27 = 256 MB. A block_size 2^17 sweep at V=4096 would otherwise grow
-# a 1 GiB temporary (x n_chains under the vmap engine) that the scatter
-# form never allocated — an OOM regression, not a speedup. Forcing
-# nwk_matmul=True bypasses the bound for experiments.
 _NWK_MATMUL_MAX_ELEMS = 1 << 27
-# Collision-density crossover per backend: the auto gate engages the
-# matmul form only when the n_wk scatter is collision-DENSE, measured
-# as density = block_size / V (expected colliding row-updates per vocab
-# row per block), instead of the old backend-only rule ("any V <= 4096
-# on an accelerator"). The decision table lives in docs/PERF.md ("the
-# gibbs_fit vs sweep-microbench gap"), fed by scripts/exp_fit_gap.py
-# (raw_nwk_scatter vs raw_nwk_matmul on the real corpus shape; a tiny
-# CPU smoke of the same harness runs in tier-1 so it cannot rot):
-#   * cpu — NO entry: the matmul form measured ~4x SLOWER than the
-#     scatter at the densest judged shape (V=289, B=2^17, density ~450;
-#     PERF.md r7 rows). B*V*K host MACs never beat a cache-resident
-#     scatter here, so CPU stays on the scatter at every density.
-#   * tpu — engage at density >= 32: the V=4096/B=2^16 microbench
-#     (density 16) measured the scatter as acceptable (35-37 Mtok/s,
-#     PERF.md "the exponential race"), so the crossover sits strictly
-#     above it; judged product vocabularies (V~500, B=2^17, density
-#     ~260) engage exactly as the old gate did. The scatter-vs-
-#     matmul crossover is not measured on the chip: the threshold is
-#     a placement, and moves to the measured crossover when one lands.
-# Unmeasured accelerators (gpu) get no entry and keep the scatter —
-# the same "measured platforms only" policy as scoring's bf16 gate.
 _NWK_MATMUL_MIN_DENSITY = {"tpu": 32.0}
-# Third arm of the n_wk gate: the Pallas fused sample+count kernel
-# (onix/models/pallas_gibbs.py) — removes the scatter's collision
-# serialization entirely (per-tile MXU count-merge into a VMEM-resident
-# accumulator) instead of out-muscling it with the HBM one-hot matmul.
-# Same "measured platforms only" policy: the table is EMPTY — scatter
-# vs matmul vs pallas on the judged shape (scripts/exp_fit_gap.py) is
-# not measured on the chip; the crossover density lands here, expected
-# to sit at/below the matmul's 32. Until
-# then the kernel is reachable via nwk_form="pallas" /
-# ONIX_NWK_FORM=pallas (and runs interpret-mode bit-identity in
-# tier-1), so the default path on every backend is unchanged.
-_NWK_PALLAS_MIN_DENSITY: dict[str, float] = {}
 
 
-def nwk_pallas_auto_reachable(backend: str) -> bool:
-    """Whether the AUTO n_wk gate could resolve "pallas" on `backend` —
-    the capability probe ShardedGibbsLDA uses to drop the shard_map
-    static replication check (shard_map has no replication rule for
-    pallas_call) exactly when the pallas arm might trace. NOT a form
-    decision: the form itself still resolves through select_nwk_form's
-    resolve_form_gate chain — this only answers "is the pallas row of
-    that gate's table populated for this backend"."""
-    # lint: exempt[gates] -- capability probe next to the table it reads; the form decision still goes through select_nwk_form's resolve_form_gate chain
-    return _NWK_PALLAS_MIN_DENSITY.get(backend) is not None
-
-
-def env_nwk_form() -> str | None:
-    """Resolve the ONIX_NWK_FORM experiment override. "auto" (and
-    empty) mean None — the same spelling LDAConfig.nwk_form accepts for
-    "defer to the measured gate" — so exporting ONIX_NWK_FORM=auto
-    resets an inherited override instead of crashing; anything else is
-    validated by select_nwk_form at trace time. Read this ONCE per
-    engine/trace decision: the sharded engine keys its shard_map
-    replication-check drop off the same resolved value it samples with,
-    so the two can never disagree mid-session."""
-    import os
-    env = os.environ.get("ONIX_NWK_FORM")
-    if not env or env == "auto":
-        return None
-    return env
-
-
+# lint: exempt[gates] -- no env or config layer to order: pin, else table
 def select_nwk_form(*, backend: str, block_size: int, n_rows: int,
-                    nwk_matmul: bool | None = None,
                     nwk_form: str | None = None) -> str:
-    """Trace-time decision for the n_wk count-update form — the single
-    gate shared by every engine (tests/test_pallas_gibbs.py exercises
-    its edge cases directly).
-
-    Priority (config.resolve_form_gate — the ONE precedence chain
-    shared with `select_bank_form` and `select_serve_form`, so the
-    three gate tables cannot drift): explicit `nwk_form` ("scatter" |
-    "matmul" | "pallas"), then the legacy `nwk_matmul` bool, then the
-    measured per-backend collision-density tables (density =
-    block_size / n_rows expected colliding row-updates per count row
-    per block) bounded by the exactness/memory caps. No env layer
-    HERE: the engines resolve ONIX_NWK_FORM themselves (env_nwk_form),
-    where an explicit test-arm pin must outrank an exported override
-    (make_block_step's documented contract), and pass the result in as
-    `nwk_form`. All three forms are bit-identical; this picks the
-    measured-fastest one for the platform and shape.
-    """
-    from onix.config import resolve_form_gate
-    explicit = nwk_form
-    if explicit is None and nwk_matmul is not None:
-        explicit = "matmul" if nwk_matmul else "scatter"
-
-    def measured() -> str | None:
-        pallas_density = _NWK_PALLAS_MIN_DENSITY.get(backend)
-        if (pallas_density is not None
-                and block_size >= pallas_density * n_rows
-                and n_rows <= _NWK_MATMUL_MAX_V):
-            return "pallas"
-        min_density = _NWK_MATMUL_MIN_DENSITY.get(backend)
-        if (min_density is not None
-                and block_size >= min_density * n_rows
-                and n_rows <= _NWK_MATMUL_MAX_V
-                # Exactness bound: every output of the f32 accumulation
-                # is a sum of block_size {-1,0,1} terms, so |output| <=
-                # block_size must stay below 2^24 or integers stop
-                # being representable exactly. MAX_ELEMS implies it for
-                # V >= 8 only; the explicit bound covers tiny-V/huge-B
-                # days.
-                and block_size < (1 << 24)
-                and block_size * n_rows <= _NWK_MATMUL_MAX_ELEMS):
-            return "matmul"
-        return None
-
-    return resolve_form_gate(gate="nwk_form",
-                             choices=("scatter", "matmul", "pallas"),
-                             explicit=explicit, measured=measured,
-                             default="scatter")
+    """The n_wk count-update form, "scatter" or "matmul", decided at
+    trace time and nowhere else: the tests' pin `nwk_form` if given,
+    else "matmul" where `_NWK_MATMUL_MIN_DENSITY` has an entry for
+    `backend`, the block is that dense (block_size / n_rows) and the
+    exactness and memory caps hold, else "scatter". Reads no
+    environment and no config: every engine gets what its backend and
+    shapes resolve to."""
+    if nwk_form is not None:
+        if nwk_form not in ("scatter", "matmul"):
+            raise ValueError(
+                f"nwk_form must be scatter|matmul, got {nwk_form!r}")
+        return nwk_form
+    # lint: exempt[gates] -- this function is the table's one gate
+    min_density = _NWK_MATMUL_MIN_DENSITY.get(backend)
+    if (min_density is not None
+            and block_size >= min_density * n_rows
+            and n_rows <= _NWK_MATMUL_MAX_V
+            and block_size < (1 << 24)
+            and block_size * n_rows <= _NWK_MATMUL_MAX_ELEMS):
+        return "matmul"
+    return "scatter"
 
 
 # ---------------------------------------------------------------------------
@@ -291,11 +208,11 @@ def select_nwk_form(*, backend: str, block_size: int, n_rows: int,
 # Crossover tables follow the measured-platforms-only policy of the
 # n_wk gate: auto engages the sparse arm only where a committed
 # measurement says it wins, keyed by K (the axis the win scales with).
-#   * cpu — K >= 64: measured on this 2-core host
-#     (docs/SPARSE_r11_cpu.json, exp_fit_gap 2e6 --k-sweep {16,64,256}):
+#   * cpu — K >= 64: measured on a 2-core host
+#     (docs/SPARSE_r11_cpu.json, a 2e6-token K sweep {16,64,256}):
 #     sparse/dense per-token fit cost 0.87x at K=16 (A=8), 1.80x at
-#     K=64 (A=8), 4.46x at K=256 (A=16, mh=2); 2.80x at K=256 on the
-#     bench shape (docs/SPARSE_r11_bench_cpu.json). 64 is the LOWEST
+#     K=64 (A=8), 4.46x at K=256 (A=16, mh=2); 2.80x at K=256 on a
+#     second shape (docs/SPARSE_r11_bench_cpu.json). 64 is the LOWEST
 #     MEASURED K where the sparse arm wins (the true crossover sits
 #     somewhere in (16, 64), unmeasured). The crossover sits above the
 #     judged K=20 pipelines — defaults there are unchanged.
@@ -307,8 +224,8 @@ _SAMPLER_SPARSE_MIN_K: dict[str, float] = {"cpu": 64.0}
 
 def env_sampler_form() -> str | None:
     """Resolve the ONIX_SAMPLER_FORM experiment override. "auto" (and
-    empty) mean None — defer to the measured gate — mirroring
-    env_nwk_form. Engines read this ONCE at construction: the resolved
+    empty) mean None — defer to the measured gate. Engines read this
+    ONCE at construction: the resolved
     form joins the checkpoint fingerprint, so the compiled sampler and
     the resume identity can never disagree."""
     import os
@@ -324,13 +241,11 @@ def select_sampler_form(*, backend: str, k_topics: int,
     the gate shared by GibbsLDA and ShardedGibbsLDA.
 
     Priority (config.resolve_form_gate — the ONE precedence chain
-    shared with select_nwk_form / select_bank_form /
-    select_serve_form, r17: this gate was the last hand-rolled chain):
-    explicit `sampler_form`, then the measured per-backend K crossover
+    shared with select_bank_form / select_serve_form): explicit
+    `sampler_form`, then the measured per-backend K crossover
     (_SAMPLER_SPARSE_MIN_K; unmeasured platforms keep dense). No env
     layer HERE: the engines resolve ONIX_SAMPLER_FORM themselves
-    (_resolved_sampler_form), where the dense-pin deference must sit
-    BETWEEN the env and the measured table, and hand the result in as
+    (_resolved_sampler_form), config first, and hand the result in as
     `sampler_form`. An explicit "sparse" is honored at ANY K — at tiny
     K the top-A block simply saturates (A == K)."""
     from onix.config import resolve_form_gate
@@ -378,44 +293,31 @@ def merge_fingerprint(form: str, staleness: int) -> dict:
     return {"merge": [form, int(staleness)]}
 
 
-def _resolved_sampler_form(sampler_form: str | None, *, k_topics: int,
-                           pinned: bool) -> str:
+def _resolved_sampler_form(sampler_form: str | None, *,
+                           k_topics: int) -> str:
     """The ONE deference chain behind every sampler-form decision —
-    explicit form, then ONIX_SAMPLER_FORM, then dense when a
-    dense-only knob is pinned (an n_wk form or a block-sampler draw
-    form, argument or ONIX_NWK_FORM: the sparse arm has neither knob,
-    so auto stealing a pinned run would silently mislabel that
-    experiment), then the measured gate. Shared by resolve_sampler
-    (both engines) and make_sweep_kernel (standalone callers) so a
-    policy change can never make them resolve different arms for the
-    same config/env."""
+    explicit form, then ONIX_SAMPLER_FORM, then the measured gate.
+    Shared by resolve_sampler (both engines) and make_sweep_kernel
+    (standalone callers) so a policy change can never make them resolve
+    different arms for the same config/env."""
     form = sampler_form
     if form is None:
         form = env_sampler_form()
-    if form is None and (pinned or env_nwk_form() is not None):
-        form = "dense"
     return select_sampler_form(backend=jax.default_backend(),
                                k_topics=k_topics, sampler_form=form)
 
 
-def resolve_sampler(config, *, k_topics: int,
-                    nwk_form: str | None = None) -> tuple[str, int, dict]:
+def resolve_sampler(config, *, k_topics: int) -> tuple[str, int, dict]:
     """The ONE construction-time sampler resolution shared by GibbsLDA
     and ShardedGibbsLDA: config (explicit lda.sampler_form beats all),
-    then ONIX_SAMPLER_FORM, then — only for the measured auto gate —
-    deference to an explicit n_wk pin (a user who pinned
-    nwk_form=matmul/pallas is running an n_wk experiment; the sparse
-    arm has no n_wk form, so auto silently stealing the run would
-    mislabel their measurement — auto stays dense instead; an explicit
-    sampler_form/env still wins), then _SAMPLER_SPARSE_MIN_K. Returns
+    then ONIX_SAMPLER_FORM, then _SAMPLER_SPARSE_MIN_K. Returns
     (form, resolved_active, kwargs-for-make_sweep_kernel); the form
     feeds both the compiled programs and the checkpoint fingerprint,
     so keeping this in one place is what keeps the two engines from
     ever resolving different arms for the same config."""
     sform = (None if config.sampler_form == "auto"
              else config.sampler_form)
-    form = _resolved_sampler_form(sform, k_topics=k_topics,
-                                  pinned=nwk_form is not None)
+    form = _resolved_sampler_form(sform, k_topics=k_topics)
     active = resolve_sparse_active(k_topics, config.sparse_active)
     return form, active, dict(sampler_form=form, sparse_active=active,
                               sparse_mh=config.sparse_mh)
@@ -639,7 +541,6 @@ def make_sparse_block_step(*, alpha: float, eta: float, v_eta: float,
 
 def make_sweep_kernel(*, alpha: float, eta: float, n_vocab: int,
                       k_topics: int, nwk_form: str | None = None,
-                      nwk_matmul: bool | None = None,
                       sampler_form: str | None = None,
                       sparse_active: int = 0, sparse_mh: int = 2,
                       sampler: str | None = None):
@@ -652,17 +553,13 @@ def make_sweep_kernel(*, alpha: float, eta: float, n_vocab: int,
     (z, n_dk, n_wk, n_k, key). The sparse form rebuilds its stale
     proposal tables from the sweep-start counts on every call (table
     freshness is a per-sweep property, independent of how many sweeps
-    a dispatch fuses)."""
-    form = _resolved_sampler_form(
-        sampler_form, k_topics=k_topics,
-        pinned=(nwk_form is not None or nwk_matmul is not None
-                or sampler is not None))
+    a dispatch fuses). `nwk_form` and `sampler` are make_block_step's
+    test pins, handed on to the dense form."""
+    form = _resolved_sampler_form(sampler_form, k_topics=k_topics)
     if form == "dense":
         block_step = make_block_step(alpha=alpha, eta=eta,
                                      n_vocab=n_vocab, k_topics=k_topics,
-                                     nwk_form=nwk_form,
-                                     nwk_matmul=nwk_matmul,
-                                     sampler=sampler)
+                                     nwk_form=nwk_form, sampler=sampler)
 
         def kernel(z, n_dk, n_wk, n_k, key, docs, words, mask):
             (n_dk, n_wk, n_k, key), z = jax.lax.scan(
@@ -687,8 +584,7 @@ def make_sweep_kernel(*, alpha: float, eta: float, n_vocab: int,
 
 
 def make_block_step(*, alpha: float, eta: float, n_vocab: int,
-                    k_topics: int, nwk_matmul: bool | None = None,
-                    nwk_form: str | None = None,
+                    k_topics: int, nwk_form: str | None = None,
                     sampler: str | None = None):
     """The collapsed-Gibbs block sampler shared by the single-device and
     sharded engines — one definition so the documented dp=1 equivalence
@@ -696,17 +592,16 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
 
     carry = (n_dk, n_wk, n_k, key); xs = (docs, words, mask, z_old).
 
-    `nwk_form`: force the n_wk count-update form ("scatter" |
-    "matmul" | "pallas"); `nwk_matmul` is the legacy bool spelling
-    (True = matmul, False = scatter). None picks at trace time via
-    `select_nwk_form` — the measured per-backend collision-density gate
-    (ONIX_NWK_FORM / ONIX_NWK_MATMUL override for experiments). All
+    `nwk_form` is the tests' pin of the n_wk count-update form
+    ("scatter" | "matmul"; the bit-identity tests compare the two
+    through it). No engine passes it: None lets `select_nwk_form` pick
+    at trace time from the backend and the block's static shapes. Both
     forms produce bit-identical int32 counts and the same z stream.
 
     `sampler`: force the categorical draw form ("gumbel" | "race");
-    None keeps the measured per-backend pick (gumbel on accelerators,
-    race on CPU — docs/PERF.md "exponential race"). Test-only knob: it
-    lets CPU tier-1 assert the TPU sampler's math bit-for-bit.
+    None keeps the per-backend pick (gumbel on accelerators, race on
+    CPU). Test-only knob: it lets CPU tier-1 assert the TPU sampler's
+    math bit-for-bit.
     """
     v_eta = n_vocab * eta
     # Sampler form is picked once at trace time; it is a platform
@@ -718,66 +613,20 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
         use_gumbel = sampler == "gumbel"
     else:
         raise ValueError(f"sampler must be gumbel|race, got {sampler!r}")
-    import os
-    # Env overrides apply only when the caller passed NO explicit form
-    # (either spelling) — an explicit nwk_matmul/nwk_form argument must
-    # outrank an exported experiment override, or the test arms that
-    # pin forms would silently compare a form against itself.
-    if nwk_form is None and nwk_matmul is None:
-        nwk_form = env_nwk_form()
-        if nwk_form is None:
-            env = os.environ.get("ONIX_NWK_MATMUL")
-            if env in ("0", "1"):
-                nwk_matmul = env == "1"
 
     def block_step(carry, xs):
         n_dk, n_wk, n_k, key = carry
         d, w, m, z_old = xs
         key, skey = jax.random.split(key)
-        # n_wk shape is static under trace, so the form choice resolves
-        # to ONE compiled path. The auto gate is the measured collision-
-        # density crossover table (select_nwk_form / the module comments
-        # at _NWK_MATMUL_MIN_DENSITY and _NWK_PALLAS_MIN_DENSITY).
+        # n_wk's shape is static under trace, so the form resolves to
+        # ONE compiled path.
         form = select_nwk_form(backend=backend, block_size=w.shape[0],
-                               n_rows=n_wk.shape[0],
-                               nwk_matmul=nwk_matmul, nwk_form=nwk_form)
+                               n_rows=n_wk.shape[0], nwk_form=nwk_form)
         if form == "matmul" and w.shape[0] >= (1 << 24):
             raise ValueError(
                 f"nwk matmul form with block size {w.shape[0]} >= 2^24: "
                 "the one-hot matmul's f32 accumulation is no longer "
                 "bit-exact at this block size")
-        if form == "pallas":
-            # Fused sample + count-merge kernel: the SAME skey feeds one
-            # noise draw at the reference's [B, K] shape, so the key
-            # stream is untouched; sampling and the collision-dense
-            # n_wk delta run inside the kernel (pallas_gibbs module doc)
-            # and the n_dk scatter stays here (collision-free).
-            from onix.models import pallas_gibbs
-            shape = (w.shape[0], k_topics)
-            with device_scope("onix.sweep.gather"):
-                ndk_rows, nwk_rows = n_dk[d], n_wk[w]
-            # The kernel samples and counts the n_wk delta in one pass:
-            # both are booked to the sampling scope.
-            with device_scope("onix.sweep.sample"):
-                if use_gumbel:
-                    noise = jax.random.gumbel(skey, shape,
-                                              dtype=jnp.float32)
-                else:
-                    noise = jax.random.uniform(skey, shape,
-                                               dtype=jnp.float32,
-                                               minval=1e-38)
-                z_new, d_wk = pallas_gibbs.sample_count_block(
-                    ndk_rows, nwk_rows, n_k, noise, w, z_old, m,
-                    alpha=alpha, eta=eta, v_eta=v_eta, k_topics=k_topics,
-                    n_rows=n_wk.shape[0], use_gumbel=use_gumbel)
-                delta = (_one_hot(z_new, k_topics)
-                         - _one_hot(z_old, k_topics))
-            with device_scope("onix.sweep.scatter"):
-                n_dk = n_dk.at[d].add(delta)
-            with device_scope("onix.sweep.nwk"):
-                n_wk = n_wk + d_wk
-                n_k = n_k + delta.sum(axis=0, dtype=jnp.int32)
-            return (n_dk, n_wk, n_k, key), z_new
         # Device scopes onix.sweep.* (docs/OBSERVABILITY.md): the
         # profiler books each op's time to the scope it was traced in.
         with device_scope("onix.sweep.gather"):
@@ -788,20 +637,17 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
             nwk = n_wk[w].astype(jnp.float32) - ohf
             nk = n_k.astype(jnp.float32)[None, :] - ohf
         # Categorical sampling — two statistically identical forms,
-        # chosen per backend at trace time (docs/PERF.md "exponential
-        # race", measured both ways on both platforms):
+        # chosen per backend at trace time:
         #   * CPU: exponential race z = argmax p_k/e_k, e~Exp(1) — the
         #     Gumbel-argmax trick in LINEAR space at one log per
-        #     element instead of four; measured 1.75x faster (the
-        #     transcendentals dominate on CPU). Per-element products
-        #     keep full relative precision — no cumsum, so no
-        #     rare-topic rounding (why inverse-CDF was rejected: a
-        #     linear f32 cumsum makes transitions to topics below
-        #     ~2^-24 of the total exactly impossible).
+        #     element instead of four (the transcendentals dominate on
+        #     CPU). Per-element products keep full relative precision —
+        #     no cumsum, so no rare-topic rounding (why inverse-CDF was
+        #     rejected: a linear f32 cumsum makes transitions to topics
+        #     below ~2^-24 of the total exactly impossible).
         #   * TPU: classic log-space Gumbel-argmax — the sweep is
-        #     scatter-bound there so extra transcendentals are free,
-        #     and log space measured ~5% faster (37.5 vs 35.8 Mtok/s,
-        #     scripts/exp_gibbs_sweep.py on v5lite).
+        #     scatter-bound there (onix.sweep.sample is 16% of a sweep:
+        #     PERF.md section 5), so the extra transcendentals hide.
         with device_scope("onix.sweep.sample"):
             if use_gumbel:
                 logp = (jnp.log(ndk + alpha)
@@ -851,7 +697,6 @@ def sweep(
     eta: float,
     n_vocab: int,
     accumulate,
-    nwk_form: str | None = None,
     sampler_form: str | None = None,
     sparse_active: int = 0,
     sparse_mh: int = 2,
@@ -870,7 +715,7 @@ def sweep(
     platforms and everywhere below the crossover)."""
     k_topics = state.n_dk.shape[1]
     kernel = make_sweep_kernel(alpha=alpha, eta=eta, n_vocab=n_vocab,
-                               k_topics=k_topics, nwk_form=nwk_form,
+                               k_topics=k_topics,
                                sampler_form=sampler_form,
                                sparse_active=sparse_active,
                                sparse_mh=sparse_mh)
@@ -907,7 +752,6 @@ def superstep(
     burn_in: int,
     start_sweep,
     n_steps: int,
-    nwk_form: str | None = None,
     sampler_form: str | None = None,
     sparse_active: int = 0,
     sparse_mh: int = 2,
@@ -928,7 +772,7 @@ def superstep(
         return sweep(st, doc_blocks, word_blocks, mask_blocks,
                      alpha=alpha, eta=eta, n_vocab=n_vocab,
                      accumulate=start_sweep + i >= burn_in,
-                     nwk_form=nwk_form, sampler_form=sampler_form,
+                     sampler_form=sampler_form,
                      sparse_active=sparse_active,
                      sparse_mh=sparse_mh), None
 
@@ -1056,31 +900,11 @@ def log_likelihood(
         return total / jnp.maximum(n, 1.0)
 
 
-# Relative predictive-ll band within which the sparse arm must land on
-# the dense arm — the gate-arm parity contract asserted by BOTH
-# decision harnesses (bench.gibbs_sweep_sparse and exp_fit_gap
-# --k-sweep), shared so the committed decision tables and the per-run
-# bench assertion can never measure different contracts.
+# Relative predictive-ll band within which a different chain with the
+# same stationary target (the sparse arm, the async merge) must land on
+# its reference, and below which a refit's final ll may not fall under
+# its initial one (pipelines/daily.py, pipelines/fleet.py).
 LL_PARITY_BAND = 0.05
-
-
-def counts_log_likelihood(
-    n_dk: jax.Array, n_wk: jax.Array, n_k: jax.Array,
-    doc_blocks: jax.Array, word_blocks: jax.Array, mask_blocks: jax.Array,
-    *, alpha: float, eta: float,
-) -> float:
-    """Mean per-token log p(w|d) straight from instantaneous raw counts
-    — the smoothing formula of posterior_estimates without the
-    accumulator plumbing, for harnesses that time raw sweep kernels and
-    hold (n_dk, n_wk, n_k) rather than a GibbsState."""
-    ndk = n_dk.astype(jnp.float32)
-    nwk = n_wk.astype(jnp.float32)
-    theta = (ndk + alpha) / (ndk.sum(-1, keepdims=True)
-                             + ndk.shape[1] * alpha)
-    phi = (nwk + eta) / (n_k.astype(jnp.float32)[None, :]
-                         + nwk.shape[0] * eta)
-    return float(log_likelihood(theta, phi, doc_blocks, word_blocks,
-                                mask_blocks))
 
 
 class GibbsLDA:
@@ -1097,34 +921,27 @@ class GibbsLDA:
         self.n_docs = n_docs
         self.n_vocab = n_vocab
         chains = config.n_chains
-        # "auto" defers to the measured per-backend gate at trace time;
-        # an explicit config form pins it (select_nwk_form validates).
-        form = None if config.nwk_form == "auto" else config.nwk_form
         # Sampler form resolves ONCE here (resolve_sampler: config,
-        # then ONIX_SAMPLER_FORM, then nwk-pin deference, then the
-        # measured gate) — the RESOLVED value feeds both the compiled
-        # programs and the checkpoint fingerprint, so the two can never
-        # disagree and a resume across an arm change is refused (the
-        # sparse arm is a different chain, not a bit-identical form
-        # like nwk).
+        # then ONIX_SAMPLER_FORM, then the measured gate) — the
+        # RESOLVED value feeds both the compiled programs and the
+        # checkpoint fingerprint, so the two can never disagree and a
+        # resume across an arm change is refused (the sparse arm is a
+        # different chain, not a bit-identical form like n_wk's two).
         self.sampler_form, self.sparse_active, sampler_kw = \
-            resolve_sampler(config, k_topics=config.n_topics,
-                            nwk_form=form)
+            resolve_sampler(config, k_topics=config.n_topics)
         base_sweep = functools.partial(
             sweep, alpha=config.alpha, eta=config.eta, n_vocab=n_vocab,
-            nwk_form=form, **sampler_kw)
+            **sampler_kw)
         base_super = functools.partial(
             superstep, alpha=config.alpha, eta=config.eta,
-            n_vocab=n_vocab, burn_in=config.burn_in, nwk_form=form,
-            **sampler_kw)
+            n_vocab=n_vocab, burn_in=config.burn_in, **sampler_kw)
         base_est = functools.partial(
             posterior_estimates, alpha=config.alpha, eta=config.eta)
         # donate_argnums=(0,): the incoming GibbsState's buffers are
         # dead the moment the dispatch returns (every caller rebinds),
         # so XLA reuses them for the output counts instead of copying
-        # the [D,K]+[V,K] tables every sweep — the sharded engine has
-        # donated since r7 (sharded_gibbs.py); this brings the plain
-        # engine level.
+        # the [D,K]+[V,K] tables every sweep, as the sharded engine
+        # does.
         if chains == 1:
             self._sweep = jax.jit(base_sweep,
                                   static_argnames=("accumulate",),
@@ -1135,14 +952,11 @@ class GibbsLDA:
             # The fit loop's unit of dispatch: n_steps sweeps chained in
             # one program, with the boundary log-likelihood fused in —
             # the ll gathers run on device right behind the last sweep
-            # instead of costing two more dispatches (docs/PERF.md "the
-            # gibbs_fit vs sweep-microbench gap", hypotheses A/D).
+            # instead of costing two more dispatches.
             # `with_initial_ll` additionally evaluates ll on the
             # INCOMING state (fit's pre-sweep ll_history point), so the
             # whole first segment — initial ll, S sweeps, boundary ll —
-            # is ONE dispatch; measured worth ~14% of the CPU fit wall
-            # (the standalone ll's sync + dispatch-boundary allocator
-            # churn, not its compute).
+            # is ONE dispatch and one host sync.
             def superstep_ll(state, d, w, m, start, n_steps,
                              with_initial_ll=False):
                 ll0 = None
@@ -1208,13 +1022,12 @@ class GibbsLDA:
         """Run the fit loop as fused supersteps: sweeps are chained S at
         a time inside one jitted program (`superstep`), with the burn-in
         accumulate fold and the boundary log-likelihood on device — one
-        dispatch and one host sync per S sweeps instead of per sweep
-        (docs/PERF.md "the gibbs_fit vs sweep-microbench gap"). Segment
-        boundaries land exactly on checkpoint/fault/final sweeps
+        dispatch and one host sync per S sweeps instead of per sweep.
+        Segment boundaries land exactly on checkpoint/fault/final sweeps
         (`plan_segments`), and a per-sweep `callback` collapses segments
         to single sweeps, so host-visible behavior at every boundary is
         unchanged; the chained loop is bit-identical to sweep-at-a-time
-        (tested). Like the sharded engine (since r7), the dispatch
+        (tested). Like the sharded engine, the dispatch
         donates the incoming state's buffers: a `callback` that wants
         to RETAIN anything across sweeps must materialize it
         (np.asarray) inside the callback — holding the state's jax

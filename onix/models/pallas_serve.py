@@ -7,17 +7,16 @@ batched gather/matmul scoring, the r13 feedback membership search
 CPU, docs/FEEDBACK_r13_cpu.json), and the chunked bottom-M scan — each
 round-tripping the [chunk] candidate scores through HBM between
 programs. This module collapses them into ONE `pallas_call` per
-request, in the r8 `pallas_gibbs.py` mold (ROADMAP item 3; the
-bounded-staleness literature the fit layer builds on — AD-LDA, arxiv
-0909.4603; Streaming Gibbs, arxiv 1601.01142 — makes the same
-argument: keep hot state resident, defer the global exchange; here the
-hot state is the winner buffer and the filter tables).
+request (the bounded-staleness literature the fit layer builds on —
+AD-LDA, arxiv 0909.4603; Streaming Gibbs, arxiv 1601.01142 — makes the
+same argument: keep hot state resident, defer the global exchange;
+here the hot state is the winner buffer and the filter tables).
 
 One grid step per token tile. Per tile (all VMEM-resident):
 
   1. scoring — mode "dot": the gathered theta[d]/phi[w] rows come in as
-     [tile, K] blocks (gathered OUTSIDE the kernel, like r8's count
-     rows: Mosaic has no gather lowering) and the kernel takes the
+     [tile, K] blocks (gathered OUTSIDE the kernel: Mosaic has no
+     gather lowering) and the kernel takes the
      row-wise product-sum — the float ops of `scoring.score_events`,
      but NOT its accumulation order: the K-term sum's association is
      the compiler's, so this mode's scores sit within a couple of ulp
@@ -40,11 +39,10 @@ One grid step per token tile. Per tile (all VMEM-resident):
      this jax has NO gather rule, see the lowering-rules table), so
      the kernel trades the O(log F) serial probes for O(F/lanes)
      fully-parallel compares against tables that are typically tens of
-     entries; the filter-size ladder in bench.py's `feedback_rescore`
-     is the decision input for where that trade stops winning. The
-     adjustment is the exact `filter.apply_filter` order: boost
-     members scale by boost_scale, suppress members go to +inf, BEFORE
-     the tol screen.
+     entries (the filter size at which that trade stops winning: not
+     measured on the chip). The adjustment is the exact
+     `filter.apply_filter` order: boost members scale by boost_scale,
+     suppress members go to +inf, BEFORE the tol screen.
   3. bottom-M — the per-request winner buffer ([M] scores + [M]
      indices, lexicographically sorted ascending) lives in VMEM across
      every grid step (constant out index map) and is flushed to HBM
@@ -66,7 +64,7 @@ is equality against the same tables; rank sums and the scatter are
 int32/select ops (no float accumulation of indices), and the score
 scatter moves values by select, never arithmetic. The only float
 arithmetic beyond scoring is the boost multiply — the same single f32
-op `apply_filter` issues. Interpret mode (the default off-TPU, shared
+op `apply_filter` issues. Interpret mode (the default off-TPU,
 `ONIX_PALLAS_INTERPRET` override) lowers to plain XLA ops, so tier-1
 asserts bit-identity on CPU (tests/test_pallas_serve.py) and the same
 code compiles through Mosaic on a real TPU (the `tpu`-marked tests;
@@ -77,13 +75,13 @@ resolves through `config.resolve_form_gate` next to
 `model_bank.select_bank_form`; `_SERVE_FUSED_MIN_EVENTS` is
 DELIBERATELY EMPTY — tpu included: the crossover is not measured on
 the chip, so `auto` resolves to "xla" on every backend today and nothing changes
-behavior without a measurement. VMEM budget math is in docs/PERF.md
-("fused serving kernel").
+behavior without a measurement.
 """
 
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -91,12 +89,10 @@ from jax.experimental import pallas as pl
 
 from onix.config import resolve_form_gate
 from onix.models.scoring import TopK, _empty_topk
-from onix.models.pallas_gibbs import _default_interpret
 
 # Token-tile width of the serving grid. 256 rows keeps every per-tile
-# temporary comfortably inside VMEM at the budget worked in PERF.md
-# (the [M, tile] cross-rank matrix is the big one) while amortizing
-# the per-tile merge over enough events.
+# temporary comfortably inside VMEM (the [M, tile] cross-rank matrix is
+# the big one) while amortizing the per-tile merge over enough events.
 _SERVE_TILE = 256
 # Filter entries compared per VMEM search tile: 2048 entries = 8 KB
 # per half column, a [tile, 2048] compare temporary of 2 MB. Tables
@@ -110,8 +106,8 @@ _SCATTER_BLOCK = 256
 
 # Measured per-backend crossover: events per request above which the
 # fused one-kernel path beats the three-stage XLA path. Same
-# measured-platforms-only policy as `_NWK_PALLAS_MIN_DENSITY` and
-# `_BANK_GATHER_MIN_EVENTS`: DELIBERATELY EMPTY — including "tpu":
+# measured-platforms-only policy as `_BANK_GATHER_MIN_EVENTS`:
+# DELIBERATELY EMPTY — including "tpu":
 # the fused-vs-xla crossover is not measured on the chip, so
 # serve_form="auto" resolves to "xla" everywhere today. CPU gets no
 # entry either way: the interpret-mode
@@ -120,12 +116,35 @@ _SCATTER_BLOCK = 256
 _SERVE_FUSED_MIN_EVENTS: dict[str, float] = {}
 
 
+def _default_interpret() -> bool:
+    """Interpret everywhere but a real TPU (Mosaic is TPU-only; the
+    emulation is trace-time, so it jits and vmaps like any jnp code).
+    ONIX_PALLAS_INTERPRET=0/1 pins either way for experiments.
+
+    Keyed off the PHYSICAL device platform, not jax.default_backend():
+    the verify/test idiom for driving TPU trace arms on CPU mocks
+    default_backend, and the kernel must keep emulating there — only
+    hardware that can actually run Mosaic should compile it. A device
+    probe that fails propagates: on a TPU the mode is compiled or the
+    call fails, never a quiet emulation."""
+    env = os.environ.get("ONIX_PALLAS_INTERPRET")
+    if env in ("0", "1"):
+        return env == "1"
+    return jax.devices()[0].platform != "tpu"
+
+
+def pallas_mode() -> str:
+    """"compiled" (Mosaic) or "interpret" (XLA emulation) — the mode
+    the kernel runs in here; chip_smoke.py stamps it."""
+    return "interpret" if _default_interpret() else "compiled"
+
+
 def select_serve_form(form: str, n_events: int,
                       backend: str | None = None) -> str:
     """Resolve the serving-scan form for one request/dispatch.
 
     Priority (config.resolve_form_gate — the shared chain with
-    select_bank_form/select_nwk_form): ONIX_SERVE_FORM env override >
+    select_bank_form): ONIX_SERVE_FORM env override >
     explicit config form > the measured `_SERVE_FUSED_MIN_EVENTS`
     table for this backend > "xla". Both forms are bit-identical
     (winners, scores, tie order), so this is pure performance."""

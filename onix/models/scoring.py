@@ -115,7 +115,7 @@ def _scan_bottom_k(arrays: tuple, n: int, score_chunk, *,
     in), expected candidates per chunk fall toward k/chunks_seen, so
     the steady-state merge is O(k+B), not O(k+chunk). EXACT either way:
     count > B falls back to the full merge inside the same lax.cond —
-    never a lossy cap (PERF.md lever 4)."""
+    never a lossy cap."""
     if n == 0:     # static shape: resolved at trace time, not per-call
         return _empty_topk(max_results)
     # `onix.select` names everything here but `score_chunk` (device
@@ -204,7 +204,7 @@ def top_suspicious(
     materializes both gathered [chunk, K] operands in lane-padded
     [chunk, 128] layout (~6.4x traffic); the inner scan gives the
     gather-dot a cheap [sub] consumer so it fuses, and only [chunk]
-    f32 scores reach top_k (docs/PERF.md).
+    f32 scores reach top_k.
 
     A branch-and-bound variant (prune events whose score lower bound
     `θmax[d]·φ[w, argmax θ[d]]` beats the running k-th best) was built,
@@ -213,15 +213,15 @@ def top_suspicious(
     candidates in every regime tried — diffuse tables, peaked tables,
     even model-generated (fitted-telemetry-like) events — so the scan
     always fell back to exhaustive scoring plus bound overhead (2.8x
-    slower on chip). docs/PERF.md "round-2 selection experiments" has
-    the full table; don't rebuild it without a fundamentally tighter
-    bound.
+    slower, over a remote device link, before PR 21). Don't rebuild it
+    without a fundamentally tighter bound.
 
     `merge_buffer` enables the exact two-phase merge (_scan_bottom_k);
     `table_dtype="bfloat16"` stores the gathered tables at half width
-    (measured 1.52x on the materialization-bound r2 form — scores then
-    round at bf16 precision, so keep it off where the 0.95 overlap bar
-    is being judged unless the overlap study revalidates it).
+    (1.52x on the materialization-bound form, over a remote device
+    link, before PR 21 — scores then round at bf16 precision, so keep
+    it off where the 0.95 overlap bar is being judged unless the
+    overlap study revalidates it).
     """
     if table_dtype is not None:
         theta = theta.astype(table_dtype)
@@ -238,8 +238,8 @@ def top_suspicious(
 
 def _subscan_scores(theta, phi_wk, dc, wc):
     """score_events over a chunk via an inner scan of 1/8-chunk slices
-    — the fusion-isolating form shared by every full-scoring chunk
-    (docs/PERF.md "keep top_k away from the gather-dot")."""
+    — the fusion-isolating form shared by every full-scoring chunk:
+    it keeps top_k away from the gather-dot."""
     sub = max(dc.shape[0] // 8, 1)
     if dc.shape[0] % sub:
         return score_events(theta, phi_wk, dc, wc)
@@ -348,10 +348,9 @@ def table_bottom_k(
 # ---------------------------------------------------------------------------
 # bf16-screened exact selection
 #
-# bf16 tables-at-rest halve the gather traffic of the selection scan (the
-# measured-fastest form on chip), but raw bf16 scores round at 2^-8 and can
-# flip the top-k set near the boundary — the bench's per-run identity gate
-# then rejects the speed. The screened variants below keep the bf16 scan as
+# bf16 tables-at-rest halve the gather traffic of the selection scan, but
+# raw bf16 scores round at 2^-8 and can flip the top-k set near the
+# boundary. The screened variants below keep the bf16 scan as
 # a SCREEN only: they retain an oversized candidate buffer by bf16 score,
 # rescore just those candidates with the f32 tables, and certify exactness
 # on device from the rounding bound.
@@ -385,10 +384,8 @@ def table_bottom_k(
 # are bit-identical by construction, so sound=True certifies a
 # bit-identical result. top_suspicious_screened's rescore recomputes the
 # gather-dot in a separately compiled XLA program, and separately compiled
-# programs can differ in the dot's last ulp (the same caveat bench.py
-# records for its variant pair); sound=True there certifies the result up
-# to last-ulp ties at the k-th boundary, and the bench additionally gates
-# on per-run set identity before headlining it.
+# programs can differ in the dot's last ulp; sound=True there certifies
+# the result up to last-ulp ties at the k-th boundary.
 # ---------------------------------------------------------------------------
 
 _SCREEN_REL = 2.0 ** -6
@@ -551,9 +548,9 @@ def table_pair_bottom_k_screened(
 
 def _screened_enabled() -> bool:
     # Platform default, env-overridable. On TPU the screened scan was
-    # the fastest certified form in round 3 (docs/PERF.md "Screened
-    # selection": 132.2M ev/s vs 118.6M exact on the same run, sound +
-    # set-identical; predates PR 21); everywhere else —
+    # the fastest certified form over a remote device link, before
+    # PR 21 (132.2M ev/s vs 118.6M exact on the same run, sound +
+    # set-identical; no cell drives it: ROADMAP D6); everywhere else —
     # CPU (no gather-bandwidth win) and unmeasured accelerators (an
     # uncertifiable screen would pay BOTH scans via the fallback) — the
     # f32 scan stays the default. Any env value other than "1"
@@ -627,8 +624,8 @@ def table_pair_bottom_k_fast(table_flat, idx_src, idx_dst, table_bf16=None,
 
 # Dedup pays once the device scan shrinks enough to cover the host-side
 # np.unique sort; real telemetry is Zipf over (ip, word) pairs, so the
-# unique-pair count is typically a small fraction of the event count
-# (docs/PERF.md lever #1). Uniform-random data dedups to ~nothing and
+# unique-pair count is typically a small fraction of the event count.
+# Uniform-random data dedups to ~nothing and
 # takes the direct path.
 _DEDUP_THRESHOLD = 0.7
 

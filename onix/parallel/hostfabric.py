@@ -133,11 +133,8 @@ def fabric_fingerprint(cfg, n_hosts: int, local_devices: int,
     n_data = n_hosts * local_devices
     d_local = max(1, -(-n_docs // n_data))
     s_step = cfg.superstep or lda_gibbs.SUPERSTEP_DEFAULT
-    nwk_form = None if cfg.nwk_form == "auto" else cfg.nwk_form
-    if nwk_form is None:
-        nwk_form = lda_gibbs.env_nwk_form()
     sampler_form, sparse_active, _ = lda_gibbs.resolve_sampler(
-        cfg, k_topics=cfg.n_topics, nwk_form=nwk_form)
+        cfg, k_topics=cfg.n_topics)
     tau = int(cfg.merge_staleness) if cfg.merge_form == "async" else 0
     extra = {"mesh": [n_data, 1], "layout": 4,
              "hosts": [n_hosts, local_devices],

@@ -221,22 +221,22 @@ class ShardedGibbsState(NamedTuple):
 
 
 def _local_sweep(z, n_dk, n_wk, n_k, key, docs, words, mask, *,
-                 alpha, eta, n_vocab, k_topics, nwk_form=None,
+                 alpha, eta, n_vocab, k_topics,
                  sampler_form=None, sparse_active=0, sparse_mh=2):
     """The per-device sweep body — the single-device engine's sweep
     kernel, shared via lda_gibbs.make_sweep_kernel so the math (and
     the sampler-form gate) stays identical. `n_wk` may be a vocabulary
     CHUNK with local word ids; the denominator terms (n_k + V*eta)
-    stay global. The n_wk count-update form (scatter | matmul |
-    pallas) gates on the LOCAL chunk width — under mp sharding each
-    chunk's collision density is what matters. The sparse sampler arm
+    stay global. The n_wk count-update form (scatter | matmul) gates
+    on the LOCAL chunk width — under mp sharding each chunk's
+    collision density is what matters. The sparse sampler arm
     is chunk-clean too: its stale proposal tables are built from this
     device's local rows (doc-sharded n_dk, the local n_wk chunk) and
     every per-token gather is a local-row gather, so mp sharding needs
     no global rebuild."""
     kernel = lda_gibbs.make_sweep_kernel(
         alpha=alpha, eta=eta, n_vocab=n_vocab, k_topics=k_topics,
-        nwk_form=nwk_form, sampler_form=sampler_form,
+        sampler_form=sampler_form,
         sparse_active=sparse_active, sparse_mh=sparse_mh)
     return kernel(z, n_dk, n_wk, n_k, key, docs, words, mask)
 
@@ -270,28 +270,16 @@ class ShardedGibbsLDA:
 
         S = max(1, int(config.sync_splits))
         burn = config.burn_in
-        # "auto" defers to the measured per-backend gate at trace time
-        # (lda_gibbs.select_nwk_form); explicit config forms pin it. An
-        # ONIX_NWK_FORM override present at construction is captured
-        # here; when it is unset (form None), BOTH the block steps and
-        # the replication-check decision below re-resolve the env at
-        # trace time — the same moment, so the compiled form and the
-        # check can never disagree even if the env changes in between.
-        nwk_form = (None if config.nwk_form == "auto" else config.nwk_form)
-        if nwk_form is None:
-            nwk_form = lda_gibbs.env_nwk_form()
         # Sampler form: resolved ONCE at construction via the shared
         # lda_gibbs.resolve_sampler (config, then ONIX_SAMPLER_FORM,
-        # then nwk-pin deference, then the measured gate) — the
-        # resolved value feeds every compiled sweep AND the checkpoint
-        # fingerprint, and sharing the resolver with GibbsLDA is what
-        # keeps the two engines from ever resolving different arms for
-        # the same config. The sparse arm is a different chain, so a
+        # then the measured gate) — the resolved value feeds every
+        # compiled sweep AND the checkpoint fingerprint, and sharing
+        # the resolver with GibbsLDA is what keeps the two engines
+        # from ever resolving different arms for the same config. The sparse arm is a different chain, so a
         # resume across an arm change must be refused, not silently
         # continued.
         self.sampler_form, self.sparse_active, sampler_kw = \
-            lda_gibbs.resolve_sampler(config, k_topics=k,
-                                      nwk_form=nwk_form)
+            lda_gibbs.resolve_sampler(config, k_topics=k)
         # Count-merge form (r14): resolved once at construction like
         # the sampler form — the value feeds the compiled superstep AND
         # the checkpoint fingerprint (merge_fingerprint), so the
@@ -302,30 +290,12 @@ class ShardedGibbsLDA:
         use_async = self.merge_form == "async"
         tau = int(config.merge_staleness) if use_async else 0
         self.merge_tau = tau
-        # shard_map has no replication rule for pallas_call, so the
-        # sweep-carrying shard regions must drop the static replication
-        # check whenever the Pallas form CAN be traced (explicitly
-        # pinned, or auto-reachable because the backend has a measured
-        # pallas crossover entry). The check is a tracing-time linter,
-        # not semantics — psum/out_specs behave identically without it
-        # (the dp>1 pallas-vs-scatter equality tests ride this path).
-        # Evaluated at TRACE time, right where make_block_step resolves
-        # the same form, so the two decisions always read the same env.
-        def sweep_smap_kw():
-            # The async merge arm's count views are genuinely device-
-            # VARYING mid-superstep (own deltas fresh, peers' stale) and
-            # only the boundary flush restores replication-in-value, so
-            # the static replication linter has nothing true to check —
-            # drop it, exactly as the pallas arm must.
-            if use_async:
-                return {"check_vma": False}
-            form = (nwk_form if nwk_form is not None
-                    else lda_gibbs.env_nwk_form())
-            maybe_pallas = (
-                form == "pallas"
-                or (form is None and lda_gibbs.nwk_pallas_auto_reachable(
-                    jax.default_backend())))
-            return {"check_vma": False} if maybe_pallas else {}
+        # The async merge arm's count views are genuinely device-
+        # VARYING mid-superstep (own deltas fresh, peers' stale) and only
+        # the boundary flush restores replication-in-value, so shard_map's
+        # static replication check has nothing true to check there: the
+        # async arm drops it, the sync arm keeps it.
+        sweep_smap_kw = {"check_vma": False} if use_async else {}
 
         def _group_sweep(z_g, n_dk_l, n_wk_l, n_k_l, key_c,
                          d_g, w_g, m_g):
@@ -351,8 +321,7 @@ class ShardedGibbsLDA:
                     return _local_sweep(
                         zc, ndkc, nwkc, nkc, keyc, dg, wg, mg,
                         alpha=config.alpha, eta=config.eta,
-                        n_vocab=n_vocab, k_topics=k, nwk_form=nwk_form,
-                        **sampler_kw)
+                        n_vocab=n_vocab, k_topics=k, **sampler_kw)
 
                 z_new, ndk_new, nwk_new, nk_new, key_new = \
                     jax.vmap(one_chain)(zg, ndk_v, nwk_v, nk_v, key_c)
@@ -407,8 +376,7 @@ class ShardedGibbsLDA:
                     return _local_sweep(
                         zc, ndkc, nwkc, nkc, keyc, dg, wg, mg,
                         alpha=config.alpha, eta=config.eta,
-                        n_vocab=n_vocab, k_topics=k, nwk_form=nwk_form,
-                        **sampler_kw)
+                        n_vocab=n_vocab, k_topics=k, **sampler_kw)
 
                 z_new, ndk_new, nwk_new, nk_new, key_new = \
                     jax.vmap(one_chain)(zg, ndk_v, nwk_v, nk_v, key_c)
@@ -507,7 +475,7 @@ class ShardedGibbsLDA:
                           P(D, *mp_spec)),
                 out_specs=(P(D, *mp_spec), P(D), P(*mp_spec), P(),
                            P(D, *mp_spec)),
-                **sweep_smap_kw(),
+                **sweep_smap_kw,
             )(state.z, state.n_dk, state.n_wk, state.n_k, state.keys,
               docs, words, mask)
             do_acc = jnp.float32(accumulate)
@@ -527,8 +495,7 @@ class ShardedGibbsLDA:
             past burn_in, decided on device), and the final counts feed
             the psum-reduced ll before anything returns to the host —
             one dispatch and one sync per superstep instead of per
-            sweep (docs/PERF.md "the gibbs_fit vs sweep-microbench
-            gap"). `with_initial_ll` also evaluates ll on the INCOMING
+            sweep. `with_initial_ll` also evaluates ll on the INCOMING
             counts (fit's pre-sweep history point) inside the same
             program. Bit-identical to n_steps sweep_fn dispatches."""
             def shard_fn(z, n_dk, n_wk, n_k, keys, accd, accw, nacc,
@@ -582,7 +549,7 @@ class ShardedGibbsLDA:
                           P(D, *mp_spec), P(D, *mp_spec),
                           P(D, *mp_spec), P()),
                 out_specs=out_specs,
-                **sweep_smap_kw(),
+                **sweep_smap_kw,
             )(state.z, state.n_dk, state.n_wk, state.n_k, state.keys,
               state.acc_ndk, state.acc_nwk, state.n_acc,
               docs, words, mask, jnp.asarray(start, jnp.int32))
@@ -688,7 +655,7 @@ class ShardedGibbsLDA:
                           P(D, *mp_spec), P(D, *mp_spec),
                           P(D, *mp_spec), P()),
                 out_specs=out_specs,
-                **sweep_smap_kw(),
+                **sweep_smap_kw,
             )(state.z, state.n_dk, state.n_wk, state.n_k, state.keys,
               state.acc_ndk, state.acc_nwk, state.n_acc,
               docs, words, mask, jnp.asarray(start, jnp.int32))
@@ -707,7 +674,7 @@ class ShardedGibbsLDA:
             """dp=1/mp=1 fast path: the identical superstep math with NO
             shard_map/psum wrapping — at one device every psum is an
             identity on integer deltas, so the collective wrapper buys
-            nothing and costs real time (docs/PERF.md r7). Bit-identical
+            nothing (its cost on the chip: not measured). Bit-identical
             to the shard_map path (asserted in
             tests/test_sharded_gibbs.py), including under
             sync_splits > 1, whose grouping is pure staleness
@@ -722,7 +689,7 @@ class ShardedGibbsLDA:
                 ll0 = (sm0 / jnp.maximum(t0, 1.0)).mean()
             sweep_kernel = lda_gibbs.make_sweep_kernel(
                 alpha=config.alpha, eta=config.eta, n_vocab=n_vocab,
-                k_topics=k, nwk_form=nwk_form, **sampler_kw)
+                k_topics=k, **sampler_kw)
 
             def one_sweep(carry, i):
                 z, ndk, nwk, nk, keys, ad, aw, na = carry
@@ -806,7 +773,7 @@ class ShardedGibbsLDA:
             static_argnames=("n_steps", "with_initial_ll"),
             donate_argnums=(0,))
         # The shard_map superstep stays constructible regardless, for
-        # the fast-path equality tests and the pre-PR bench arm (no
+        # the fast-path equality tests and hostfabric's workers (no
         # donation: test callers reuse their input states). It carries
         # the RESOLVED merge form, so a dp=1 async model can still be
         # compared bit-for-bit against a sync model's wrapped path.

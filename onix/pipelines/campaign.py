@@ -4,7 +4,7 @@ The scale runner (scale.py) executes ONE datatype end-to-end and the
 three judged pipelines ran strictly sequentially: flow's host
 synthesize/word-build/corpus-build finished before flow's device fit
 started, and dns's host work waited for flow's fit to drain — on the
-measured host-bound pattern (docs/PERF.md r10: ~0.5 s/batch of host
+host-bound pattern measured on a CPU (~0.5 s/batch of host
 decode/convert on the cores XLA also uses) that serializes host work
 against device compute instead of overlapping it. This orchestrator
 composes the pieces ROADMAP item 5 names — the sharded Gibbs engine
@@ -632,8 +632,9 @@ def run_campaign(n_events: int, datatypes=DATATYPES, n_hosts: int | None = None,
 
 def winners_identical(a: dict, b: dict) -> bool:
     """Exact per-datatype winner-set/score identity between two
-    campaign manifests — the cross-arm parity check bench and the
-    chaos smoke assert (deterministic stages ⇒ identical artifacts)."""
+    campaign manifests — the cross-arm parity check the acceptance
+    script and the chaos smoke assert (deterministic stages ⇒
+    identical artifacts)."""
     if set(a["per_datatype"]) != set(b["per_datatype"]):
         return False
     for dt, pa in a["per_datatype"].items():

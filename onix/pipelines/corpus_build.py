@@ -95,7 +95,7 @@ class CorpusBundle:
     # ascending packed word keys / uint32 IPs with their vocab/doc ids.
     # They let the streaming scale path map a raw 10⁸-token chunk into
     # the TRAINED id spaces with one searchsorted against a tiny table —
-    # no per-chunk unique sort, no string rendering (docs/PERF.md).
+    # no per-chunk unique sort, no string rendering.
     word_key_sorted: np.ndarray | None = None   # int64 [V] ascending
     word_key_ids: np.ndarray | None = None      # int32 [V] -> vocab id
     doc_u32_sorted: np.ndarray | None = None    # uint32 [D] ascending

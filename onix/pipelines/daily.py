@@ -59,8 +59,7 @@ identities — and therefore φ̂ rows, feedback pairs, and the analyst's
 dismissals — stay comparable across days.
 
 Drivers: `python -m onix.pipelines.daily` (the chaos tests' subprocess
-entry), scripts/exp_daily.py (the acceptance experiment), and the
-bench `daily_loop` component.
+entry) and scripts/exp_daily.py (the acceptance experiment).
 """
 
 from __future__ import annotations

@@ -13,9 +13,9 @@ compute on the VPU instead of a host preprocessing stage.
 
 As of round 6 this path is the DEFAULT for all three datatypes in both
 the scale runner's streaming stage and the SVI streaming scorer
-(`ONIX_HOST_WORDS=1` — or the legacy `ONIX_DEVICE_WORDS=0` — pins the
-host reference builders, kept as the cross-check arm the parity tests
-compare winners against). Two supporting pieces live here too:
+(`ONIX_HOST_WORDS=1` pins the host reference builders, kept as the
+cross-check arm the parity tests compare winners against). Two
+supporting pieces live here too:
 
 * **Double-buffered chunk staging** (`stage_*_cols` / STAGE_FNS; with
   TABLE_FNS and SCAN_FNS the datatype-keyed entry of the day scan):
@@ -74,14 +74,12 @@ from onix.utils.obs import device_scope
 def host_words_forced() -> bool:
     """True when the env pins the HOST word builders. Device-resident
     word creation is the default hot path in the scale and streaming
-    pipelines; `ONIX_HOST_WORDS=1` (or the legacy spelling
-    `ONIX_DEVICE_WORDS=0`) selects the host reference implementation —
-    kept as the cross-check arm the device-vs-host parity tests and
-    artifacts compare against."""
+    pipelines; `ONIX_HOST_WORDS=1` selects the host reference
+    implementation — kept as the cross-check arm the device-vs-host
+    parity tests and artifacts compare against."""
     import os
 
-    return (os.environ.get("ONIX_HOST_WORDS") == "1"
-            or os.environ.get("ONIX_DEVICE_WORDS") == "0")
+    return os.environ.get("ONIX_HOST_WORDS") == "1"
 
 
 # Compact-key layout (int32), LSB-first: pbin | bbin | hbin | proto |

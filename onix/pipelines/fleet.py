@@ -63,8 +63,7 @@ epoch, so a live bank invalidates exactly the refitted tenants'
 cached winners and no others.
 
 Drivers: `python -m onix.pipelines.fleet` (the chaos tests' subprocess
-entry), scripts/exp_fleet.py (the acceptance experiment), and the
-bench `daily_fleet` component.
+entry) and scripts/exp_fleet.py (the acceptance experiment).
 """
 
 from __future__ import annotations
@@ -172,7 +171,7 @@ def _refit_classes(classes, cfg: LDAConfig, programs: dict, *,
     vmapped dispatch per class); `batched=False` is the sequential-
     supervisor arm — the SAME per-lane program dispatched once per
     tenant, which is the O(N) wall this module exists to remove and
-    the bit-identity reference the bench asserts against."""
+    the bit-identity reference tests/test_fleet.py asserts against."""
     from onix.parallel import fleet_shard
 
     k = cfg.n_topics

@@ -132,9 +132,8 @@ def run_rehearsal(n_events: int = 100_000, n_sweeps: int = 300,
         # The bf16 arm: identical fit, tables rounded to bfloat16 at
         # rest — exactly what `top_suspicious(..., table_dtype=
         # "bfloat16")` does on TPU (gather bf16, upcast, f32 dot).
-        # Scoring it against the SAME oracle answers whether the 1.27x
-        # bench lever meets the judged fidelity bar (docs/PERF.md
-        # round-3 selection measurements #3). Opt-in: it costs a full
+        # Scoring it against the SAME oracle answers whether bf16
+        # tables meet the judged fidelity bar. Opt-in: it costs a full
         # extra score_all pass, and its wall is recorded apart so
         # jax_fit_and_score stays comparable across rounds.
         import jax.numpy as jnp
@@ -197,12 +196,9 @@ def run_rehearsal(n_events: int = 100_000, n_sweeps: int = 300,
 
 def summarize_cells(cells: dict) -> dict:
     """Per-datatype min-over-seeds summary of rehearsal cells keyed
-    "<datatype>/seed<N>". The r03–r05 study drivers and the artifact
-    merge tool that consumed this were consolidated in r14 (their
-    recipes live in the committed docs/OVERLAP_r0*.json artifacts;
-    single cells re-run via `scripts/exp_campaign.py --rehearsal-cell`
-    — docs/PERF.md "overlap study drivers, consolidated"); this stays
-    the ONE judged-bar aggregation for any future study."""
+    "<datatype>/seed<N>": the ONE judged-bar aggregation for a study
+    (recipes in the committed docs/OVERLAP_r0*.json artifacts; single
+    cells re-run via `scripts/exp_campaign.py --rehearsal-cell`)."""
     per_dt = {}
     for dt in sorted({k.split("/")[0] for k in cells}):
         mine = [c for k, c in cells.items() if k.startswith(dt + "/")]

@@ -69,8 +69,8 @@ Streaming-specific design (vs the batch path in pipelines/run.py):
 - **Depth-k host pipeline (r10).** ColumnPrefetcher keeps up to k
   future batches' file decode + frame→columns conversion in flight on
   worker threads or a process pool (measured auto-pick; bounded,
-  in-order, backpressured), so the ~30% host slice of the batch wall
-  (docs/PERF.md r6) rides under the device step.
+  in-order, backpressured), so the host slice of the batch wall (~30%
+  on a CPU host) rides under the device step.
 - **Capped shape lattice (r10).** `_pick_pad` bounds the compiled
   (pad_to, pad_docs) set: past `pipeline.stream_max_shapes`,
   adversarial batch-size streams re-pad into covering shapes instead
@@ -342,8 +342,8 @@ class StreamingScorer:
         self.words_mode_batches = {"device": 0, "host": 0}
         # Device dispatch syncs per program family — the number the
         # superstep collapses (one svi_update+score dispatch per S
-        # batches instead of two per batch), tracked so artifacts and
-        # bench.py report it instead of inferring it.
+        # batches instead of two per batch), tracked so artifacts
+        # report it instead of inferring it.
         self.dispatches = {"words": 0, "svi_update": 0, "score": 0,
                            "superstep": 0}
         # Shape-lattice accounting (_pick_pad): every NEW (pad_to,
@@ -1052,9 +1052,9 @@ class StreamingScorer:
         """Word-create, model-update, and score one minibatch.
 
         `cols` takes a pre-converted column dict from convert_columns
-        (the ColumnPrefetcher hands it over) so the ~30%-of-batch-wall
-        frame→columns host conversion (docs/PERF.md r6) that already ran
-        under the previous batch's device step is not paid again.
+        (the ColumnPrefetcher hands it over) so the frame→columns host
+        conversion (~30% of the batch wall on a CPU host) that already
+        ran under the previous batch's device step is not paid again.
 
         Chaos hook: a `stream:batch` rule in the active fault plan
         fires HERE, before any scorer state (model, doc table, gamma,
@@ -1324,10 +1324,10 @@ def _produce_item(datatype: str, item):
 class ColumnPrefetcher:
     """Depth-k bounded prefetch pipeline for the streaming host stage.
 
-    The steady-state streaming batch spends ~30% of its wall in the
-    frame→columns conversion (docs/PERF.md r6) — pure host
-    string/array work that needs no scorer state — and, through
-    run_stream, the file decode ahead of it. This iterator runs up to
+    The steady-state streaming batch spends ~30% of its wall (on a CPU
+    host) in the frame→columns conversion — pure host string/array work
+    that needs no scorer state — and, through run_stream, the file
+    decode ahead of it. This iterator runs up to
     `depth` future batches' decode+conversion on worker threads OR
     process-pool workers while the caller processes the current one:
 

@@ -6,8 +6,7 @@ north star multiplies that by tenant. The batch pipelines in
 the piece that turns the scorer into a SERVICE: many tenants' tables
 stacked into bank-shaped device arrays, mixed-tenant request batches
 scored through ONE jitted program, LRU residency for banks larger than
-device memory, and a load harness that replays skewed tenant traffic
-(docs/PERF.md "model bank").
+device memory, and a load harness that replays skewed tenant traffic.
 """
 
 from onix.serving.model_bank import (BankRefusal, BankService, ModelBank,

@@ -102,8 +102,7 @@ def make_stream(spec: HarnessSpec) -> list[ScoreRequest]:
     (dashboards re-opening a scored day) looks like."""
     rng = np.random.default_rng(spec.seed + 1)
     ranks = (rng.zipf(spec.zipf_a, spec.n_requests) - 1) % spec.n_tenants
-    # Scatter ranks so hot tenants aren't id-contiguous (same trick as
-    # bench._zipf_pairs).
+    # Scatter ranks so hot tenants aren't id-contiguous.
     tenant_ids = (ranks * 2654435761) % spec.n_tenants
     events: dict[tuple[int, int], tuple[np.ndarray, np.ndarray]] = {}
     stream = []

@@ -17,10 +17,9 @@ mixed-tenant request batch is scored by ONE jitted program: a
 tenant-slot gather feeding the exact chunked bottom-M machinery of
 `scoring._scan_bottom_k`, so per-tenant winners are bit-identical to
 the single-tenant `top_suspicious` path (asserted in
-tests/test_model_bank.py and per-run in bench.py's `model_bank`
-component).
+tests/test_model_bank.py).
 
-Two batched forms, gated like the n_wk count-update forms:
+Two batched forms, gated by shape and backend:
 
 * ``vmap``   — `jax.vmap` over the request axis; each lane slices its
   tenant's tables out of the bank (`theta_bank[slot]`) and runs the
@@ -367,10 +366,10 @@ def _bank_kernel_for(form: str, serve: str):
     elsewhere)."""
     if serve != "fused":
         return _BANK_KERNELS[form]
-    from onix.models import pallas_gibbs, pallas_serve
+    from onix.models import pallas_serve
     fused = {"vmap": pallas_serve.bank_score_vmap_fused,
              "gather": pallas_serve.bank_score_gather_fused}[form]
-    interpret = pallas_gibbs._default_interpret()
+    interpret = pallas_serve._default_interpret()
     return functools.partial(fused, interpret=interpret)
 
 
@@ -1104,12 +1103,11 @@ class ModelBank:
         from onix.models.pallas_serve import select_serve_form
         # Gate on n_pad — the PER-LANE event count each fused kernel
         # actually runs at — so the crossover table keeps one unit
-        # (per-scan events) across every consumer; the seeding bench
-        # row measures a single scan at exactly that unit.
+        # (per-scan events) across every consumer.
         serve = select_serve_form(self.serve_form, n_pad)
-        # The RESOLVED serve form joins the shape key so manifests and
-        # bench stamps record what actually compiled (acceptance: gate
-        # artifacts must name the arm, not the request).
+        # The RESOLVED serve form joins the shape key so manifests
+        # record what actually compiled (gate artifacts must name the
+        # arm, not the request).
         shape_key = (form, serve, shard.d_pad, shard.v_pad, shard.k,
                      r_pad, n_pad, max_results, filt_dims)
         self.compiled_shapes.add(shape_key)
@@ -1325,9 +1323,8 @@ class BankService:
                 # Two scopes on purpose: peak_depth is THIS service's
                 # high-water (admission_stats / GET /bank/stats — one
                 # service per server); the registry gauge is the
-                # process-wide max across services (what bench's
-                # detail.resilience snapshot carries — a harness
-                # running several services reports the worst one).
+                # process-wide max across services (a harness running
+                # several services reports the worst one).
                 self.peak_depth = max(self.peak_depth, depth)
         if shed_pending is not None:
             counters.inc("serve.shed")
@@ -1372,7 +1369,7 @@ class BankService:
                 if deadline is not None and deadline.expired():
                     # counters: resilience.deadline_exceeded is inc'd
                     # by Deadline.check; serve.deadline_expired is the
-                    # serve-tier view bench folds into artifacts.
+                    # serve-tier view artifacts carry.
                     counters.inc("serve.deadline_expired")
                     deadline.check("serve request (queued past its "
                                    "deadline budget)")

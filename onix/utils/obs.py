@@ -36,7 +36,6 @@ import time
 #: `counter-namespaces`).
 COUNTER_NAMESPACES: dict[str, str] = {
     "bank": "model-bank residency/cache/dispatch events (onix/serving)",
-    "bench": "bench.py harness self-reporting (component errors)",
     "campaign": "campaign orchestrator retries/preemptions (pipelines/campaign.py)",
     "ckpt": "checkpoint/model integrity events (digest mismatches)",
     "daily": "continuous-operation supervisor events (warm/cold refits, drift fallbacks, ledger refusals, poison-day rollbacks; pipelines/daily.py)",
@@ -59,7 +58,7 @@ class CounterRegistry:
     """Process-wide named event counters — the one place every
     resilience event (retry, quarantine, salvage, injected fault,
     checkpoint digest mismatch) is tallied, so watcher stats, streaming
-    stage reports, and bench/scale manifests all read the same numbers
+    stage reports, and scale manifests all read the same numbers
     instead of each keeping a private ledger. Thread-safe; names are
     dotted paths (`ingest.quarantined`, `salvage.skipped_records`)."""
 
@@ -133,7 +132,7 @@ class OccupancyClock:
     the shared discipline behind the r14 campaign orchestrator
     (onix/pipelines/campaign.py), generalizing the streaming
     prefetcher's rule that only CONSUMER-BLOCKED seconds count as wait
-    (streaming.py prefetch_wait; docs/PERF.md r10).
+    (streaming.py prefetch_wait).
 
     `busy(name)` marks a stage busy on the calling thread; stages may
     run concurrently on different threads. `blocked(name)` records
@@ -280,8 +279,8 @@ def device_scope(name: str):
 
 
 def device_summary() -> dict:
-    """The default devices as JAX reports them — what every script,
-    bench.py and chip_smoke.py print and stamp beside their numbers."""
+    """The default devices as JAX reports them — what every script and
+    chip_smoke.py print and stamp beside their numbers."""
     import jax
     devices = jax.devices()
     return {"platform": devices[0].platform,
@@ -358,17 +357,16 @@ class RunLog:
 
 
 # ---------------------------------------------------------------------------
-# Roofline accounting (docs/PERF.md).
+# Roofline accounting.
 #
-# The judged hot loops are MEMORY-bound on every platform measured: the
-# scoring scan is two table-row gathers + a score write per event, and
-# the Gibbs sweep is bounded by the n_dk/n_wk scatter-add (PERF.md "the
-# scatter IS the sweep's ceiling"). The honest efficiency number is
+# The hot loops are MEMORY-bound: the scoring scan is table-row gathers
+# and a score write per event, and the Gibbs sweep is bounded by the
+# n_dk scatter-add (PERF.md section 5). The honest efficiency number is
 # therefore achieved bytes/s against the device's peak memory
-# bandwidth, not FLOP/s. bench.py derives each component's modeled
-# bytes/item from its shape and reports `detail.roofline`, so a
-# throughput regression shows up as a tracked fraction-of-peak drop
-# instead of a prose claim.
+# bandwidth, not FLOP/s. The benchmark's own byte models live with it
+# (benchmark/models.py); here are the peaks, the one model a script of
+# this tree still prices (bank_score_bytes_per_event) and the entry's
+# arithmetic.
 # ---------------------------------------------------------------------------
 
 # Chip HBM peaks, bytes/s (vendor specs), keyed on jax device_kind
@@ -420,142 +418,20 @@ def device_peak_bytes_per_s() -> tuple[float, str]:
         "its source")
 
 
-def gibbs_sweep_bytes_per_token(k_topics: int) -> float:
-    """Modeled memory traffic per sampled token (docs/PERF.md roofline):
-    n_dk[d] and n_wk[w] row read + scatter write-back (4·K·4 B) plus the
-    token stream (d, w, z: 12 B). Shared by bench.py's gibbs_sweep AND
-    gibbs_fit_effective roofline entries — the fit loop samples the same
-    tokens through the same sweep kernel, so a widening gap between the
-    two fractions is fit-loop overhead (dispatch, ll evals, wrapping),
-    which is exactly the number the superstep work tracks."""
-    return 4 * k_topics * 4 + 12
-
-
-def gibbs_pallas_bytes_per_token(k_topics: int, n_rows: int,
-                                 block_size: int) -> float:
-    """Modeled HBM traffic per token for the Pallas fused sample+count
-    block step (onix/models/pallas_gibbs.py; docs/PERF.md "Pallas fused
-    sample+count"): the gathered n_dk[d]/n_wk[w] row reads plus the
-    n_dk row scatter write-back (3·K·4 B), the pre-generated noise row
-    written by the RNG and read by the kernel (2·K·4 B), the token
-    stream (w, z_old in, z_new out: 12 B), and the dense [V, K] n_wk
-    delta flush amortized over the block (V·K·4 / B). The n_wk
-    write-back that the scatter model charges per token is gone — that
-    is the kernel's whole point — so on collision-dense shapes the
-    pallas model moves MORE bytes per token than the scatter model
-    only via the noise rows, while removing the serialization."""
-    return (5 * k_topics * 4 + 12
-            + n_rows * k_topics * 4 / max(block_size, 1))
-
-
-def gibbs_sparse_bytes_per_token(k_topics: int, n_active: int,
-                                 mh_steps: int, n_docs: int = 0,
-                                 n_vocab: int = 0,
-                                 sweep_tokens: int = 0) -> float:
-    """Modeled memory traffic per token for the r11 sparse O(K_active)
-    sampler arm (lda_gibbs sampler_form="sparse"; docs/PERF.md "sparse
-    sampler family"): the per-doc active block gathers (ids + counts +
-    stale-phi values: 3·A·4 B), per MH proposal the F+-tree bisection
-    (ceil(log2 K) scalar CDF gathers) plus ~10 scalar target/proposal
-    gathers and 12 B of uniforms, the six rank-1 count scatters
-    (read+write: 48 B), and the token stream (16 B). When the sweep
-    shape is given, the per-sweep stale-table rebuild (top-A over
-    [D,K] + the [V,K] CDF: read + write) is amortized over the sweep's
-    tokens — the honest charge for the table freshness the MH
-    correction leans on. The whole point vs gibbs_sweep_bytes_per_token
-    (4·K·4 + 12): traffic scales with A + mh·log K, not K."""
-    import math
-    log_k = math.ceil(math.log2(max(k_topics, 2)))
-    per_token = (3 * n_active * 4
-                 + mh_steps * ((log_k + 10) * 4 + 12)
-                 + 48 + 16)
-    if n_docs and n_vocab and sweep_tokens:
-        build = (n_docs * k_topics * 4            # top_k read of n_dk
-                 + 2 * n_docs * n_active * 4      # act tables write
-                 + 3 * n_vocab * k_topics * 4)    # phi read + cdf r/w
-        per_token += build / sweep_tokens
-    return per_token
-
-
-def fleet_refit_bytes_per_token(k_topics: int, n_sweeps: int) -> float:
-    """Modeled memory traffic per stacked PADDED token across one
-    tenant's fleet refit (onix/models/fleet_gibbs.py; bench.py
-    `daily_fleet` roofline): the count build (one n_dk/n_wk row
-    scatter + the token stream: 4·K·4 + 12 B), then `n_sweeps` Gibbs
-    sweeps at the sweep kernel's per-token traffic
-    (gibbs_sweep_bytes_per_token), then the burn-in accumulator adds
-    (2·K·4 B per sweep per token's rows, charged per token) and the
-    two boundary ll evaluations (2·(2·K·4 + 12) B). Padded tokens move
-    the same bytes as real ones — that is what `padding_stats`'
-    token_pad_waste_frac prices — so the model charges the PADDED
-    stream and the bench divides by padded tokens·tenants."""
-    build = 4 * k_topics * 4 + 12
-    sweeps = n_sweeps * (gibbs_sweep_bytes_per_token(k_topics)
-                         + 2 * k_topics * 4)
-    ll = 2 * (2 * k_topics * 4 + 12)
-    return build + sweeps + ll
-
-
 def bank_score_bytes_per_event(k_topics: int, dtype_bytes: int = 4) -> float:
     """Modeled memory traffic per scored event through the model bank's
-    batched program (onix/serving/model_bank.py; bench.py `model_bank`
-    roofline): the two bank-row gathers (θ_bank[slot, d], φ_bank[slot,
+    batched program (onix/serving/model_bank.py;
+    scripts/exp_model_bank.py prices its replay with it): the two
+    bank-row gathers (θ_bank[slot, d], φ_bank[slot,
     w]: 2·K·dtype B — the tenant axis folds into the gather index, so
     the TENANT gather is these same rows, charged once), the per-event
     token stream (d, w ids + mask: 12 B), the request's tenant slot
     read amortized per event (≈4 B charged flat), and the f32 score
     write feeding selection (4 B). Identical per-event traffic to the
-    single-tenant scan's model (bench `_roofline_detail`) plus the slot
-    read — which is exactly the claim: banking N tenants adds a slot
+    single-tenant scan (2·K·dtype + 12 + 4 B) plus the slot read —
+    which is exactly the claim: banking N tenants adds a slot
     gather, not N× dispatch overhead."""
     return 2 * k_topics * dtype_bytes + 12 + 4 + 4
-
-
-def fused_serve_bytes_per_event(k_topics: int, n_filter_entries: int = 0,
-                                n_events: int = 0, max_results: int = 0,
-                                mode: str = "dot") -> float:
-    """Modeled HBM traffic per event for the r15 fused serving kernel
-    (onix/models/pallas_serve.py; bench.py `fused_serve` roofline).
-    Per event: the score operands — mode "dot": the two gathered
-    theta/phi rows written by the outside gather and read by the
-    kernel (2·2·K·4 B: the materialize-then-stream cost the kernel
-    pays for Mosaic's missing gather rule, charged honestly at both
-    ends); mode "min2"/"scores": the pre-gathered f32 score columns
-    (2·4 / 4 B) plus the same gather's read side (4 B each) — plus the
-    key stream (word lo half 4 B + pair halves 8 B) and the pad mask
-    (4 B). Per CALL, amortized over the events: the FILTER SEARCH
-    BYTES — every sentinel-padded table entry's (hi, lo) uint32 pair
-    streams HBM→VMEM exactly once (8 B/entry; the per-tile compare
-    sweep then re-reads it from VMEM for free, which is the fused
-    arm's membership claim) — and the single winner flush
-    (max_results·8 B, once per request instead of once per chunk).
-    The XLA arm's corresponding model re-reads candidates between its
-    three programs; the DIFFERENCE between the two models is the HBM
-    round-trip the fusion removes."""
-    if mode == "dot":
-        per_event = 4 * k_topics * 4
-    elif mode == "min2":
-        per_event = 2 * (4 + 4)
-    else:
-        per_event = 4 + 4
-    per_event += 4 + 8 + 4
-    per_call = n_filter_entries * 8 + max_results * 8
-    return per_event + per_call / max(n_events, 1)
-
-
-def svi_estep_bytes_per_pair(k_topics: int, iters: float) -> float:
-    """Modeled memory traffic per deduped (doc, bucket) pair of the
-    streaming SVI step (bench.py `streaming` roofline; docs/PERF.md
-    r10): per local E-step iteration, the gamma-row gather for
-    elog_theta (K·4 B), the cached elog_beta row read (K·4 B), and the
-    phi scatter-add back into gamma (K·4 B) — 3·K·4 B/iteration — plus
-    the one-time elog_beta row materialization and the scoring
-    gather-dot + score write (2·K·4 + 4 B). `iters` is the modeled
-    iteration count; artifacts pass the warm-pass length
-    (svi_warm_iters) as the floor every pair pays, so the fraction is
-    a LOWER bound on achieved traffic (compacted extended iterations
-    move less than the model charges full-block)."""
-    return iters * 3 * k_topics * 4 + 2 * k_topics * 4 + 4
 
 
 def roofline(n_items: int, wall_s: float, bytes_per_item: float,

@@ -2,7 +2,7 @@
 decision harness (ISSUE 10; ROADMAP item 5).
 
 Arms, interleaved best-of so this host's multi-minute load waves give
-every arm the same weather (the exp_fit_gap discipline):
+every arm the same weather:
 
   * sequential_sync   — the pre-r14 shape: three datatypes strictly in
                         series, full-barrier psum folds;
@@ -34,8 +34,7 @@ async merge arm is a real multi-shard chain only on >1 device: on a
 CPU host export XLA_FLAGS=--xla_force_host_platform_device_count=8.
 
 Also carries the one load-bearing capability of the retired
-r03–r05 scripts/overlap_*.py study drivers (docs/PERF.md "overlap
-study drivers, consolidated"): `--rehearsal-cell datatype:seed`
+r03–r05 scripts/overlap_*.py study drivers: `--rehearsal-cell datatype:seed`
 re-runs a judged-overlap rehearsal cell through
 onix/pipelines/rehearsal.py, which remains the engine behind the
 committed OVERLAP_r0*.json artifacts.
@@ -109,8 +108,7 @@ def main() -> int:
     # 20 sweeps (burn 10): the ll-band contract is a CONVERGED-fit
     # comparison — at a handful of sweeps the τ>0 chain's bounded lag
     # shows up as transient mid-convergence distance from the sync
-    # arm, which the band was never meant to screen (the same reason
-    # exp_fit_gap measures at its full sweep budget).
+    # arm, which the band was never meant to screen.
     ap.add_argument("--sweeps", type=int, default=20)
     ap.add_argument("--topics", type=int, default=20)
     ap.add_argument("--chains", type=int, default=1)

@@ -71,8 +71,7 @@ class Spec:
     tp_pairs: int = 3
     beacon_events: int = 2      # beacon rows per batch (more rows per
     #                             batch accumulate word mass and fade
-    #                             the campaign out of the winner set —
-    #                             the docs/PERF.md campaign effect)
+    #                             the campaign out of the winner set)
     feedback_batch: int = 2     # label the beacon after this batch (1-based)
     max_online_lag: int = 5
     n_buckets: int = 1 << 10
@@ -153,8 +152,8 @@ def run_arm(spec: Spec, name: str, *, feedback: bool,
     # Burn-in: background-only epochs train the model before the
     # campaigns START (scores from a cold SVI model sit near the
     # uniform prior and rank by noise; and a campaign word seen all
-    # through training accumulates mass until it stops being rare —
-    # the campaign-fade effect docs/PERF.md documents). The measured
+    # through training accumulates mass until it stops being rare:
+    # the campaign fades). The measured
     # phase then injects the persistent plants into fresh-seed
     # batches: zero-lag detection of a NEW campaign against a warm
     # model, the streaming scorer's actual contract.

@@ -11,9 +11,9 @@ mixed-tenant request stream (onix/serving/load_harness.py):
                request batch, measured under BOTH kernel forms (vmap
                lane-per-request / flat tenant-gather).
 
-Timing is interleaved best-of-REPS (the exp_fit_gap discipline: this
-host's wall clock swings with multi-minute load waves, so alternating
-arms gives both the same weather), winners are asserted BIT-IDENTICAL
+Timing is interleaved best-of-REPS (this host's wall clock swings
+with multi-minute load waves, so alternating arms gives both the same
+weather), winners are asserted BIT-IDENTICAL
 between every banked form and the sequential oracle, and dispatch
 counts record the N → 1 collapse. A second section replays a windowed
 (cacheable) stream through a capacity-CAPPED bank for the serving

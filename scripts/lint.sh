@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # One-command static gate (r17): the contract linter over onix/,
-# bench.py, and scripts/ (onix/analysis/ — exception discipline, env
+# chip_smoke.py, and scripts/ (onix/analysis/ — exception discipline, env
 # registry, counter namespaces, gate discipline, fingerprint coverage,
 # jit/trace hazards, lock discipline, fault-site/doc drift; see
 # docs/ROBUSTNESS.md "The contract linter"), then the native build's

@@ -297,7 +297,7 @@ def test_repo_scope_still_covers_the_r9_file_set():
     rels = {f.rel for f in AnalysisContext.from_root(REPO).files}
     for must in ("onix/serving/model_bank.py", "onix/feedback/filter.py",
                  "onix/models/pallas_serve.py", "onix/oa/serve.py",
-                 "bench.py"):
+                 "chip_smoke.py"):
         assert must in rels, f"analysis scope lost {must}"
     assert any(r.startswith("scripts/") for r in rels)
 

@@ -21,9 +21,15 @@ import chip_smoke  # noqa: E402
 def _restore_process_globals():
     """`onix.cli.main` and `make_server` route the process-global
     telemetry singletons at the run's (temporary) store; later test
-    modules must not inherit that."""
+    modules must not inherit that. And the phases read the
+    process-global counters (`bank.cache_hit` is phase 2's
+    `cache.hits`), so they start from zero whatever test files the
+    worker has run before this one."""
     from onix.utils import telemetry
+    from onix.utils.obs import counters
+    counters.reset()
     yield
+    counters.reset()
     telemetry.reset_for_tests()
 
 

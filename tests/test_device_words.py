@@ -113,20 +113,20 @@ def test_fused_stream_selection_matches_host_path():
                                   np.asarray(want.scores))
 
 
-@pytest.mark.parametrize("gate", ["0", "1"])
-def test_scale_runner_device_words(tmp_path, gate, monkeypatch):
+@pytest.mark.parametrize("host", ["0", "1"])
+def test_scale_runner_device_words(tmp_path, host, monkeypatch):
     """The scale runner produces equivalent artifacts with words on
     host or device (identical winners at this scale), and records the
     mode."""
     from onix.pipelines import scale
 
-    monkeypatch.setenv("ONIX_DEVICE_WORDS", gate)
-    out = tmp_path / f"scale_{gate}.json"
+    monkeypatch.setenv("ONIX_HOST_WORDS", host)
+    out = tmp_path / f"scale_{host}.json"
     doc = scale.run_scale(30_000, train_events=15_000, n_sweeps=8,
                           seed=5, out_path=out)
-    assert doc["words_mode"] == ("device" if gate == "1" else "host")
+    assert doc["words_mode"] == ("host" if host == "1" else "device")
     assert doc["planted_in_bottom_k"] > 0
-    if gate == "1":
+    if host == "0":
         assert doc["walls_seconds"].get("stream_words_map", 0.0) < 0.5
 
 
@@ -134,9 +134,9 @@ def test_scale_runner_device_vs_host_same_winners(tmp_path, monkeypatch):
     from onix.pipelines import scale
 
     res = {}
-    for gate in ("0", "1"):
-        monkeypatch.setenv("ONIX_DEVICE_WORDS", gate)
-        res[gate] = scale.run_scale(30_000, train_events=15_000,
+    for host in ("0", "1"):
+        monkeypatch.setenv("ONIX_HOST_WORDS", host)
+        res[host] = scale.run_scale(30_000, train_events=15_000,
                                     n_sweeps=8, seed=5)
     assert (res["0"]["planted_in_bottom_k"]
             == res["1"]["planted_in_bottom_k"])
@@ -215,12 +215,12 @@ def test_scale_runner_device_words_dns_proxy(tmp_path, datatype,
     from onix.pipelines import scale
 
     res = {}
-    for gate in ("0", "1"):
-        monkeypatch.setenv("ONIX_DEVICE_WORDS", gate)
-        res[gate] = scale.run_scale(24_000, train_events=12_000,
+    for host in ("0", "1"):
+        monkeypatch.setenv("ONIX_HOST_WORDS", host)
+        res[host] = scale.run_scale(24_000, train_events=12_000,
                                     n_sweeps=8, seed=5, datatype=datatype)
-        assert res[gate]["words_mode"] == ("device" if gate == "1"
-                                           else "host")
+        assert res[host]["words_mode"] == ("host" if host == "1"
+                                           else "device")
     assert (res["0"]["planted_in_bottom_k"]
             == res["1"]["planted_in_bottom_k"])
     assert (res["0"]["selected_score_range"]
@@ -228,11 +228,10 @@ def test_scale_runner_device_words_dns_proxy(tmp_path, datatype,
 
 
 def test_host_words_env_spellings(tmp_path, monkeypatch):
-    """Device words are the DEFAULT; ONIX_HOST_WORDS=1 (and the legacy
-    ONIX_DEVICE_WORDS=0) pin the host cross-check arm."""
+    """Device words are the DEFAULT; ONIX_HOST_WORDS=1 pins the host
+    cross-check arm."""
     from onix.pipelines import scale
 
-    monkeypatch.delenv("ONIX_DEVICE_WORDS", raising=False)
     monkeypatch.delenv("ONIX_HOST_WORDS", raising=False)
     m = scale.run_scale(20_000, train_events=10_000, n_sweeps=6, seed=5)
     assert m["words_mode"] == "device"
@@ -351,7 +350,6 @@ def test_scale_flow_table_build_failure_degrades_to_host(monkeypatch):
         raise ValueError("synthetic compact-key overflow")
 
     monkeypatch.delenv("ONIX_HOST_WORDS", raising=False)
-    monkeypatch.delenv("ONIX_DEVICE_WORDS", raising=False)
     monkeypatch.setattr(device_words, "build_flow_tables", boom)
     m = scale.run_scale(20_000, train_events=10_000, n_sweeps=6, seed=5)
     assert m["words_mode"] == "host"

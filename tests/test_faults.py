@@ -252,7 +252,7 @@ def test_chaos_plan_end_to_end_artifacts_identical(tmp_path):
 # under its historical name so coverage never lapses across the move:
 # the same handler set (Exception/BaseException/bare), the same
 # visibility calls, over the same file scope (all of onix/ plus
-# bench.py and scripts/*.py — scope preservation itself is asserted in
+# chip_smoke.py and scripts/*.py — scope preservation itself is asserted in
 # tests/test_analysis.py::test_repo_scope_still_covers_the_r9_file_set).
 # ---------------------------------------------------------------------------
 
@@ -260,7 +260,7 @@ def test_chaos_plan_end_to_end_artifacts_identical(tmp_path):
 def test_no_silent_except_exception_in_onix():
     """Every `except Exception` / `except BaseException` / BARE
     `except:` handler in onix/ (serving and feedback included), in
-    bench.py, and in scripts/ must log, increment an obs counter,
+    chip_smoke.py, and in scripts/ must log, increment an obs counter,
     re-raise, or otherwise answer visibly — a swallowed exception in a
     resilience-hardened pipeline is indistinguishable from silent data
     loss."""

@@ -181,36 +181,44 @@ def test_fit_ll_history_lands_on_superstep_boundaries():
     assert all(np.isfinite(ll) for _, ll in fit["ll_history"])
 
 
-def test_nwk_matmul_form_bit_identical():
-    """The MXU one-hot-matmul n_wk delta must equal the scatter form
-    bit for bit over full sweeps (it is exact integer math in f32 —
-    lda_gibbs module comment at _NWK_MATMUL_MAX_V)."""
+# The product vocabulary's width, a tiny vocabulary over several
+# blocks, and a block that is no multiple of 8; both draw forms: the
+# race is what CPU runs, the Gumbel-argmax what the chip runs.
+@pytest.mark.parametrize(
+    "n_docs,n_vocab,k,block",
+    [(150, 512, 20, 640), (60, 40, 4, 256), (50, 64, 5, 1000)])
+@pytest.mark.parametrize("sampler", ["race", "gumbel"])
+def test_nwk_matmul_form_bit_identical(n_docs, n_vocab, k, block, sampler):
+    """The MXU one-hot-matmul n_wk delta (the form the chip runs) must
+    equal the scatter form (the form tier-1 runs) bit for bit over full
+    sweeps: it is exact integer math in f32 (lda_gibbs module comment
+    at _NWK_MATMUL_MAX_V)."""
     import jax
-    import jax.numpy as jnp
 
     from onix.models.lda_gibbs import init_state, make_block_step
 
-    corpus, _, _ = synthetic_lda_corpus(n_docs=60, n_vocab=40, n_topics=4,
+    corpus, _, _ = synthetic_lda_corpus(n_docs, n_vocab, min(k, 5),
                                         mean_doc_len=30, seed=2)
-    cfg = LDAConfig(n_topics=4, n_sweeps=3, block_size=128, seed=1)
+    cfg = LDAConfig(n_topics=k, n_sweeps=3, block_size=block, seed=1)
     model = GibbsLDA(cfg, corpus.n_docs, corpus.n_vocab)
     docs, words, mask = model.prepare(corpus)
     states = {}
-    for form in (False, True):
+    for form in ("scatter", "matmul"):
         step = make_block_step(alpha=cfg.alpha, eta=cfg.eta,
-                               n_vocab=corpus.n_vocab,
-                               k_topics=cfg.n_topics, nwk_matmul=form)
+                               n_vocab=corpus.n_vocab, k_topics=k,
+                               nwk_form=form, sampler=sampler)
         st = init_state(docs, words, mask, corpus.n_docs, corpus.n_vocab,
-                        cfg.n_topics, cfg.seed)
+                        k, cfg.seed)
         carry = (st.n_dk, st.n_wk, st.n_k, st.key)
         z = st.z
         for _ in range(cfg.n_sweeps):
             carry, z = jax.lax.scan(step, carry, (docs, words, mask, z))
         states[form] = (np.asarray(carry[0]), np.asarray(carry[1]),
                         np.asarray(carry[2]), np.asarray(z))
-    for a, b in zip(states[False], states[True]):
-        np.testing.assert_array_equal(a, b)
+    for name, a, b in zip(("n_dk", "n_wk", "n_k", "z"),
+                          states["scatter"], states["matmul"]):
+        np.testing.assert_array_equal(a, b, err_msg=name)
     # Count-table invariants hold for the matmul form.
-    n_dk, n_wk, n_k, _ = states[True]
+    n_dk, n_wk, n_k, _ = states["matmul"]
     assert n_wk.sum() == int(np.asarray(mask).sum())
     np.testing.assert_array_equal(n_wk.sum(axis=0), n_k)

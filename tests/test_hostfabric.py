@@ -40,12 +40,15 @@ from onix.utils.obs import counters
 # 2-worker fabric fit (spawn + compile + 6 sweeps) stays ~10-20s.
 CFG = LDAConfig(n_topics=4, n_sweeps=6, burn_in=2, block_size=256,
                 superstep=2, seed=1, checkpoint_every=2)
-# Tight-ish lease/beat so death detection is fast, but with margin for
-# a loaded 1-core CI host: the beat thread is GIL-starved during XLA
-# compiles, and a lease shorter than that starvation false-positives a
-# live worker as dead (the fabric survives that too — it restarts — but
-# the tests assert exactly ONE death, the one we inflicted).
-FABRIC_KW = dict(n_hosts=2, local_devices=1, lease_s=4.0, beat_s=0.3,
+# The lease is what a loaded machine breaks: with six test workers and
+# two spawned fit workers sharing the cores, a live worker's beat
+# thread (GIL-starved during XLA compiles besides) misses a 4 s lease,
+# the coordinator declares a death nobody inflicted and restarts (the
+# fabric survives that — but the tests assert exactly ONE death, the
+# one we inflicted, or none). 20 s costs each drill with a kill that
+# long to detect it and is far past any starvation seen; the beat
+# stays short so that a live worker renews often.
+FABRIC_KW = dict(n_hosts=2, local_devices=1, lease_s=20.0, beat_s=0.3,
                  collective_deadline_s=60.0, timeout_s=240.0)
 KILL = {"host": 1, "after_sweep": 2}
 
@@ -73,8 +76,14 @@ def test_sigkill_quarantine_resume_sync_bitidentical(
     lease-based death detection; shard quarantined with a sidecar; the
     same-topology restart resumes from the last common superstep
     boundary and finishes BIT-IDENTICAL to the fault-free fit."""
+    from onix.utils import telemetry
+
     tel = tmp_path / "tel"
     tel.mkdir()
+    # The recorder is a process global: an earlier test file of this
+    # worker may have routed it elsewhere, switched it off or used up
+    # its dump cap.
+    telemetry.reset_for_tests()
     monkeypatch.setenv("ONIX_TELEMETRY_DIR", str(tel))
     ref = _ref_fit(corpus, CFG)
     before = {k: _host_counter(k) for k in
@@ -170,15 +179,12 @@ def test_torn_host_ckpt_excluded_from_resume(corpus, tmp_path, monkeypatch):
 def test_rebalance_on_death(tmp_path):
     """A dead host under on_death='rebalance': the corpus re-shards onto
     the survivor behind a deliberate fingerprint bump (stamped as
-    rebalanced_from in the topology claim), and the rebalanced model
-    keeps ll parity and plant detection with the fault-free fit."""
-    from onix.models.scoring import score_all
-
-    corpus, planted = anomaly_corpus(n_docs=48, n_vocab=96, n_topics=4,
-                                     mean_doc_len=60, n_anomalies=10,
-                                     seed=5)
+    rebalanced_from in the topology claim), and the rebalanced model is
+    the fault-free fit of the topology it fell back to, to the bit."""
+    corpus, _ = anomaly_corpus(n_docs=48, n_vocab=96, n_topics=4,
+                               mean_doc_len=60, n_anomalies=10, seed=5)
     rcfg = dataclasses.replace(CFG, n_sweeps=8, burn_in=4)
-    ref = _ref_fit(corpus, rcfg)
+    ref = _ref_fit(corpus, rcfg, dp=1)
     before = _host_counter("rebalance")
     wd = tmp_path / "fabric"
     out = hostfabric.run_fit(corpus, rcfg, wd, kill_plan=KILL,
@@ -197,20 +203,17 @@ def test_rebalance_on_death(tmp_path):
     # generation starts clean rather than misreading 2-host shards.
     assert m["resume_sweeps"][-1] == -1
 
-    # Parity with the fault-free fit: ll band + plant detection.
-    ref_ll = ref["ll_history"][-1][1]
-    fab_ll = out["ll_history"][-1][1]
-    assert abs(fab_ll - ref_ll) <= 0.05 * abs(ref_ll), (ref_ll, fab_ll)
-    k = 3 * len(planted)
-    hits_of = lambda fit: len(  # noqa: E731
-        set(np.argsort(score_all(fit["theta"], fit["phi_wk"],
-                                 corpus.doc_ids, corpus.word_ids),
-                       kind="stable")[:k].tolist())
-        & set(planted.tolist()))
-    hits_ref, hits_fab = hits_of(ref), hits_of(out)
-    assert hits_ref >= len(planted) // 2, hits_ref
-    assert hits_fab >= len(planted) // 2, hits_fab
-    assert abs(hits_fab - hits_ref) <= 3, (hits_ref, hits_fab)
+    # The survivor starts clean on the whole corpus, so its model is
+    # the in-process one-host fit, bit for bit: the same contract the
+    # same-topology drill holds the two-host fit to. (A band against
+    # the TWO-host fit compares two chains, not a fault with its
+    # absence: after these 8 sweeps neither has converged, and dp=2,
+    # whose shards sample against each other's stale counts, climbs
+    # slower: ll -2.943 against -2.713 here, -2.690 against -2.691
+    # after 64 sweeps.)
+    assert np.array_equal(ref["theta"], out["theta"])
+    assert np.array_equal(ref["phi_wk"], out["phi_wk"])
+    assert out["ll_history"][-1] == ref["ll_history"][-1]
 
 
 # ---------------------------------------------------------------------------

@@ -150,28 +150,3 @@ def test_roofline_math_and_cpu_peak():
     peak, src = device_peak_bytes_per_s()
     assert peak and peak > 1e8           # tests force the CPU backend
     assert "probe" in src
-
-
-def test_bench_roofline_detail_shapes():
-    """bench._roofline_detail derives scoring-scan and gibbs-sweep
-    entries from completed component dicts and skips partials."""
-    import bench
-
-    detail = {
-        "scoring_uniform": {"n_events_per_pass": 1 << 20,
-                            "passes_in_one_program": 2,
-                            "wall_seconds": 1.0,
-                            "selection": "bf16_screened_f32_rescore"},
-        "gibbs_sweep": {"n_tokens": 1 << 20, "sweeps_in_one_program": 2,
-                        "n_topics": 20, "wall_seconds": 1.0},
-    }
-    rl = bench._roofline_detail(detail)
-    assert set(rl) >= {"peak_bytes_per_s", "peak_source",
-                       "scoring_scan", "gibbs_sweep"}
-    # bf16 selection halves the modeled gather bytes vs f32.
-    assert rl["scoring_scan"]["modeled_bytes_per_item"] == 2 * 20 * 2 + 12
-    assert rl["gibbs_sweep"]["modeled_bytes_per_item"] == 4 * 20 * 4 + 12
-    assert rl["scoring_scan"]["achieved_bytes_per_s"] > 0
-    # A partial checkpoint (no wall yet) must not produce an entry.
-    rl2 = bench._roofline_detail({"scoring_uniform": {"partial": "x"}})
-    assert "scoring_scan" not in rl2

@@ -47,33 +47,32 @@ def test_select_sampler_form_priorities(monkeypatch):
     assert select_sampler_form(backend="cpu", k_topics=20) == "dense"
 
 
-def test_auto_gate_defers_to_explicit_nwk_pin(monkeypatch):
-    """A user who pinned nwk_form (config or ONIX_NWK_FORM) is running
-    an n_wk experiment; the sparse arm has no n_wk form, so the AUTO
-    sampler gate must stay dense instead of silently stealing the run.
-    An explicit sampler_form (config or env) still wins."""
+def test_sampler_resolution_order(monkeypatch):
+    """The sampler resolution's order: the config field, then
+    ONIX_SAMPLER_FORM, then the measured table - and nothing else (an
+    n_wk experiment used to hold the auto gate on dense; there is no
+    such pin any more)."""
     from onix.models.lda_gibbs import resolve_sampler
-    monkeypatch.delenv("ONIX_NWK_FORM", raising=False)
     monkeypatch.delenv("ONIX_SAMPLER_FORM", raising=False)
     cfg = LDAConfig(n_topics=64)
+    # The table alone: sparse from the cpu crossover up, dense below.
     assert resolve_sampler(cfg, k_topics=64)[0] == "sparse"
-    assert resolve_sampler(cfg, k_topics=64,
-                           nwk_form="matmul")[0] == "dense"
-    monkeypatch.setenv("ONIX_NWK_FORM", "pallas")
+    assert resolve_sampler(LDAConfig(n_topics=20), k_topics=20)[0] == "dense"
+    # The environment outranks the table, in both directions.
+    monkeypatch.setenv("ONIX_SAMPLER_FORM", "dense")
     assert resolve_sampler(cfg, k_topics=64)[0] == "dense"
-    monkeypatch.delenv("ONIX_NWK_FORM")
-    # Explicit sampler_form outranks the pin in both directions.
-    cfg_s = LDAConfig(n_topics=64, sampler_form="sparse")
-    assert resolve_sampler(cfg_s, k_topics=64,
-                           nwk_form="matmul")[0] == "sparse"
     monkeypatch.setenv("ONIX_SAMPLER_FORM", "sparse")
-    assert resolve_sampler(cfg, k_topics=64,
-                           nwk_form="matmul")[0] == "sparse"
-    # Both engines ride the same resolver: the pinned-nwk GibbsLDA
-    # stays dense at a K where auto would pick sparse.
+    assert resolve_sampler(LDAConfig(n_topics=20), k_topics=20)[0] == "sparse"
+    # The config field outranks the environment.
+    cfg_d = LDAConfig(n_topics=64, sampler_form="dense")
+    assert resolve_sampler(cfg_d, k_topics=64)[0] == "dense"
+    monkeypatch.setenv("ONIX_SAMPLER_FORM", "dense")
+    cfg_s = LDAConfig(n_topics=20, sampler_form="sparse")
+    assert resolve_sampler(cfg_s, k_topics=20)[0] == "sparse"
+    # Both engines ride the same resolver.
     monkeypatch.delenv("ONIX_SAMPLER_FORM")
-    m = GibbsLDA(LDAConfig(n_topics=64, nwk_form="scatter"), 50, 40)
-    assert m.sampler_form == "dense"
+    assert GibbsLDA(cfg, 50, 40).sampler_form == "sparse"
+    assert GibbsLDA(cfg_d, 50, 40).sampler_form == "dense"
 
 
 def test_env_sampler_form_override(monkeypatch):
@@ -90,12 +89,11 @@ def test_env_sampler_form_override(monkeypatch):
     assert GibbsLDA(cfg, 10, 20).sampler_form == "sparse"
 
 
-def test_sweep_kernel_auto_defers_to_env_nwk_pin(monkeypatch):
+def test_sweep_kernel_resolves_like_the_engines(monkeypatch):
     """make_sweep_kernel is reachable by standalone callers that never
-    go through resolve_sampler, so its auto gate must apply the SAME
-    nwk-pin deference for the env spelling (ONIX_NWK_FORM), not just
-    the argument spelling — otherwise an env-pinned n_wk experiment at
-    K past the crossover silently measures the sparse arm."""
+    go through resolve_sampler, so it must apply the SAME order:
+    its argument, then ONIX_SAMPLER_FORM, then the measured gate
+    untouched - the tests' n_wk pin does not hold it on dense."""
     from onix.models import lda_gibbs
 
     seen = {}
@@ -105,16 +103,17 @@ def test_sweep_kernel_auto_defers_to_env_nwk_pin(monkeypatch):
         seen["sampler_form"] = kw.get("sampler_form")
         return real(**kw)
 
-    monkeypatch.delenv("ONIX_SAMPLER_FORM", raising=False)
     monkeypatch.setattr(lda_gibbs, "select_sampler_form", spy)
-    monkeypatch.setenv("ONIX_NWK_FORM", "matmul")
+    monkeypatch.setenv("ONIX_SAMPLER_FORM", "dense")
     lda_gibbs.make_sweep_kernel(alpha=0.5, eta=0.01, n_vocab=16,
                                 k_topics=64)
     assert seen["sampler_form"] == "dense"
-    # Without the pin, auto reaches the measured gate untouched.
-    monkeypatch.delenv("ONIX_NWK_FORM")
     lda_gibbs.make_sweep_kernel(alpha=0.5, eta=0.01, n_vocab=16,
-                                k_topics=64)
+                                k_topics=64, sampler_form="sparse")
+    assert seen["sampler_form"] == "sparse"
+    monkeypatch.delenv("ONIX_SAMPLER_FORM")
+    lda_gibbs.make_sweep_kernel(alpha=0.5, eta=0.01, n_vocab=16,
+                                k_topics=64, nwk_form="scatter")
     assert seen["sampler_form"] is None
 
 

@@ -514,13 +514,22 @@ def test_prefetcher_clean_shutdown_on_early_exit():
     assert pulled <= 1 + 3, "early exit kept draining the source"
 
 
-def test_prefetcher_process_mode_matches_thread(monkeypatch):
+def test_prefetcher_process_mode_matches_thread(monkeypatch, tmp_path):
     """The process-pool arm must be a pure transport change: identical
     (table, cols) handoffs and identical downstream scores. Counter
     deltas tallied in a worker process (e.g. salvage) merge back into
     the parent registry."""
     monkeypatch.delenv("ONIX_PREFETCH_MODE", raising=False)
     from onix.pipelines.streaming import ColumnPrefetcher
+
+    # The prefetcher pins threads where `__main__` has no file (stdin,
+    # `python -c`), and a pytest-xdist worker is such a process: give
+    # it one, so that the pool arm is what runs however the tests are
+    # started. Spawned workers run that file as `__mp_main__`.
+    import __main__
+    (tmp_path / "main.py").write_text("")
+    monkeypatch.setattr(__main__, "__file__", str(tmp_path / "main.py"),
+                        raising=False)
 
     table, _ = synth_flow_day(n_events=600, n_hosts=40, n_anomalies=3,
                               seed=9)
