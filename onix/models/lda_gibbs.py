@@ -56,6 +56,75 @@ def _one_hot(z: jax.Array, k: int) -> jax.Array:
     return jax.nn.one_hot(z, k, dtype=jnp.int32)
 
 
+# Tokens one step of a count build adds to the tables: a scatter-add of
+# up to 2^14 updates compiles without the sort that 2^15 and more bring
+# (15 s to compile at 2^17; PERF.md section 6, PR 32).
+_COUNT_ROWS = 1 << 13
+
+
+def zero_counts(n_docs: int, n_vocab: int, n_topics: int):
+    """Empty count tables for `count_block`: n_dk FLAT [D*K], n_wk [V, K]."""
+    if n_docs * n_topics >= 2 ** 31:
+        raise ValueError(
+            f"{n_docs} documents x {n_topics} topics pass the int32 "
+            "range the flat doc-topic table is indexed in")
+    return (jnp.zeros((n_docs * n_topics,), jnp.int32),
+            jnp.zeros((n_vocab, n_topics), jnp.int32))
+
+
+def count_block(tables, xs, *, n_topics: int):
+    """One token block added to the count tables: the `lax.scan` step
+    both engines build their first counts with (`build_counts` here,
+    the draw-and-count scan of `ShardedGibbsLDA.init_state`).
+
+    carry = (n_dk, n_wk) as `zero_counts` makes them; xs = (docs, words,
+    z) of one block, added in runs of `_COUNT_ROWS` tokens where they
+    divide the block (the adds are exact, so their order is free). Each
+    token adds ONE to entry `doc * K + z` of the flat n_dk (the padding
+    sentinel z == K aims past its end and is dropped) and its one-hot
+    row to n_wk (the sentinel's is zero). The forms are the ones each
+    table is fast in on the chip, a token: n_dk 9.3 ns flat, 44 ns as
+    rows of K lanes (12.7 ns where the scatter sorts, which takes 15 s
+    to compile); n_wk 7.5 ns as rows, 27 ns flat (PERF.md section 6,
+    PR 32)."""
+    rows = xs[0].shape[0]
+    if rows % _COUNT_ROWS == 0:
+        rows = _COUNT_ROWS
+
+    def run(tables, xs):
+        n_dk, n_wk = tables
+        d, w, z = xs
+        at = jnp.where(z < n_topics, d * n_topics + z, n_dk.shape[0])
+        return (n_dk.at[at].add(1, mode="drop"),
+                n_wk.at[w].add(_one_hot(z, n_topics))), None
+
+    return jax.lax.scan(run, tables,
+                        tuple(a.reshape(-1, rows) for a in xs))
+
+
+def shape_counts(tables, n_topics: int):
+    """`count_block`'s tables -> (n_dk [D, K], n_wk [V, K], n_k [K])."""
+    n_dk, n_wk = tables
+    # Through uint32 and back (exact: the counts are not negative): the
+    # TPU compiler takes 17-24 s over a reshape that reads the flat table
+    # where the scan left it, in fast memory, and 2 s once a convert in
+    # front has copied it out (PERF.md section 6, PR 32).
+    n_dk = n_dk.astype(jnp.uint32).reshape(-1, n_topics).astype(jnp.int32)
+    return n_dk, n_wk, n_wk.sum(axis=0, dtype=jnp.int32)
+
+
+def build_counts(doc_blocks, word_blocks, z, n_docs: int, n_vocab: int,
+                 n_topics: int):
+    """Exact (n_dk, n_wk, n_k) of the assignments `z`, blockwise under
+    `lax.scan`: a flat one-hot over the whole corpus would materialize
+    an [N, K]-padded temp that OOMs HBM past ~10M tokens (hit at 40M)."""
+    tables, _ = jax.lax.scan(
+        functools.partial(count_block, n_topics=n_topics),
+        zero_counts(n_docs, n_vocab, n_topics),
+        (doc_blocks, word_blocks, z))
+    return shape_counts(tables, n_topics)
+
+
 def init_state_keyed(
     key: jax.Array,
     doc_blocks: jax.Array,
@@ -65,29 +134,13 @@ def init_state_keyed(
     n_vocab: int,
     n_topics: int,
 ) -> GibbsState:
-    """Random topic init + exact count build, blockwise.
-
-    Counts are scattered one token block at a time under `lax.scan`: a
-    flat one-hot over the whole corpus would materialize an
-    [N, K]-padded temp that OOMs HBM past ~10M tokens (hit at 40M)."""
+    """Random topic init + exact count build (`build_counts`)."""
     key, zkey = jax.random.split(key)
     shape = doc_blocks.shape
     z = jax.random.randint(zkey, shape, 0, n_topics, dtype=jnp.int32)
     z = jnp.where(mask_blocks > 0, z, n_topics)   # sentinel for padding
-
-    def count_block(carry, xs):
-        n_dk, n_wk, n_k = carry
-        d, w, zb = xs
-        oh = _one_hot(zb, n_topics)               # [B, K]; padding -> 0
-        return (n_dk.at[d].add(oh), n_wk.at[w].add(oh),
-                n_k + oh.sum(axis=0, dtype=jnp.int32)), None
-
-    (n_dk, n_wk, n_k), _ = jax.lax.scan(
-        count_block,
-        (jnp.zeros((n_docs, n_topics), jnp.int32),
-         jnp.zeros((n_vocab, n_topics), jnp.int32),
-         jnp.zeros((n_topics,), jnp.int32)),
-        (doc_blocks, word_blocks, z))
+    n_dk, n_wk, n_k = build_counts(doc_blocks, word_blocks, z,
+                                   n_docs, n_vocab, n_topics)
     return GibbsState(
         z=z, n_dk=n_dk, n_wk=n_wk, n_k=n_k, key=key,
         acc_ndk=jnp.zeros((n_docs, n_topics), jnp.float32),
@@ -121,14 +174,16 @@ def init_chains(
     n_chains: int,
 ) -> GibbsState:
     """Stacked state for `n_chains` independent chains (leading chain
-    axis on every array). Chains differ only in their PRNG streams; on
-    TPU vmap turns the per-chain gathers/scatters into one batched
-    program, so C chains cost ~one sweep of C× the tokens."""
+    axis on every array). Chains differ only in their PRNG streams. Each
+    is made on its own and the states stacked: under a chain vmap every
+    scatter-add of the count build copied its whole stacked table twice
+    (PERF.md section 6, PR 32)."""
     keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
         jax.random.PRNGKey(seed), jnp.arange(n_chains, dtype=jnp.uint32))
-    return jax.vmap(
-        lambda k: init_state_keyed(k, doc_blocks, word_blocks, mask_blocks,
-                                   n_docs, n_vocab, n_topics))(keys)
+    return jax.tree.map(
+        lambda *chains: jnp.stack(chains),
+        *(init_state_keyed(k, doc_blocks, word_blocks, mask_blocks,
+                           n_docs, n_vocab, n_topics) for k in keys))
 
 
 # The n_wk count update has two bit-identical forms (the tests compare

@@ -463,7 +463,7 @@ def _worker_body(workdir: pathlib.Path, host_id: int, spec: dict,
             return 4
         start = resume_sweep + 1
     if state is None:
-        state = engine.init_state(sc)
+        state = engine.init_state(sc, device_blocks=(docs, words, mask))
 
     from onix.models.lda_gibbs import (SUPERSTEP_DEFAULT, plan_segments,
                                        run_fit_segments)

@@ -79,6 +79,11 @@ class ShardedCorpus(NamedTuple):
     n_vocab_local: int        # Vc = ceil(V / M)
 
 
+# A token of a bucket on its way into the blocks: local doc id and
+# chunk word row as one 8-byte item, so one in-place shuffle deals both.
+_TOKEN_PAIR = np.dtype([("d", np.int32), ("w", np.int32)])
+
+
 def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
                  seed: int = 0, n_mp: int = 1,
                  n_groups: int = 1) -> ShardedCorpus:
@@ -87,10 +92,12 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
     `n_groups` pads the block count to a multiple so the sweep can
     synchronize counts after every group (cfg.sync_splits)."""
     n_docs = corpus.n_docs
-    lengths = corpus.doc_lengths()
     # Snake round-robin over docs sorted by length (desc): near-optimal
     # load balance, fully vectorized — no per-document Python loop (the
     # partitioner must handle ~10^6 IP documents, SURVEY.md §7.3.4).
+    # One shard takes every document whatever the order: no lengths.
+    lengths = (corpus.doc_lengths() if n_data > 1
+               else np.zeros(n_docs, np.int32))
     order = np.argsort(lengths, kind="stable")[::-1]
     pos = np.arange(n_docs)
     fwd = pos % n_data
@@ -109,13 +116,29 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
     doc_map = np.full((n_data, d_local), -1, np.int32)
     doc_map[shard_of_doc, local_of_doc] = np.arange(n_docs, dtype=np.int32)
 
-    # Bucket tokens by (doc's data shard, word % n_mp); pad all buckets
-    # to the max bucket token count.
+    # Bucket = (doc's data shard, word % n_mp), padded to the largest.
+    # Every token is touched once: a bucket's (local doc, chunk row)
+    # pairs are packed side by side, shuffled in place (the same
+    # Fisher-Yates draws, so the same order, as `d[perm], w[perm]` with
+    # `perm = rng.permutation(n)`) and unpacked straight into the padded
+    # blocks. Where the mesh's shape says an answer (one data shard: a
+    # document's local id is its own; one chunk: a word's row is its id;
+    # one bucket: every token is in it) the arithmetic is not done.
     rng = np.random.default_rng(seed)
-    tok_data = shard_of_doc[corpus.doc_ids]
-    tok_mp = (corpus.word_ids % n_mp).astype(np.int64)
-    bucket = tok_data.astype(np.int64) * n_mp + tok_mp
-    bucket_counts = np.bincount(bucket, minlength=n_data * n_mp)
+    doc_ids, word_ids = corpus.doc_ids, corpus.word_ids
+    tok_bucket = None                       # int32, like the ids
+    if n_data > 1:
+        tok_bucket = shard_of_doc[doc_ids]
+        if n_mp > 1:
+            tok_bucket *= np.int32(n_mp)
+    if n_mp > 1:
+        tok_chunk = word_ids % np.int32(n_mp)
+        tok_bucket = tok_chunk if tok_bucket is None else (
+            np.add(tok_bucket, tok_chunk, out=tok_bucket))
+    if tok_bucket is None:
+        bucket_counts = np.array([corpus.n_tokens])
+    else:
+        bucket_counts = np.bincount(tok_bucket, minlength=n_data * n_mp)
     max_tokens = int(bucket_counts.max()) if corpus.n_tokens else 1
     block = min(block_size, max(max_tokens, 1))
     nb = -(-max_tokens // block)
@@ -125,16 +148,19 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
     doc_blocks = np.zeros((n_data, n_mp, padded_len), np.int32)
     word_blocks = np.zeros((n_data, n_mp, padded_len), np.int32)
     mask_blocks = np.zeros((n_data, n_mp, padded_len), np.float32)
-    for p in range(n_data):
-        for m in range(n_mp):
-            sel = bucket == p * n_mp + m
-            d = local_of_doc[corpus.doc_ids[sel]]
-            w = (corpus.word_ids[sel] // n_mp).astype(np.int32)
-            perm = rng.permutation(d.shape[0])
-            d, w = d[perm], w[perm]
-            doc_blocks[p, m, : d.shape[0]] = d
-            word_blocks[p, m, : d.shape[0]] = w
-            mask_blocks[p, m, : d.shape[0]] = 1.0
+    for q, n in enumerate(bucket_counts.tolist()):
+        p, m = divmod(q, n_mp)
+        d, w = doc_ids, word_ids
+        if tok_bucket is not None:
+            sel = tok_bucket == q
+            d, w = d[sel], w[sel]
+        pairs = np.empty(n, _TOKEN_PAIR)
+        pairs["d"] = d if n_data == 1 else local_of_doc[d]
+        pairs["w"] = w if n_mp == 1 else w // np.int32(n_mp)
+        rng.shuffle(pairs)
+        doc_blocks[p, m, :n] = pairs["d"]
+        word_blocks[p, m, :n] = pairs["w"]
+        mask_blocks[p, m, :n] = 1.0
     return ShardedCorpus(
         doc_blocks=doc_blocks.reshape(n_data, n_mp, nb, block),
         word_blocks=word_blocks.reshape(n_data, n_mp, nb, block),
@@ -749,6 +775,76 @@ class ShardedGibbsLDA:
             # (matches GibbsLDA's ll_chains).
             return (s / jnp.maximum(t, 1.0)).mean()
 
+        def init_fn(keys, docs, words, mask, z, n_docs_local: int,
+                    n_vocab_local: int):
+            """The chain's first state, made where the token blocks
+            already are: per (data shard, vocabulary chunk) ONE scan
+            over the blocks that draws every chain's assignments of a
+            block (uniform over K, a key a block split off the chain's
+            key; `z` None) or takes them as given (`z`: the warm start's
+            host draw), plants the sentinel K on the padding and adds
+            the block to each chain's count tables
+            (lda_gibbs.count_block, the single-device engine's). The
+            tables then reduce as the sweep's deltas do - n_dk over the
+            chunks of a data shard, n_wk over the data shards of a
+            chunk, n_k over both - and land in the shardings `_specs`
+            names. The accumulators are zeros made here too. Returns
+            the state's arrays in its fields' order, all but n_acc
+            (init_state)."""
+            drawn = z is None
+            given = () if drawn else (z,)
+
+            def shard_fn(keys, d, w, m, *z_in):
+                d0, w0, m0 = d[0, 0], w[0, 0], m[0, 0]
+                nb = d0.shape[0]
+                key_c = keys[0, 0]                          # [C, 2]
+                if drawn:
+                    key_c, zkey = jnp.moveaxis(
+                        jax.vmap(jax.random.split)(key_c), 1, 0)
+                    src = jax.vmap(                         # [C, nb, 2]
+                        lambda kc: jax.random.split(kc, nb))(zkey)
+                else:
+                    src = z_in[0][0, 0]                     # [C, nb, B]
+                # A chain's tables are a carry of their own, not a row
+                # of a stacked one: under a chain vmap every scatter-add
+                # copied its whole table twice (PERF.md section 6, PR 32).
+                tables0 = (tuple(
+                    jax.lax.pcast(t, both, to="varying")
+                    for t in lda_gibbs.zero_counts(
+                        n_docs_local, n_vocab_local, k)),) * key_c.shape[0]
+
+                def block(tables, xs):
+                    db, wb, mb, i = xs
+                    zb = src[:, i]          # the block's keys, or its z
+                    if drawn:
+                        zb = jax.vmap(lambda kb: jax.random.randint(
+                            kb, mb.shape, 0, k, dtype=jnp.int32))(zb)
+                    zb = jnp.where(mb > 0, zb, k)           # [C, B]
+                    return tuple(
+                        lda_gibbs.count_block(t, (db, wb, zc),
+                                              n_topics=k)[0]
+                        for t, zc in zip(tables, zb)), zb
+
+                tables, z_f = jax.lax.scan(
+                    block, tables0, (d0, w0, m0, jnp.arange(nb)))
+                ndk, nwk, nk = (jnp.stack(t) for t in zip(*(
+                    lda_gibbs.shape_counts(t, k) for t in tables)))
+                ndk = jax.lax.psum(ndk, M) if M else ndk
+                nwk = jax.lax.psum(nwk, D)
+                nk = jax.lax.psum(nk, both)
+                return (z_f.swapaxes(0, 1)[None, None], ndk[None],
+                        nwk[None], nk, key_c[None, None],
+                        jnp.zeros_like(ndk, jnp.float32)[None],
+                        jnp.zeros_like(nwk, jnp.float32)[None])
+
+            tok = P(D, *mp_spec)
+            return jax.shard_map(
+                shard_fn, mesh=self.mesh,
+                in_specs=(tok,) * (4 + len(given)),
+                out_specs=(tok, P(D), P(*mp_spec), P(), tok,
+                           P(D), P(*mp_spec)),
+            )(keys, docs, words, mask, *given)
+
         self._sweep = jax.jit(sweep_fn, static_argnames=("accumulate",),
                               donate_argnums=(0,))
         self._ll = jax.jit(ll_fn)
@@ -781,6 +877,12 @@ class ShardedGibbsLDA:
             wrapped_superstep,
             static_argnames=("n_steps", "with_initial_ll"))
         self._mp_axis = M
+        self._init = jax.jit(
+            init_fn, static_argnames=("n_docs_local", "n_vocab_local"),
+            donate_argnames=("z",),
+            out_shardings=tuple(
+                NamedSharding(self.mesh, spec)
+                for spec in self._specs().values() if spec is not None))
 
     # -- sharding specs ----------------------------------------------------
 
@@ -794,40 +896,43 @@ class ShardedGibbsLDA:
     # -- state construction ----------------------------------------------
 
     def init_state(self, sc: ShardedCorpus,
-                   init_phi: np.ndarray | None = None) -> ShardedGibbsState:
+                   init_phi: np.ndarray | None = None,
+                   device_blocks=None) -> ShardedGibbsState:
+        """The chain's first state, drawn and counted on the device
+        (`init_fn`): no table crosses the host link, and on the cold
+        path no assignment either. `device_blocks` are `sc`'s blocks
+        where the caller has put them already (`device_corpus`)."""
         cfg = self.config
         k = cfg.n_topics
         C = cfg.n_chains
         p, m, nb, b = sc.doc_blocks.shape
-        rng = np.random.default_rng(cfg.seed)
-        if init_phi is None:
-            # Independent initial assignments per chain (the restart
-            # ensemble's whole point); padding shares the K sentinel.
-            z = rng.integers(0, k, size=(p, m, C, nb, b)).astype(np.int32)
-        else:
+        specs = self._specs()
+        z = None
+        if init_phi is not None:
             # φ̂-as-prior warm start (Streaming Gibbs, arxiv
             # 1601.01142): draw each token's initial topic from
             # p(k|w) ∝ init_phi[w, k] — yesterday's posterior word-
             # topic distribution — instead of uniform, so the chain
             # starts near the previous day's mode and needs a fraction
             # of the cold sweep budget (daily.warm_sweeps). Host-side,
-            # deterministic in cfg.seed; counts build from z below
-            # exactly as in the cold path. init_phi rows are GLOBAL
-            # vocab ids; the blocked layout holds local chunk ids
-            # (word // n_mp for chunk word % n_mp).
+            # deterministic in cfg.seed; the device counts it as it
+            # counts its own draw. init_phi rows are GLOBAL vocab ids;
+            # the blocked layout holds local chunk ids (word // n_mp
+            # for chunk word % n_mp).
             init_phi = np.asarray(init_phi, np.float64)
             if init_phi.shape[0] != sc.n_vocab:
                 raise ValueError(
                     f"init_phi covers {init_phi.shape[0]} words, corpus "
                     f"has {sc.n_vocab} — map the prior into TODAY's "
                     "vocabulary first (campaign.map_phi_prior)")
+            rng = np.random.default_rng(cfg.seed)
             z = np.empty((p, m, C, nb * b), np.int32)
             flat_w = sc.word_blocks.reshape(p, m, -1)
             step = 1 << 18       # bound the [T, K] cdf temp, not z
             for q in range(p):
                 for c in range(m):
-                    w_global = flat_w[q, c].astype(np.int64) * m + c
-                    w_global = np.minimum(w_global, sc.n_vocab - 1)
+                    w_global = np.minimum(flat_w[q, c] * np.int32(m)
+                                          + np.int32(c), sc.n_vocab - 1)
                     for s in range(0, w_global.shape[0], step):
                         sl = slice(s, s + step)
                         # The cdf depends only on the words — build it
@@ -839,44 +944,23 @@ class ShardedGibbsLDA:
                             z[q, c, ch, sl] = np.minimum(
                                 (cdf < u[:, None]).sum(axis=1),
                                 k - 1).astype(np.int32)
-            z = z.reshape(p, m, C, nb, b)
-        z = np.where(sc.mask_blocks[:, :, None] > 0, z, k)
-        # Exact global counts built host-side once (init only).
-        n_dk = np.zeros((p, C, sc.n_docs_local, k), np.int32)
-        n_wk = np.zeros((m, C, sc.n_vocab_local, k), np.int32)
-        flat_z = z.reshape(p, m, C, -1)
-        flat_d = sc.doc_blocks.reshape(p, m, -1)
-        flat_w = sc.word_blocks.reshape(p, m, -1)
-        flat_m = sc.mask_blocks.reshape(p, m, -1) > 0
-        for q in range(p):
-            for c in range(m):
-                sel = flat_m[q, c]
-                for ch in range(C):
-                    np.add.at(n_dk[q, ch],
-                              (flat_d[q, c][sel], flat_z[q, c, ch][sel]), 1)
-                    np.add.at(n_wk[c, ch],
-                              (flat_w[q, c][sel], flat_z[q, c, ch][sel]), 1)
-        n_k = n_wk.sum(axis=(0, 2)).astype(np.int32)   # [C, K]
+            z = put_global(z.reshape(p, m, C, nb, b), self.mesh,
+                           specs["z"])
         # Independent per-device/per-chain streams: split, never adjacent
         # raw seeds (seed and seed+1 would otherwise share most streams).
         keys = jax.random.split(jax.random.PRNGKey(cfg.seed),
                                 p * m * C).reshape(p, m, C, -1)
-
-        specs = self._specs()
-        arrays = {
-            "z": z, "n_dk": n_dk, "n_wk": n_wk, "n_k": n_k, "keys": keys,
-            "acc_ndk": np.zeros((p, C, sc.n_docs_local, k), np.float32),
-            "acc_nwk": np.zeros((m, C, sc.n_vocab_local, k), np.float32),
-            "n_acc": np.zeros((), np.int32),
-        }
+        docs, words, mask = device_blocks or self.device_corpus(sc)
+        arrays = self._init(put_global(keys, self.mesh, specs["keys"]),
+                            docs, words, mask, z,
+                            n_docs_local=sc.n_docs_local,
+                            n_vocab_local=sc.n_vocab_local)
         # n_acc's None spec means "leave uncommitted" single-process; a
         # process-spanning mesh needs every jit input globally placed,
         # so it rides an explicitly replicated P() there.
-        put = {name: (jnp.asarray(a)
-                      if specs[name] is None and jax.process_count() == 1
-                      else put_global(a, self.mesh, specs[name] or P()))
-               for name, a in arrays.items()}
-        return ShardedGibbsState(**put)
+        n_acc = (jnp.zeros((), jnp.int32) if jax.process_count() == 1
+                 else put_global(np.zeros((), np.int32), self.mesh, P()))
+        return ShardedGibbsState(*arrays, n_acc=n_acc)
 
     def restore_state(self, arrays: dict[str, np.ndarray]) -> ShardedGibbsState:
         """Rebuild a device-sharded state from checkpointed host arrays,
@@ -1010,11 +1094,25 @@ class ShardedGibbsLDA:
                     start = saved.sweep + 1
             resumed = state is not None
             if not resumed:
-                state = self.init_state(sc, init_phi=init_phi)
+                # The span ends when the device has made the state, not
+                # when the host has asked for it.
+                state = jax.block_until_ready(self.init_state(
+                    sc, init_phi=init_phi,
+                    device_blocks=(docs, words, mask)))
             if span is not None:
-                span.attrs.update(
-                    resumed=resumed,
-                    bytes=sum(int(a.nbytes) for a in state))
+                nbytes = sum(int(a.nbytes) for a in state)
+                span.attrs.update(resumed=resumed, bytes=nbytes)
+                # h2d_bytes, what crossed the host link for the state:
+                # all of a restored one, the assignments of a warm
+                # start's host draw, nothing of a cold start's.
+                if resumed:
+                    span.attrs.update(h2d_bytes=nbytes)
+                elif init_phi is not None:
+                    span.attrs.update(draw="host", counts="device",
+                                      h2d_bytes=int(state.z.nbytes))
+                else:
+                    span.attrs.update(draw="device", counts="device",
+                                      h2d_bytes=0)
         from onix.models.lda_gibbs import run_fit_segments
         segments = plan_segments(
             start, n_sweeps, S_step,

@@ -108,7 +108,7 @@ SPAN_REGISTRY: dict[str, str] = {
     "fit.checkpoint": "run_fit_segments: one checkpoint save at a superstep boundary (state to the host, then to disk)",
     "fit.device_corpus": "ShardedGibbsLDA.fit: the blocked corpus to the device(s) (device_corpus)",
     "fit.estimates": "ShardedGibbsLDA.fit: final counts to the host and theta/phi in global order (estimates)",
-    "fit.init_state": "ShardedGibbsLDA.fit: the chain's first state, drawn on the host or restored from a checkpoint, and its transfer",
+    "fit.init_state": "ShardedGibbsLDA.fit: the chain's first state, drawn (cold: on the device; warm: on the host) and counted on the device, or restored from a checkpoint",
     "fit.notify": "run_fit_segments: the caller's per-boundary callback",
     "fit.prepare": "ShardedGibbsLDA.fit: host layout of the corpus into shard blocks (prepare)",
     "fit.superstep": "run_fit_segments: the dispatch of one fused superstep program (returns with the device still running)",
