@@ -44,6 +44,7 @@ dp×mp, and dcn×dp×mp meshes.
 
 from __future__ import annotations
 
+import bisect
 from typing import NamedTuple
 
 import jax
@@ -148,12 +149,24 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
     doc_blocks = np.zeros((n_data, n_mp, padded_len), np.int32)
     word_blocks = np.zeros((n_data, n_mp, padded_len), np.int32)
     mask_blocks = np.zeros((n_data, n_mp, padded_len), np.float32)
+    by_bucket = None
+    if tok_bucket is not None:
+        # One stable partition of the tokens by bucket (numpy sorts keys
+        # of a byte or two by radix: one pass), not a pass over every
+        # token per bucket: a bucket's tokens are a run of `by_bucket`,
+        # in the corpus's order, as `tok_bucket == q` picked them.
+        by_bucket = np.argsort(
+            tok_bucket.astype(np.min_scalar_type(n_data * n_mp - 1)),
+            kind="stable")
+        del tok_bucket
+    start = 0
     for q, n in enumerate(bucket_counts.tolist()):
         p, m = divmod(q, n_mp)
         d, w = doc_ids, word_ids
-        if tok_bucket is not None:
-            sel = tok_bucket == q
+        if by_bucket is not None:
+            sel = by_bucket[start:start + n]
             d, w = d[sel], w[sel]
+            start += n
         pairs = np.empty(n, _TOKEN_PAIR)
         pairs["d"] = d if n_data == 1 else local_of_doc[d]
         pairs["w"] = w if n_mp == 1 else w // np.int32(n_mp)
@@ -170,6 +183,17 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
         n_vocab=corpus.n_vocab,
         n_vocab_local=-(-corpus.n_vocab // n_mp),
     )
+
+
+def bucket_tokens(sc: ShardedCorpus) -> np.ndarray:
+    """Live tokens of every (data shard, chunk) bucket, int64 [P, M]. A
+    bucket's live tokens come first (`shard_corpus`), so its count is
+    where its mask falls to 0: a search, not a pass over the tokens."""
+    p, m = sc.mask_blocks.shape[:2]
+    flat = sc.mask_blocks.reshape(p, m, -1)
+    return np.array([[bisect.bisect_left(flat[q, c], True,
+                                         key=lambda slot: slot == 0)
+                      for c in range(m)] for q in range(p)], np.int64)
 
 
 def ring_push(ring, delta):
@@ -219,7 +243,11 @@ def put_global(a, mesh, spec) -> jax.Array:
     agree across hosts by construction."""
     sharding = NamedSharding(mesh, spec)
     if jax.process_count() == 1:
-        return jax.device_put(jnp.asarray(a), sharding)
+        # Straight from the host into each chip's shard. Through
+        # `jnp.asarray` first the whole array landed on the first chip
+        # and was dealt out from there: at dp=4 that chip peaked at
+        # 14.4 GB where it holds 2.5 (PERF.md section 6, PR 33).
+        return jax.device_put(a, sharding)
     host = np.asarray(a)
     return jax.make_array_from_callback(host.shape, sharding,
                                         lambda idx: host[idx])
@@ -355,13 +383,15 @@ class ShardedGibbsLDA:
                 # chunk deltas over the data axes (ICI, then DCN),
                 # doc-topic deltas over mp, topic totals over both.
                 # All chains' deltas ride ONE collective (leading C
-                # axis reduces elementwise).
-                d_wk = jax.lax.psum(nwk_new - nwk_v, D)
-                d_dk = (jax.lax.psum(ndk_new - ndk_v, M)
-                        if M else ndk_new - ndk_v)
-                d_k = jax.lax.psum(nk_new - nk_v, both)
-                return (ndk_r + d_dk, nwk_r + d_wk, nk_r + d_k,
-                        key_new), z_new
+                # axis reduces elementwise). Under the scope: the
+                # all-reduces, and in them the wait for the slowest chip.
+                with device_scope("onix.sweep.merge"):
+                    d_wk = jax.lax.psum(nwk_new - nwk_v, D)
+                    d_dk = (jax.lax.psum(ndk_new - ndk_v, M)
+                            if M else ndk_new - ndk_v)
+                    d_k = jax.lax.psum(nk_new - nk_v, both)
+                    return (ndk_r + d_dk, nwk_r + d_wk, nk_r + d_k,
+                            key_new), z_new
 
             (ndk_f, nwk_f, nk_f, key_f), z_out = jax.lax.scan(
                 group_step, (n_dk_l, n_wk_l, n_k_l, key_c),
@@ -409,19 +439,20 @@ class ShardedGibbsLDA:
                 # Peers' deltas = the collective total minus our own;
                 # own deltas stay in the view immediately (the AD-LDA
                 # discipline — a shard is never stale w.r.t. itself).
-                own_wk = nwk_new - nwk_v
-                peer_wk = jax.lax.psum(own_wk, D) - own_wk
-                own_k = nk_new - nk_v
-                peer_k = jax.lax.psum(own_k, both) - own_k
-                fold_wk, r_wk = ring_push(r_wk, peer_wk)
-                fold_k, r_k = ring_push(r_k, peer_k)
-                if M:
-                    own_dk = ndk_new - ndk_v
-                    peer_dk = jax.lax.psum(own_dk, M) - own_dk
-                    fold_dk, r_dk = ring_push(r_dk, peer_dk)
-                    ndk_new = ndk_new + fold_dk
-                return (ndk_new, nwk_new + fold_wk, nk_new + fold_k,
-                        key_new, (r_dk, r_wk, r_k)), z_new
+                with device_scope("onix.sweep.merge"):
+                    own_wk = nwk_new - nwk_v
+                    peer_wk = jax.lax.psum(own_wk, D) - own_wk
+                    own_k = nk_new - nk_v
+                    peer_k = jax.lax.psum(own_k, both) - own_k
+                    fold_wk, r_wk = ring_push(r_wk, peer_wk)
+                    fold_k, r_k = ring_push(r_k, peer_k)
+                    if M:
+                        own_dk = ndk_new - ndk_v
+                        peer_dk = jax.lax.psum(own_dk, M) - own_dk
+                        fold_dk, r_dk = ring_push(r_dk, peer_dk)
+                        ndk_new = ndk_new + fold_dk
+                    return (ndk_new, nwk_new + fold_wk, nk_new + fold_k,
+                            key_new, (r_dk, r_wk, r_k)), z_new
 
             (ndk_f, nwk_f, nk_f, key_f, rings_f), z_out = jax.lax.scan(
                 group_step, (n_dk_l, n_wk_l, n_k_l, key_c, rings),
@@ -472,6 +503,12 @@ class ShardedGibbsLDA:
 
             with device_scope("onix.sweep.loglik"):
                 return jax.vmap(one_chain)(ndk_f, nwk_f, nk_v)
+
+        def _ll_merge(sm, t):
+            """The chips' log-likelihood sums as one: a merge across
+            chips like the sweep's, booked where that is."""
+            with device_scope("onix.sweep.merge"):
+                return jax.lax.psum(sm, both), jax.lax.psum(t, both)
 
         mp_spec = (M,) if M else ()
 
@@ -533,8 +570,7 @@ class ShardedGibbsLDA:
                     nk0_v = jax.lax.pcast(n_k, both, to="varying")
                     sm0, t0 = _chain_ll_local(n_dk[0], n_wk[0], nk0_v,
                                               d0, w0, m0, zero)
-                    sm0 = jax.lax.psum(sm0, both)
-                    t0 = jax.lax.psum(t0, both)
+                    sm0, t0 = _ll_merge(sm0, t0)
 
                 def one_sweep(carry, i):
                     zg, ndk_r, nwk_r, nk_r, key_c, ad, aw, na = carry
@@ -556,7 +592,7 @@ class ShardedGibbsLDA:
                 nk_v = jax.lax.pcast(nk_f, both, to="varying")
                 sm, t = _chain_ll_local(ndk_f, nwk_f, nk_v,
                                         d0, w0, m0, zero)
-                sm, t = jax.lax.psum(sm, both), jax.lax.psum(t, both)
+                sm, t = _ll_merge(sm, t)
                 z_full = z_g2.swapaxes(0, 1).reshape(C, nb, B)
                 outs = (z_full[None, None], ndk_f[None], nwk_f[None],
                         nk_f, key_f[None, None], ad[None], aw[None],
@@ -620,8 +656,7 @@ class ShardedGibbsLDA:
                     # staleness correction.
                     sm0, t0 = _chain_ll_local(n_dk[0], n_wk[0], n_k,
                                               d0, w0, m0, zero)
-                    sm0 = jax.lax.psum(sm0, both)
-                    t0 = jax.lax.psum(t0, both)
+                    sm0, t0 = _ll_merge(sm0, t0)
 
                 def one_sweep(carry, i):
                     (zg, ndk_r, nwk_r, nk_r, key_c, rings,
@@ -662,7 +697,7 @@ class ShardedGibbsLDA:
                 nk_f = nk_f + _ring_sum(r_k)
                 sm, t = _chain_ll_local(ndk_f, nwk_f, nk_f,
                                         d0, w0, m0, zero)
-                sm, t = jax.lax.psum(sm, both), jax.lax.psum(t, both)
+                sm, t = _ll_merge(sm, t)
                 z_full = z_g2.swapaxes(0, 1).reshape(C, nb, B)
                 outs = (z_full[None, None], ndk_f[None], nwk_f[None],
                         nk_f, key_f[None, None], ad[None], aw[None],
@@ -763,7 +798,7 @@ class ShardedGibbsLDA:
                 zero = jax.lax.pcast(jnp.float32(0), both, to="varying")
                 s, t = _chain_ll_local(n_dk[0], n_wk[0], n_k_v,
                                        d[0, 0], w[0, 0], m[0, 0], zero)
-                return jax.lax.psum(s, both), jax.lax.psum(t, both)
+                return _ll_merge(s, t)
 
             s, t = jax.shard_map(
                 shard_fn, mesh=self.mesh,
@@ -829,9 +864,10 @@ class ShardedGibbsLDA:
                     block, tables0, (d0, w0, m0, jnp.arange(nb)))
                 ndk, nwk, nk = (jnp.stack(t) for t in zip(*(
                     lda_gibbs.shape_counts(t, k) for t in tables)))
-                ndk = jax.lax.psum(ndk, M) if M else ndk
-                nwk = jax.lax.psum(nwk, D)
-                nk = jax.lax.psum(nk, both)
+                with device_scope("onix.init.merge"):
+                    ndk = jax.lax.psum(ndk, M) if M else ndk
+                    nwk = jax.lax.psum(nwk, D)
+                    nk = jax.lax.psum(nk, both)
                 return (z_f.swapaxes(0, 1)[None, None], ndk[None],
                         nwk[None], nk, key_c[None, None],
                         jnp.zeros_like(ndk, jnp.float32)[None],
@@ -1032,8 +1068,16 @@ class ShardedGibbsLDA:
         n_sweeps = cfg.n_sweeps if n_sweeps is None else n_sweeps
         S_step = cfg.superstep or SUPERSTEP_DEFAULT
         with telemetry.TRACER.span("fit.prepare", tokens=corpus.n_tokens,
-                                   docs=corpus.n_docs):
+                                   docs=corpus.n_docs) as span:
             sc = self.prepare(corpus)
+            if span is not None:
+                # The shards' balance: the sweep ends with its fullest.
+                per_shard = bucket_tokens(sc).sum(axis=1)
+                span.attrs.update(
+                    shards=self.n_data,
+                    tokens_max_shard=int(per_shard.max()),
+                    tokens_min_shard=int(per_shard.min()),
+                    pad_slots=int(sc.mask_blocks.size - per_shard.sum()))
         with telemetry.TRACER.span(
                 "fit.device_corpus",
                 bytes=sum(int(a.nbytes) for a in (
@@ -1120,21 +1164,32 @@ class ShardedGibbsLDA:
                               if checkpoint_dir is not None else 0),
             fault_sweep=fault_inject_sweep,
             per_sweep=callback is not None)
-        state, ll_history = run_fit_segments(
-            state, start, segments,
-            superstep_fn=lambda st, s0, n, init: self._superstep(
-                st, docs, words, mask, s0, n_steps=n,
-                with_initial_ll=init),
-            initial_ll_fn=lambda st: self._ll(st, docs, words, mask),
-            checkpoint_every=cfg.checkpoint_every,
-            checkpoint_dir=checkpoint_dir,
-            save_fn=lambda st, s: ckpt.save(
-                checkpoint_dir, s,
-                {k: np.asarray(v) for k, v in st._asdict().items()},
-                {"fingerprint": fp, "engine": "sharded_gibbs"}),
-            fault_sweep=fault_inject_sweep,
-            notify=(None if callback is None
-                    else lambda s, st, ll: callback(s, st)))
+        # What one sweep's merge moves per chip: every chain's n_wk
+        # chunk and n_k (n_dk's rows too where mp shards them).
+        merge_bytes = (int(state.n_wk.nbytes) // self.n_mp
+                       + int(state.n_k.nbytes))
+        if self.n_mp > 1:
+            merge_bytes += int(state.n_dk.nbytes) // self.n_data
+        with telemetry.TRACER.span(
+                "fit.supersteps", sweeps=n_sweeps - start,
+                merge_form=self.merge_form,
+                merge_bytes_per_sweep=(merge_bytes
+                                       * max(1, int(cfg.sync_splits)))):
+            state, ll_history = run_fit_segments(
+                state, start, segments,
+                superstep_fn=lambda st, s0, n, init: self._superstep(
+                    st, docs, words, mask, s0, n_steps=n,
+                    with_initial_ll=init),
+                initial_ll_fn=lambda st: self._ll(st, docs, words, mask),
+                checkpoint_every=cfg.checkpoint_every,
+                checkpoint_dir=checkpoint_dir,
+                save_fn=lambda st, s: ckpt.save(
+                    checkpoint_dir, s,
+                    {k: np.asarray(v) for k, v in st._asdict().items()},
+                    {"fingerprint": fp, "engine": "sharded_gibbs"}),
+                fault_sweep=fault_inject_sweep,
+                notify=(None if callback is None
+                        else lambda s, st, ll: callback(s, st)))
         with telemetry.TRACER.span("fit.estimates"):
             theta, phi_wk = self.estimates(state, sc, corpus.n_docs)
         return {"state": state, "sharded_corpus": sc,

@@ -112,6 +112,7 @@ SPAN_REGISTRY: dict[str, str] = {
     "fit.notify": "run_fit_segments: the caller's per-boundary callback",
     "fit.prepare": "ShardedGibbsLDA.fit: host layout of the corpus into shard blocks (prepare)",
     "fit.superstep": "run_fit_segments: the dispatch of one fused superstep program (returns with the device still running)",
+    "fit.supersteps": "ShardedGibbsLDA.fit: the whole sweep loop (run_fit_segments) under one span; attributes say what one sweep's cross-chip merge moves (merge_bytes_per_sweep)",
     "fit.wait": "run_fit_segments: float(ll) at a superstep boundary, the host blocked on the device",
     "fleet.day": "fleet supervisor: one simulated day across every executing tenant (prepare, fleet refit, per-tenant accepts)",
     "fleet.refit": "fleet supervisor: the day's fused fleet refit — stacked warm/cold class dispatches plus the drift-gated cold second pass",

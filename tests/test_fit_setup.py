@@ -73,6 +73,7 @@ def _host_counts(sc, z, k):
 LAYOUTS = {
     (1, 1, 1): "e6f770d5e5567c06", (1, 1, 3): "1b56b8661077f8ba",
     (2, 1, 1): "87c1480f5b546de6", (4, 1, 2): "7bc8b4f9ba505a57",
+    (3, 1, 1): "8453cd71c2c23304", (4, 1, 1): "7bc8b4f9ba505a57",
     (1, 2, 1): "98a29efa6b5da7a1", (1, 4, 1): "b34adb1f16ca3f22",
     (2, 2, 1): "e6d81f5492d82680", (4, 2, 3): "e00dc1176254e28c",
     (2, 4, 1): "3d2cc1003e175a8b",
@@ -119,6 +120,28 @@ def test_shard_corpus_deals_every_token_once(corpus, n_data, n_mp,
     # And it is the deal the engine has always made for this seed.
     assert _digest(sc.doc_blocks, sc.word_blocks, sc.mask_blocks,
                    sc.doc_map) == LAYOUTS[n_data, n_mp, n_groups]
+
+
+# The same on a corpus of 598252 tokens with documents of very unequal
+# lengths, pinned on the tree before PR 33 (a pass over every token per
+# bucket, `tok_bucket == q`): since then one stable partition serves
+# every bucket, and the one generator still shuffles them in order.
+SKEWED = {(4, 1): "4e4fa771010c68b4", (2, 2): "1e6bf4e7c5b6e050",
+          (4, 2): "364c99e349d5f0da"}
+
+
+@pytest.mark.parametrize("n_data,n_mp", sorted(SKEWED))
+def test_one_partition_deals_the_buckets_as_a_pass_each_did(n_data, n_mp):
+    c, _, _ = synthetic_lda_corpus(n_docs=3000, n_vocab=300, n_topics=K,
+                                   mean_doc_len=200, alpha=0.2, eta=0.05,
+                                   seed=6)
+    sc = shard_corpus(c, n_data, 4096, seed=3, n_mp=n_mp)
+    assert _digest(sc.doc_blocks, sc.word_blocks, sc.mask_blocks,
+                   sc.doc_map) == SKEWED[n_data, n_mp]
+    # The span's balance figures come from a search, not a count.
+    from onix.parallel.sharded_gibbs import bucket_tokens
+    np.testing.assert_array_equal(
+        bucket_tokens(sc), (sc.mask_blocks > 0).sum(axis=(2, 3)))
 
 
 def test_shard_corpus_other_seed_other_deal(corpus):
@@ -312,3 +335,54 @@ def test_count_block_is_the_single_device_engines(corpus):
     np.testing.assert_array_equal(np.asarray(n_dk), np.asarray(st.n_dk[0, 0]))
     np.testing.assert_array_equal(np.asarray(n_wk), np.asarray(st.n_wk[0, 0]))
     np.testing.assert_array_equal(np.asarray(n_k), np.asarray(st.n_k[0]))
+
+
+# -- the cross-chip merge has a name ----------------------------------------
+
+def _scopes_in(text):
+    import re
+    found = set()
+    for loc in re.findall(r'loc\("([^"]*)"', text):
+        found.update(p for p in loc.split("/") if p.startswith("onix."))
+    return found
+
+
+def test_merge_scope_is_in_the_shard_map_superstep_alone(eight_devices,
+                                                         corpus):
+    """`onix.sweep.merge` names the psum fold of the `shard_map`
+    superstep (and `onix.init.merge` the first state's); the dp=1 fast
+    path has no psum and no such scope."""
+    texts = {}
+    for dp in (4, 1):
+        model = _model(corpus, dp, 1)
+        sc = model.prepare(corpus)
+        blocks = model.device_corpus(sc)
+        state = model.init_state(sc, device_blocks=blocks)
+        assert model.dp1_fast is (dp == 1)
+        texts[dp] = model._superstep.lower(
+            state, *blocks, 0, n_steps=1,
+            with_initial_ll=False).as_text(debug_info=True)
+        init = model._init.lower(
+            state.keys, *blocks, None, n_docs_local=sc.n_docs_local,
+            n_vocab_local=sc.n_vocab_local).as_text(debug_info=True)
+        assert "onix.init.merge" in _scopes_in(init)
+    assert "onix.sweep.merge" in _scopes_in(texts[4])
+    assert "onix.sweep.scatter" in _scopes_in(texts[1])
+    assert "onix.sweep.merge" not in _scopes_in(texts[1])
+
+
+def test_fit_spans_say_how_the_shards_balance(eight_devices, corpus):
+    telemetry.reset_for_tests()
+    model = _model(corpus, 4, 1, n_chains=2)
+    fit = model.fit(corpus, n_sweeps=2)
+    spans = {s.name: s.attrs for s in telemetry.TRACER.spans()}
+    live = (fit["sharded_corpus"].mask_blocks > 0).sum(axis=(1, 2, 3))
+    assert spans["fit.prepare"] == {
+        "tokens": corpus.n_tokens, "docs": corpus.n_docs, "shards": 4,
+        "tokens_max_shard": int(live.max()),
+        "tokens_min_shard": int(live.min()),
+        "pad_slots": int(fit["sharded_corpus"].mask_blocks.size
+                         - corpus.n_tokens)}
+    assert spans["fit.supersteps"] == {
+        "sweeps": 2, "merge_form": "sync",
+        "merge_bytes_per_sweep": 2 * (corpus.n_vocab * K + K) * 4}

@@ -262,8 +262,16 @@ def test_fit_spans_count_the_work_at_their_boundary():
     spans = {}
     for s in telemetry.TRACER.spans():
         spans.setdefault(s.name, []).append(s)
+    slots = -(-corpus.n_tokens // 256) * 256
     assert spans["fit.prepare"][0].attrs == {
-        "tokens": corpus.n_tokens, "docs": corpus.n_docs}
+        "tokens": corpus.n_tokens, "docs": corpus.n_docs, "shards": 1,
+        "tokens_max_shard": corpus.n_tokens,
+        "tokens_min_shard": corpus.n_tokens,
+        "pad_slots": slots - corpus.n_tokens}
+    # One chain's n_wk [V, K] and n_k [K], int32: what a merge moves.
+    assert spans["fit.supersteps"][0].attrs == {
+        "sweeps": 3, "merge_form": "sync",
+        "merge_bytes_per_sweep": (corpus.n_vocab * 5 + 5) * 4}
     assert spans["fit.init_state"][0].attrs["resumed"] is False
     assert spans["fit.init_state"][0].attrs["bytes"] > 0
     assert spans["fit.device_corpus"][0].attrs["bytes"] > 0
