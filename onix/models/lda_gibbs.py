@@ -238,6 +238,117 @@ def select_nwk_form(*, backend: str, block_size: int, n_rows: int,
     return "scatter"
 
 
+# The n_dk table as the block scan carries it has two bit-identical
+# forms (tests/test_ndk_form.py): "rows", one document a K-lane row
+# ([D, K]: K=20 fills 20 of a row's 128 lanes, so the table, every row
+# read and every row added to is 84% padding on the chip), and
+# "packed", G documents a 128-lane row ([ceil(D/G), 128]): the block
+# step reads row d // G and picks the document's K lanes by d % G, and
+# adds a 128-lane delta to that row. G is the largest power of two of
+# documents that fit, so K=20 packs 4 (80 lanes) and not 6: the chip
+# read the block step 0.9 ns a token faster at 4 (three selects for
+# five in the pick; the scatter-add the same). The one chip reading,
+# flow-fit's shapes, ns a token (PERF.md section 6, PR 34): the block
+# step 17.1 packed for 21.5 rows at 209000 documents, 13.2 for 15.7 at
+# 60600; of it the scatter-add 10.9 for 13.5 and 7.3 for 9.9.
+# make_sweep_kernel packs once at a sweep's start and unpacks once at
+# its end; everywhere else n_dk is [D, K]. _NDK_PACKED_BACKENDS lists
+# the backends with such a reading; one without (cpu: tier-1 runs
+# there) keeps "rows", and so does K over 64, where G would be 1.
+_NDK_LANES = 128
+_NDK_PACKED_BACKENDS = ("tpu",)
+
+
+def _ndk_group(k_topics: int) -> int:
+    """The largest power of two of K-lane documents a row holds."""
+    fit = _NDK_LANES // k_topics if k_topics > 0 else 0
+    return 1 << (fit.bit_length() - 1) if fit else 0
+
+
+# lint: exempt[gates] -- no env or config layer to order: pin, else table
+def select_ndk_form(*, backend: str, k_topics: int,
+                    ndk_form: str | None = None) -> tuple[str, int]:
+    """The form of n_dk inside a sweep and its group size G, ("rows",
+    1) or ("packed", G >= 2), decided at trace time and nowhere else:
+    the tests' pin `ndk_form` if given, else "packed" where
+    `_NDK_PACKED_BACKENDS` names `backend` and two documents or more
+    fit a 128-lane row. Reads no environment and no config."""
+    group = _ndk_group(k_topics)
+    if ndk_form is not None:
+        if ndk_form not in ("rows", "packed"):
+            raise ValueError(
+                f"ndk_form must be rows|packed, got {ndk_form!r}")
+        if ndk_form == "packed" and group < 2:
+            raise ValueError(
+                f"ndk_form packed needs two documents a {_NDK_LANES}-lane "
+                f"row, K={k_topics} fits {group}")
+        return ("packed", group) if ndk_form == "packed" else ("rows", 1)
+    if backend in _NDK_PACKED_BACKENDS and group >= 2:
+        return "packed", group
+    return "rows", 1
+
+
+def ndk_layout(n_docs: int, k_topics: int, *, sampler_form: str) -> dict:
+    """What a sweep of `n_docs` documents carries its n_dk as, for the
+    fit's spans: `ndk_form`, `ndk_group` and `ndk_rows_packed`, the
+    rows of the table the block scan reads and adds to. The sparse
+    sampler keeps [D, K]."""
+    form, group = (select_ndk_form(backend=jax.default_backend(),
+                                   k_topics=k_topics)
+                   if sampler_form == "dense" else ("rows", 1))
+    return {"ndk_form": form, "ndk_group": group,
+            "ndk_rows_packed": -(-n_docs // group)}
+
+
+def pack_ndk(n_dk: jax.Array, group: int) -> jax.Array:
+    """[D, K] -> [ceil(D / group), 128]: document d's K counts in lanes
+    (d % group) * K onward of row d // group; the tail documents and
+    the lanes past group * K are zero."""
+    n_docs, k = n_dk.shape
+    n_rows = -(-n_docs // group)
+    rows = jnp.pad(n_dk, ((0, n_rows * group - n_docs), (0, 0)))
+    rows = rows.reshape(n_rows, group * k)
+    return jnp.pad(rows, ((0, 0), (0, _NDK_LANES - group * k)))
+
+
+def _pick_lanes(rows: jax.Array, slot: jax.Array, k_topics: int,
+                group: int) -> jax.Array:
+    """rows [B, 128] of pack_ndk's table, slot [B] in [0, group) ->
+    [B, K]: lanes slot * K onward of each row. Picked with the rows
+    turned over, [128, B]: the chip lays a [B, K] array out transposed
+    whatever is asked, so K-lane slices of the rows cost a transposing
+    copy each, and this way the 128 lanes are turned once, full, and
+    the slices and selects run along the tokens (18.0 ns a token the
+    block step for 26.2, PERF.md section 6, PR 34)."""
+    rows_t = rows.T
+    out = rows_t[:k_topics]
+    for j in range(1, group):
+        out = jnp.where((slot == j)[None, :],
+                        rows_t[j * k_topics:(j + 1) * k_topics], out)
+    return out.T
+
+
+def _lane_delta(slot: jax.Array, z_old: jax.Array, z_new: jax.Array,
+                k_topics: int) -> jax.Array:
+    """The block's update of pack_ndk's table, [B, 128] int32: +1 in
+    lane slot * K + z_new and -1 in lane slot * K + z_old of each
+    token's row. A sentinel z (== K, padding) gives a zero row: slot *
+    K + K is the next document's first lane."""
+    base = slot * k_topics
+    lanes = jnp.arange(_NDK_LANES, dtype=jnp.int32)[None, :]
+    lane_new = jnp.where(z_new < k_topics, base + z_new, -1)
+    lane_old = jnp.where(z_old < k_topics, base + z_old, -1)
+    return ((lanes == lane_new[:, None]).astype(jnp.int32)
+            - (lanes == lane_old[:, None]).astype(jnp.int32))
+
+
+def unpack_ndk(packed: jax.Array, n_docs: int, k_topics: int,
+               group: int) -> jax.Array:
+    """pack_ndk's reverse: [ceil(D / group), 128] -> [D, K]."""
+    rows = packed[:, :group * k_topics]
+    return rows.reshape(-1, k_topics)[:n_docs]
+
+
 # ---------------------------------------------------------------------------
 # Sampler-form gate (r11): dense O(K) block sampler vs the sparse
 # O(K_active) arm.
@@ -594,8 +705,39 @@ def make_sparse_block_step(*, alpha: float, eta: float, v_eta: float,
     return block_step
 
 
+def _squeeze_one_chain(kernel):
+    """`kernel` with a vmap rule of its own: an axis of one (the
+    engines' chain axis, every cell's) is taken off the arguments and
+    put back on the results, not vmapped over; a longer one is
+    `jax.vmap`'s as before. The chip lays the block's [B, K] arrays out
+    with the tokens along the lanes, and [1, B, K] ones K to a 128-lane
+    row: under a chain vmap over one chain the sampler's arithmetic and
+    the packed form's pick ran on rows 84% padding, and the packed
+    sweep read the rows form's 5.74 s (PERF.md section 6, PR 34)."""
+    from jax.custom_batching import custom_vmap
+
+    # A call of its own: the scopes opened inside keep their names (one
+    # opened under a vmap reads `vmap(onix...)`, which no reader books).
+    kernel = jax.jit(kernel)
+    wrapped = custom_vmap(kernel)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        if axis_size == 1:
+            outs = kernel(*(a[0] if b else a
+                            for a, b in zip(args, in_batched)))
+            outs = tuple(o[None] for o in outs)
+        else:
+            outs = jax.vmap(kernel, in_axes=[0 if b else None
+                                             for b in in_batched])(*args)
+        return outs, (True,) * len(outs)
+
+    return wrapped
+
+
 def make_sweep_kernel(*, alpha: float, eta: float, n_vocab: int,
                       k_topics: int, nwk_form: str | None = None,
+                      ndk_form: str | None = None,
                       sampler_form: str | None = None,
                       sparse_active: int = 0, sparse_mh: int = 2,
                       sampler: str | None = None):
@@ -609,19 +751,37 @@ def make_sweep_kernel(*, alpha: float, eta: float, n_vocab: int,
     proposal tables from the sweep-start counts on every call (table
     freshness is a per-sweep property, independent of how many sweeps
     a dispatch fuses). `nwk_form` and `sampler` are make_block_step's
-    test pins, handed on to the dense form."""
+    test pins, handed on to the dense form; `ndk_form` is the tests'
+    pin of `select_ndk_form`. Where that resolves to "packed" the
+    dense form packs n_dk at the sweep's start, scans the blocks over
+    the packed table and unpacks at its end: n_dk is [D, K] on both
+    sides of the kernel whatever the form."""
     form = _resolved_sampler_form(sampler_form, k_topics=k_topics)
     if form == "dense":
+        _, group = select_ndk_form(backend=jax.default_backend(),
+                                   k_topics=k_topics, ndk_form=ndk_form)
         block_step = make_block_step(alpha=alpha, eta=eta,
                                      n_vocab=n_vocab, k_topics=k_topics,
-                                     nwk_form=nwk_form, sampler=sampler)
+                                     nwk_form=nwk_form, sampler=sampler,
+                                     ndk_group=group)
 
         def kernel(z, n_dk, n_wk, n_k, key, docs, words, mask):
-            (n_dk, n_wk, n_k, key), z = jax.lax.scan(
-                block_step, (n_dk, n_wk, n_k, key),
-                (docs, words, mask, z))
+            n_docs = n_dk.shape[0]
+            if group > 1:
+                with device_scope("onix.sweep.pack"):
+                    n_dk = pack_ndk(n_dk, group)
+            # onix.sweep.blocks names the loop's own work (a block's
+            # docs, words, mask and z sliced out, its new z written
+            # back); the block step's ops keep their inner scopes.
+            with device_scope("onix.sweep.blocks"):
+                (n_dk, n_wk, n_k, key), z = jax.lax.scan(
+                    block_step, (n_dk, n_wk, n_k, key),
+                    (docs, words, mask, z))
+            if group > 1:
+                with device_scope("onix.sweep.pack"):
+                    n_dk = unpack_ndk(n_dk, n_docs, k_topics, group)
             return z, n_dk, n_wk, n_k, key
-        return kernel
+        return _squeeze_one_chain(kernel) if group > 1 else kernel
 
     a = resolve_sparse_active(k_topics, sparse_active)
     v_eta = n_vocab * eta
@@ -640,7 +800,7 @@ def make_sweep_kernel(*, alpha: float, eta: float, n_vocab: int,
 
 def make_block_step(*, alpha: float, eta: float, n_vocab: int,
                     k_topics: int, nwk_form: str | None = None,
-                    sampler: str | None = None):
+                    sampler: str | None = None, ndk_group: int = 1):
     """The collapsed-Gibbs block sampler shared by the single-device and
     sharded engines — one definition so the documented dp=1 equivalence
     can never silently diverge.
@@ -657,7 +817,17 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
     None keeps the per-backend pick (gumbel on accelerators, race on
     CPU). Test-only knob: it lets CPU tier-1 assert the TPU sampler's
     math bit-for-bit.
+
+    `ndk_group`: the documents a row of the carry's n_dk holds. 1, the
+    bare call's, is [D, K]; G > 1 is `pack_ndk`'s [ceil(D/G), 128],
+    which `make_sweep_kernel` hands in where `select_ndk_form` says
+    so. The [B, K] counts read, the draw and the other tables are the
+    same to the bit.
     """
+    if not 1 <= ndk_group <= max(1, _NDK_LANES // k_topics):
+        raise ValueError(
+            f"ndk_group {ndk_group}: K={k_topics} fits "
+            f"{_NDK_LANES // k_topics} documents a {_NDK_LANES}-lane row")
     v_eta = n_vocab * eta
     # Sampler form is picked once at trace time; it is a platform
     # property, not runtime state, so the traced program is static.
@@ -688,7 +858,14 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
             oh_old = _one_hot(z_old, k_topics)      # zero row for padding
             ohf = oh_old.astype(jnp.float32)
             # Counts excluding each token's own current assignment.
-            ndk = n_dk[d].astype(jnp.float32) - ohf
+            if ndk_group > 1:
+                # The document's row of ndk_group documents, then its
+                # own K lanes of it.
+                row, slot = d // ndk_group, d % ndk_group
+                ndk_d = _pick_lanes(n_dk[row], slot, k_topics, ndk_group)
+            else:
+                ndk_d = n_dk[d]
+            ndk = ndk_d.astype(jnp.float32) - ohf
             nwk = n_wk[w].astype(jnp.float32) - ohf
             nk = n_k.astype(jnp.float32)[None, :] - ohf
         # Categorical sampling — two statistically identical forms,
@@ -719,13 +896,21 @@ def make_block_step(*, alpha: float, eta: float, n_vocab: int,
                                    axis=-1).astype(jnp.int32)
             z_new = jnp.where(m > 0, z_new, z_old)  # padding keeps sentinel
             # Dense one-hot delta rows, NOT per-element scalar scatters:
-            # XLA's TPU scatter vectorizes the K lane dimension of row
-            # updates, so the dense [B,K] delta runs ~2x faster than the
-            # "only 2 of K entries change" rank-1 formulation (measured
-            # 35M vs 18M tokens/s at K=20).
+            # XLA's TPU scatter adds a whole row an index, so the dense
+            # delta costs one add a token where the "only 2 of K entries
+            # change" rank-1 formulation costs two (on the chip, into
+            # flow-fit's n_dk: 12.7 ns a K-lane row, 9.3 ns a scalar
+            # into the flat table and so 18.6 a moved token; PERF.md
+            # section 6, PR 32).
             delta = _one_hot(z_new, k_topics) - oh_old  # int32-exact
         with device_scope("onix.sweep.scatter"):
-            n_dk = n_dk.at[d].add(delta)
+            if ndk_group > 1:
+                # The same two entries, in the document's lanes of its
+                # 128-lane row.
+                n_dk = n_dk.at[row].add(
+                    _lane_delta(slot, z_old, z_new, k_topics))
+            else:
+                n_dk = n_dk.at[d].add(delta)
         with device_scope("onix.sweep.nwk"):
             if form == "matmul":
                 oh_w = jax.nn.one_hot(w, n_wk.shape[0], dtype=jnp.bfloat16)

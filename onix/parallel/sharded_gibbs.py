@@ -1174,7 +1174,10 @@ class ShardedGibbsLDA:
                 "fit.supersteps", sweeps=n_sweeps - start,
                 merge_form=self.merge_form,
                 merge_bytes_per_sweep=(merge_bytes
-                                       * max(1, int(cfg.sync_splits)))):
+                                       * max(1, int(cfg.sync_splits))),
+                # n_dk as a chip's block scan carries it.
+                **lda_gibbs.ndk_layout(sc.n_docs_local, cfg.n_topics,
+                                       sampler_form=self.sampler_form)):
             state, ll_history = run_fit_segments(
                 state, start, segments,
                 superstep_fn=lambda st, s0, n, init: self._superstep(

@@ -385,4 +385,7 @@ def test_fit_spans_say_how_the_shards_balance(eight_devices, corpus):
                          - corpus.n_tokens)}
     assert spans["fit.supersteps"] == {
         "sweeps": 2, "merge_form": "sync",
-        "merge_bytes_per_sweep": 2 * (corpus.n_vocab * K + K) * 4}
+        "merge_bytes_per_sweep": 2 * (corpus.n_vocab * K + K) * 4,
+        # n_dk as a chip's block scan carries it: [Dl, K] on the CPU.
+        "ndk_form": "rows", "ndk_group": 1,
+        "ndk_rows_packed": fit["sharded_corpus"].n_docs_local}

@@ -271,7 +271,9 @@ def test_fit_spans_count_the_work_at_their_boundary():
     # One chain's n_wk [V, K] and n_k [K], int32: what a merge moves.
     assert spans["fit.supersteps"][0].attrs == {
         "sweeps": 3, "merge_form": "sync",
-        "merge_bytes_per_sweep": (corpus.n_vocab * 5 + 5) * 4}
+        "merge_bytes_per_sweep": (corpus.n_vocab * 5 + 5) * 4,
+        "ndk_form": "rows", "ndk_group": 1,
+        "ndk_rows_packed": corpus.n_docs}
     assert spans["fit.init_state"][0].attrs["resumed"] is False
     assert spans["fit.init_state"][0].attrs["bytes"] > 0
     assert spans["fit.device_corpus"][0].attrs["bytes"] > 0
