@@ -16,8 +16,10 @@ event counts are cut):
   phase 3  scale path  `run_scale` (BASELINE config 4's code, 1/100 length)
 
 Every number it prints is a SMOKE OBSERVATION, NOT A BENCHMARK: one cold
-run, compile included. Not driven here: `onix stream`,
-`pipelines.daily`, `pipelines.fleet`, the host fabric.
+run, compile included. Not driven here: `onix stream` (its resident
+superstep runs on the chip in the benchmark's `flow-stream-catchup`
+cell since PR 35), `pipelines.daily`, `pipelines.fleet`, the host
+fabric.
 
 Exit 0 only if every phase passed; then the last stdout line is
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}

@@ -59,9 +59,7 @@ def minibatch_arrays(doc_ids: np.ndarray, word_ids: np.ndarray,
                      pad_docs: int | None = None,
                      weights: np.ndarray | None = None):
     """Host half of make_minibatch: densify + pad, returning plain
-    NumPy arrays (doc_ids, word_ids, mask, doc_map, n_docs). The
-    streaming superstep stacks S of these before ONE device transfer,
-    so the per-batch jnp conversion must be separable."""
+    NumPy arrays (doc_ids, word_ids, mask, doc_map, n_docs)."""
     uniq, local = np.unique(np.asarray(doc_ids), return_inverse=True)
     t = len(local)
     pad_to = t if pad_to is None else pad_to
@@ -117,10 +115,52 @@ from onix.models.compaction import (compact_front, ladder_index,  # noqa: E402
                                     pow2_ladder as _active_ladder)
 
 
+# With the words' table handed over, a pass over the tokens works
+# through them in runs of this many: a float32 [T, K] array is laid out
+# on the TPU with K padded to 128 lanes (4.3 GB at T = 2^23, K = 20, and
+# an E-step pass keeps two or three), a run's rows are 64 MB. The Gibbs
+# kernel's block: its scatter-add of 2^17 K-lane rows is what PERF.md's
+# unit costs were read at.
+_TOKEN_RUN = 1 << 17
+
+
+def _token_runs(n: int) -> int:
+    """How many runs a pass over `n` tokens is made in: one (the whole
+    axis at once) unless `n` is a multiple of `_TOKEN_RUN` longer than
+    one run."""
+    return 1 if n <= _TOKEN_RUN or n % _TOKEN_RUN else n // _TOKEN_RUN
+
+
+def _in_token_runs(fn, init, arrays: tuple, n_runs=None):
+    """`fn(carry, *arrays) -> (carry, y)` over the token axis, the whole
+    of it at once or a run at a time (`_token_runs`; the y's joined
+    again). `n_runs` (traced) stops after that many runs, for a pass
+    whose tokens of weight sit in front; such a pass has no y."""
+    n, runs = arrays[0].shape[0], _token_runs(arrays[0].shape[0])
+    if runs == 1:
+        return fn(init, *arrays)
+    arrays = tuple(a.reshape(runs, _TOKEN_RUN) for a in arrays)
+    if n_runs is not None:
+        return jax.lax.fori_loop(
+            0, n_runs, lambda i, c: fn(c, *(a[i] for a in arrays))[0],
+            init), None
+    carry, ys = jax.lax.scan(lambda c, xs: fn(c, *xs), init, arrays)
+    return carry, jax.tree.map(lambda y: y.reshape(n, *y.shape[2:]), ys)
+
+
 def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
                 local_iters: int, meanchange_tol: float,
-                warm_iters: int, estep_form: str = "svi") -> jax.Array:
-    """The local E-step over one minibatch's tokens.
+                warm_iters: int, estep_form: str = "svi",
+                with_stats: bool = False, elog_beta=None):
+    """The local E-step over one minibatch's tokens. Returns gamma; with
+    `with_stats` (static) also what ran, as int32 scalars: the passes
+    over the full padded block, the passes of the extended loop, and the
+    tokens of the compacted active set that loop worked on.
+
+    With `elog_beta` ([V, K]) given, `elog_beta_t` holds the tokens'
+    WORD IDS in place of their rows: every pass gathers the rows it
+    needs a run of tokens at a time (`_TOKEN_RUN`) and no [T, K] array
+    is ever whole; the sums are the same sums in another order.
 
     `estep_form` picks the update family (static):
 
@@ -142,7 +182,10 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
       padded [T,K] block iterates until the slowest doc converges
       (kept bit-identical: existing streaming checkpoints and the
       batch SVI engine ride this path unchanged).
-    * ``warm_iters > 0`` — the r10 warm/cold split. Warm-started
+    * ``warm_iters > 0`` — the r10 warm/cold split (with `elog_beta`
+      given and the tokens a multiple of `_TOKEN_RUN`, the extended
+      loop stops each pass after the runs that hold the active tokens,
+      in place of the pow2 bucket). Warm-started
       returning docs (the stream's common case) converge within a
       short fixed-trip pass over the full block; the unconverged
       remainder is then COMPACTED — its docs' tokens gathered to the
@@ -155,7 +198,7 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
       O(T · K) — the r6 loop charged every token until the SLOWEST
       doc converged.
     """
-    def e_step(gamma, d_ids, eb_t, m):
+    def e_step(gamma, d_ids, eb_t, m, n_runs=None):
         if estep_form == "scvb0":
             # Collapsed zeroth-order responsibilities: gamma holds
             # alpha + N_theta (> 0 always), eb_t holds log(phi_hat)
@@ -164,14 +207,29 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             elog_theta = jnp.log(gamma)                  # [Bd,K]
         else:
             elog_theta = _e_log_dirichlet(gamma, axis=1)  # [Bd,K]
-        logp = elog_theta[d_ids] + eb_t                  # [T,K]
-        phi = jax.nn.softmax(logp, axis=-1) * m[:, None]
-        return alpha + jnp.zeros_like(gamma).at[d_ids].add(phi)
+
+        def add(acc, d, e, mm):
+            logp = elog_theta[d] + (e if elog_beta is None
+                                    else elog_beta[e])   # [T,K]
+            phi = jax.nn.softmax(logp, axis=-1) * mm[:, None]
+            return acc.at[d].add(phi), None
+
+        zero = jnp.zeros_like(gamma)
+        if elog_beta is None:
+            return alpha + add(zero, d_ids, eb_t, m)[0]
+        return alpha + _in_token_runs(add, zero, (d_ids, eb_t, m),
+                                      n_runs)[0]
+
+    def out(gamma, full, ext=0, n_act=0):
+        if not with_stats:
+            return gamma
+        return gamma, (jnp.int32(full), jnp.int32(ext), jnp.int32(n_act))
 
     if meanchange_tol <= 0.0:
-        return jax.lax.fori_loop(
+        return out(jax.lax.fori_loop(
             0, local_iters,
-            lambda _, g: e_step(g, doc_ids, elog_beta_t, mask), gamma0)
+            lambda _, g: e_step(g, doc_ids, elog_beta_t, mask), gamma0),
+            local_iters)
 
     if warm_iters <= 0:
         def body(carry):
@@ -190,9 +248,9 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             _, delta, i = carry
             return (i < local_iters) & (delta > meanchange_tol)
 
-        gamma, _, _ = jax.lax.while_loop(
+        gamma, _, i = jax.lax.while_loop(
             cond, body, (gamma0, jnp.float32(jnp.inf), jnp.int32(0)))
-        return gamma
+        return out(gamma, i)
 
     t = doc_ids.shape[0]
     warm = min(int(warm_iters), int(local_iters))
@@ -207,25 +265,33 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         0, warm, warm_body,
         (gamma0, jnp.full((gamma0.shape[0],), jnp.inf, jnp.float32)))
     if rem_iters <= 0:
-        return gamma
+        return out(gamma, warm)
 
     active_d = delta_d > meanchange_tol              # [Bd]
     act_tok = active_d[doc_ids] & (mask > 0.0)       # [T]
     n_act = act_tok.sum()
     # Stable compaction: active docs' tokens to the front, order kept.
-    perm = compact_front(act_tok)
-    c_doc = doc_ids[perm]
-    c_eb = elog_beta_t[perm]
-    c_mask = jnp.where(act_tok, mask, 0.0)[perm]
+    if elog_beta is None:
+        perm = compact_front(act_tok)
+        c_doc = doc_ids[perm]
+        c_eb = elog_beta_t[perm]
+        c_mask = jnp.where(act_tok, mask, 0.0)[perm]
+    else:
+        # The same order, the three 1-D columns carried through the one
+        # sort (a gather of a scalar an index costs the chip more than
+        # the sort does).
+        _, c_doc, c_eb, c_mask = jax.lax.sort(
+            (~act_tok, doc_ids, elog_beta_t, jnp.where(act_tok, mask, 0.0)),
+            num_keys=1, is_stable=True)
 
-    def make_branch(size):
+    def make_branch(size, n_runs=None):
         d_ids = jax.lax.slice_in_dim(c_doc, 0, size)
         eb_t = jax.lax.slice_in_dim(c_eb, 0, size)
         m = jax.lax.slice_in_dim(c_mask, 0, size)
 
         def body(carry):
             g, _, i = carry
-            g2 = e_step(g, d_ids, eb_t, m)
+            g2 = e_step(g, d_ids, eb_t, m, n_runs)
             # Converged docs stay frozen; active docs' updates are
             # exact (every token of an active doc sits inside the
             # compacted slice — activity is per-doc, and the slice is
@@ -240,21 +306,27 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             return (i < rem_iters) & (delta > meanchange_tol)
 
         def branch(g):
-            g2, _, _ = jax.lax.while_loop(
+            g2, _, i = jax.lax.while_loop(
                 cond, body,
                 # n_act == 0 skips the extended phase outright (the
                 # init delta fails cond on entry).
                 (g, jnp.where(n_act > 0, jnp.float32(jnp.inf),
                               jnp.float32(0.0)), jnp.int32(0)))
-            return g2
+            return g2, i
         return branch
 
+    if elog_beta is not None and _token_runs(t) > 1:
+        # A pass made in runs needs no ladder: the one loop stops after
+        # the runs that hold the active tokens.
+        gamma, ext = make_branch(t, -(-n_act // _TOKEN_RUN))(gamma)
+        return out(gamma, warm, ext, n_act)
     sizes = _active_ladder(t)
     # Smallest rung that still holds every active token (compaction
     # preserves order, so the first n_act compacted slots are exactly
     # the active tokens).
     idx = ladder_index(n_act, sizes)
-    return jax.lax.switch(idx, [make_branch(s) for s in sizes], gamma)
+    gamma, ext = jax.lax.switch(idx, [make_branch(s) for s in sizes], gamma)
+    return out(gamma, warm, ext, n_act)
 
 
 def svi_step(
@@ -339,103 +411,85 @@ def phi_estimate(state: SVIState) -> jax.Array:
     return state.lam / state.lam.sum(axis=0, keepdims=True)
 
 
-class SuperBatch(NamedTuple):
-    """S stacked minibatches sharing one static (T, Bd) shape — the
-    unit the streaming superstep consumes. `doc_map` carries indices
-    into the superstep's UNION gamma store (not global doc ids): the
-    host maps each batch's global doc ids onto the sorted union of all
-    docs the S batches touch, so warm starts chain batch-to-batch on
-    device without any host round-trip. -1 marks padding doc rows."""
-    doc_ids: jax.Array    # int32 [S, T] local-dense doc index per token
-    word_ids: jax.Array   # int32 [S, T]
-    mask: jax.Array       # float32 [S, T] token multiplicity; 0 padding
-    doc_map: jax.Array    # int32 [S, Bd] local doc -> union row (-1 pad)
-    n_docs: int           # Bd (padded) — static
-
-
-def svi_superstep(
+def svi_store_step(
     state: SVIState,
-    sb: SuperBatch,
-    gamma_union: jax.Array,   # [U_pad, K] union warm-start/store rows;
-    #                           the LAST row is a never-written dummy
-    #                           that padding doc rows gather (alpha+1)
-    corpus_docs: jax.Array,   # float32 [S] running-D per batch
+    store: jax.Array,         # float32 [cap, K]: EVERY document's gamma
+    doc_ids: jax.Array,       # int32 [T] rows of `store`, one per token
+    word_ids: jax.Array,      # int32 [T]
+    mask: jax.Array,          # float32 [T] token multiplicity; 0 padding
+    corpus_docs: jax.Array,   # float32 []: running D of this batch
     *,
     alpha: float,
     eta: float,
     tau0: float,
     kappa: float,
     local_iters: int,
-    batch_docs: int,
     meanchange_tol: float = 0.0,
     warm_iters: int = 0,
     estep_form: str = "svi",
-) -> tuple[SVIState, jax.Array, jax.Array]:
-    """Chain S minibatch updates (E-step + natural-gradient λ-step +
-    incremental scoring) inside ONE jitted program — the streaming
-    analog of the r7 Gibbs fit supersteps. Each scan step is the exact
-    `svi_step` update followed by the exact per-batch scoring math the
-    per-batch path runs (theta rows from the batch's updated gamma,
-    phi from the updated lambda, `score_events` over the padded token
-    columns), with the union gamma store carrying warm starts across
-    the S batches. Per dispatch the host fetches ONE scores block
-    [S, T] plus the updated union rows — where the per-batch loop paid
-    ~3 dispatch syncs per batch, the superstep pays ~1 per S batches
-    (the price of a dispatch on the chip is not measured).
+):
+    """One minibatch's update against the WHOLE per-document store: the
+    exact `svi_step` (E-step from each touched document's last gamma,
+    natural-gradient lambda step) followed by the incremental scores of
+    the batch's own tokens under the updated model - the order the
+    stream states. `doc_ids` index `store` directly, so the store stays
+    where it is from batch to batch (the streaming scorer keeps it on
+    the device and donates it); there is no per-batch document re-index
+    and no union of a group's documents to build.
 
-    Returns (new_state, updated gamma_union, scores [S, T])."""
+    A document the batch does not touch sits in the E-step as a padding
+    row does in `svi_step` (it holds `alpha` and no token reaches it)
+    and keeps its stored gamma; a row never written holds whatever the
+    caller put there, `alpha + 1` for a cold start. The lambda step
+    scales by the documents the batch touches (a token of weight 0
+    touches none). Padding tokens may point at any row.
+
+    Returns (state, store, touched bool [cap], scores float32 [T],
+    stats), `stats` as `_run_e_step(with_stats=True)` gives it."""
     from onix.models.scoring import score_events
+    from onix.utils.obs import device_scope
 
-    k = state.lam.shape[1]
-    dummy = gamma_union.shape[0] - 1
+    lam = state.lam
+    scvb0 = estep_form == "scvb0"
 
-    def step(carry, xs):
-        lam, stp, store = carry
-        d_ids, w_ids, m, dmu, cdocs = xs
-        real = dmu >= 0
-        g0 = store[jnp.where(real, dmu, dummy)]
-        if estep_form == "scvb0":
-            elog_beta = jnp.log(lam / lam.sum(axis=0, keepdims=True))
-        else:
-            elog_beta = _e_log_dirichlet(lam, axis=0)
-        elog_beta_t = elog_beta[w_ids]
-        gamma = _run_e_step(g0, elog_beta_t, d_ids, m, alpha=alpha,
-                            local_iters=local_iters,
-                            meanchange_tol=meanchange_tol,
-                            warm_iters=warm_iters,
-                            estep_form=estep_form)
-        if estep_form == "scvb0":
-            elog_theta = jnp.log(gamma)
-        else:
-            elog_theta = _e_log_dirichlet(gamma, axis=1)
-        phi = jax.nn.softmax(elog_theta[d_ids] + elog_beta_t, axis=-1)
-        phi = phi * m[:, None]
-        n_real = real.sum().astype(jnp.float32)
-        scale = cdocs / jnp.maximum(n_real, 1.0)
-        lam_hat = eta + scale * jnp.zeros_like(lam).at[w_ids].add(phi)
-        rho = (tau0 + stp.astype(jnp.float32)) ** (-kappa)
-        lam2 = (1.0 - rho) * lam + rho * lam_hat
-        # Padding doc rows scatter nowhere: mode="drop" only drops
-        # indices OUT OF BOUNDS (negative indices WRAP — -1 would
-        # overwrite the dummy row), so padding maps past the store's
-        # end. Real rows land so the NEXT batch's warm start sees
-        # them.
-        store2 = store.at[jnp.where(real, dmu, store.shape[0])].set(
-            gamma, mode="drop")
-        # Incremental scoring under the updated model — the same
-        # theta/phi construction as the per-batch path (padding doc
-        # rows at the uniform prior).
-        theta = jnp.where(real[:, None],
-                          gamma / gamma.sum(axis=1, keepdims=True),
-                          1.0 / k)
-        phi_wk = lam2 / lam2.sum(axis=0, keepdims=True)
-        scores = score_events(theta, phi_wk, d_ids, w_ids)
-        return (lam2, stp + 1, store2), scores
+    def elog_rows(x, axis):
+        if scvb0:
+            return jnp.log(x / x.sum(axis=0, keepdims=True) if axis == 0
+                           else x)
+        return _e_log_dirichlet(x, axis=axis)
 
-    (lam, stp, store), scores = jax.lax.scan(
-        step, (state.lam, state.step, gamma_union),
-        (sb.doc_ids, sb.word_ids, sb.mask, sb.doc_map, corpus_docs))
-    return SVIState(lam=lam, step=stp), store, scores
+    with device_scope("onix.svi.estep"):
+        touched = jnp.zeros((store.shape[0],), jnp.float32).at[
+            doc_ids].add(mask) > 0.0
+        elog_beta = elog_rows(lam, 0)
+        gamma, stats = _run_e_step(
+            jnp.where(touched[:, None], store, alpha), word_ids,
+            doc_ids, mask, alpha=alpha, local_iters=local_iters,
+            meanchange_tol=meanchange_tol, warm_iters=warm_iters,
+            estep_form=estep_form, with_stats=True, elog_beta=elog_beta)
+    with device_scope("onix.svi.lambda"):
+        elog_theta = elog_rows(gamma, 1)
+
+        def add(acc, d, w, m):
+            # Final responsibilities under the converged gamma.
+            phi = jax.nn.softmax(elog_theta[d] + elog_beta[w], axis=-1)
+            return acc.at[w].add(phi * m[:, None]), None
+
+        sstats, _ = _in_token_runs(add, jnp.zeros_like(lam),
+                                   (doc_ids, word_ids, mask))
+        n_real = touched.sum().astype(jnp.float32)
+        scale = corpus_docs / jnp.maximum(n_real, 1.0)
+        rho = (tau0 + state.step.astype(jnp.float32)) ** (-kappa)
+        lam = (1.0 - rho) * lam + rho * (eta + scale * sstats)
+        store = jnp.where(touched[:, None], gamma, store)
+    with device_scope("onix.svi.score"):
+        theta = store / store.sum(axis=1, keepdims=True)
+        phi_wk = lam / lam.sum(axis=0, keepdims=True)
+        _, scores = _in_token_runs(
+            lambda _, d, w: (None, score_events(theta, phi_wk, d, w)),
+            None, (doc_ids, word_ids))
+    return (SVIState(lam=lam, step=state.step + 1), store, touched, scores,
+            stats)
 
 
 class SVILda:
@@ -459,14 +513,6 @@ class SVILda:
             meanchange_tol=config.svi_meanchange_tol,
             warm_iters=warm, estep_form=estep,
         ), static_argnames=("batch_docs",))
-        self._superstep = jax.jit(functools.partial(
-            svi_superstep,
-            alpha=config.alpha, eta=config.eta,
-            tau0=config.svi_tau0, kappa=config.svi_kappa,
-            local_iters=config.svi_local_iters,
-            meanchange_tol=config.svi_meanchange_tol,
-            warm_iters=warm, estep_form=estep,
-        ), static_argnames=("batch_docs",))
 
     def init(self) -> SVIState:
         return init_state(self.n_vocab, self.config.n_topics, self.config.seed)
@@ -479,13 +525,3 @@ class SVILda:
         E-step (svi_step docstring)."""
         d = float(self.corpus_docs if corpus_docs is None else corpus_docs)
         return self._step(state, batch, d, gamma0, batch_docs=batch.n_docs)
-
-    def update_superstep(self, state: SVIState, sb: SuperBatch,
-                         gamma_union, corpus_docs):
-        """S chained SVI updates + incremental scoring in one dispatch
-        (svi_superstep docstring). `gamma_union` is the [U_pad, K]
-        union warm-start store (last row a dummy for padding docs);
-        `corpus_docs` the per-batch running-D vector [S]."""
-        return self._superstep(state, sb, jnp.asarray(gamma_union),
-                               jnp.asarray(corpus_docs, jnp.float32),
-                               batch_docs=sb.n_docs)

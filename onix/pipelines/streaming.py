@@ -33,75 +33,86 @@ Streaming-specific design (vs the batch path in pipelines/run.py):
   to powers of two — a stream of irregular batches reuses a handful of
   compiled programs (asserted in tests).
 - **Device-resident word creation (default).** Once the edges freeze,
-  each columnar minibatch's binning → packed-key build → splitmix64
-  bucketing runs as ONE fused device program (device_words.py
-  `*_stream_buckets`): the int64 word key is packed in uint32 limbs and
-  hashed with 32-bit limb arithmetic, so buckets are IDENTICAL to the
-  host `_bucket_of_keys` (given identical bin indices; f32-vs-f64 edge
-  comparisons can differ ~1e-7/event — device_words docstring). The
-  per-unique string features (dns/proxy) stay host-side per refresh.
-  The tables are rebuilt from the frozen edges per batch only where
-  they depend on the batch (caller proto order, the batch's unique
-  string values) — O(uniques), not O(events).
-- **Deduped weighted E-step.** The minibatch fed to SVI is the UNIQUE
-  (doc, bucket) pairs with their counts as token weights
-  (`make_minibatch(weights=...)`): every E-step/λ-step contribution
-  multiplies by the weight, so the math is exactly the repeated-token
-  update at a fraction of the [T,K] passes (telemetry is Zipf — unique
-  pairs run 4-5x below the token count). Scoring broadcasts the
-  unique-pair scores back through the inverse index, so per-event
-  scores and alerts are unchanged in meaning.
+  each columnar minibatch's binning -> packed-key build -> splitmix64
+  bucketing runs on the device (device_words.py `*_stream_buckets`):
+  the int64 word key is packed in uint32 limbs and hashed with 32-bit
+  limb arithmetic, so buckets are IDENTICAL to the host
+  `_bucket_of_keys` (given identical bin indices; f32-vs-f64 edge
+  comparisons can differ ~1e-7/event - device_words docstring).
+- **The resident superstep (flow, `pipeline.stream_superstep` S > 1).**
+  `process_many` runs every group of S eligible minibatches as ONE
+  program, `stream_svi_step`: per batch in a `lax.scan` the buckets,
+  the document look-up in the device copy of the sorted document table
+  (`device_words._lookup_sorted`), the E-step from the documents' rows
+  of the WHOLE gamma store (`lda_svi.svi_store_step`: Hoffman's update,
+  the warm/cold compacted split of `_run_e_step`), the natural-gradient
+  lambda step, the incremental scores under the updated model, the
+  pair-min of an event's two tokens, the tolerance filter and the exact
+  bottom `max_results` (`scoring._scan_bottom_k`). The store, the
+  last-seen stamps and the table live on the device from superstep to
+  superstep (the store is donated and updated in place); the host
+  stages the raw columns one superstep ahead (`jax.device_put` per
+  column, as `device_words._put`),
+  fetches the winners of each batch and nothing else, and keeps what is
+  per unique address or rare: growth of the document table (a probe of
+  the group staged ahead, `stream_docs_probe`, says which of its tokens
+  carry an unseen address, and the host inserts those and nothing else;
+  a group whose turn comes unprobed is looked up against the host's own
+  table), eviction, alert rows, checkpoints. `self._gamma` and `self._last_seen` are filled from the
+  device at checkpoints, at eviction and when a batch takes the host
+  path. Tokens run undeduped, each with weight 1: by `make_minibatch`'s
+  contract the same update as the deduped weighted pairs.
+  `BatchResult.scores` is the device's per-event array, fetched when it
+  is read.
+- **The per-batch host path.** `process`, S <= 1, and every batch the
+  resident path declines (the first batch while the edges fit,
+  string/IPv6 doc keys, a frame the columnar converter rejects, a
+  non-power-of-two bucket count, dns and proxy, a loaded feedback noise
+  filter, ONIX_HOST_WORDS=1) go through `_process_one`: device or host
+  words, document ids and the deduped weighted (doc, bucket) pairs on
+  the host (`make_minibatch(weights=...)`), `svi_step`, the scores of
+  the unique pairs broadcast back through the inverse index.
 - **Warm/cold compacted E-step (r10).** The local E-step runs a short
-  fixed-trip warm pass over the full padded block (returning docs —
-  the stream's common case — converge inside it thanks to the gamma
+  fixed-trip warm pass over the full padded block (returning docs -
+  the stream's common case - converge inside it thanks to the gamma
   warm start), then COMPACTS the unconverged remainder's tokens into
   the smallest pow2 bucket that fits and runs the extended
-  per-document while_loop only there (lda_svi._run_e_step): extended
-  iterations stop charging every token for the slowest doc.
-- **Minibatch supersteps (r10).** `process_many` with
-  pipeline.stream_superstep = S chains S batches' E-step +
-  natural-gradient λ-step + incremental scoring inside ONE jitted
-  program (lda_svi.svi_superstep), warm starts flowing batch-to-batch
-  through a device-resident union gamma store and the scores block
-  fetched once per superstep — ~1 dispatch sync per S batches where
-  the per-batch path pays ~2 per batch (plus words), the exact
-  dispatch-amortization move of the r7 Gibbs fit supersteps.
+  per-document while_loop only there (lda_svi._run_e_step).
 - **Depth-k host pipeline (r10).** ColumnPrefetcher keeps up to k
-  future batches' file decode + frame→columns conversion in flight on
+  future batches' file decode + frame->columns conversion in flight on
   worker threads or a process pool (measured auto-pick; bounded,
-  in-order, backpressured), so the host slice of the batch wall (~30%
-  on a CPU host) rides under the device step.
-- **Capped shape lattice (r10).** `_pick_pad` bounds the compiled
-  (pad_to, pad_docs) set: past `pipeline.stream_max_shapes`,
+  in-order, backpressured).
+- **Capped shape lattice (r10).** `_pick_pad` bounds the host path's
+  compiled (pad_to, pad_docs) set: past `pipeline.stream_max_shapes`,
   adversarial batch-size streams re-pad into covering shapes instead
   of silently recompiling per batch; compiles and re-pads are counted
   (shape_stats + stream.shape_* obs counters).
-- **Escape hatch.** ONIX_HOST_WORDS=1 pins the host reference path
-  (word builders + host hash + undeduped E-step) — the cross-check arm
-  measurements compare against. The host path also catches everything
-  the device path declines: the first batch (edges still fitting),
-  string/IPv6 doc keys, non-power-of-two bucket counts, and frames the
-  columnar converter rejects.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 import os
 import pathlib
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 
 from onix.config import OnixConfig
-from onix.models.lda_svi import SVILda, SVIState, make_minibatch, phi_estimate
+from onix.models import scoring
+from onix.models.lda_svi import (SVILda, SVIState, make_minibatch,
+                                 phi_estimate, svi_store_step)
 from onix.models.scoring import score_all
+from onix.pipelines import device_words as dw
 from onix.pipelines.words import WORD_FNS
-from onix.utils import resilience
-from onix.utils.obs import counters
+from onix.utils import resilience, telemetry
+from onix.utils.obs import counters, device_scope
 
 
 def _next_pow2(n: int, floor: int = 256) -> int:
@@ -139,6 +150,128 @@ def _datatype_salt(datatype: str) -> int:
     return int.from_bytes(
         hashlib.blake2b(datatype.encode(), digest_size=8).digest(),
         "little")
+
+
+# ---------------------------------------------------------------------------
+# The resident superstep's two programs (module docstring). Neither name
+# holds `superstep` or `stream_scan`, which the benchmark's readers of
+# the fit and scan programs match on.
+# ---------------------------------------------------------------------------
+
+# Addresses are joined to the document table in runs of this many, the
+# day scan's own chunk: the join's sorts then have the sizes the scans
+# compile and run (a 2.3 M-key sort compiles in 33-43 s: PERF.md).
+_DOCS_RUN = 1 << 21
+# Selection chunk of `_scan_bottom_k`, the day scan's default.
+_SELECT_CHUNK = 1 << 21
+# The raw flow columns a group is staged as, each [S, E], and their
+# device types.
+_FLOW_COLUMNS = (("sip_u32", np.uint32), ("dip_u32", np.uint32),
+                 ("sport", np.int32), ("dport", np.int32),
+                 ("proto_id", np.int32), ("hour", np.float32),
+                 ("ibyt", np.float32), ("ipkt", np.float32))
+# Rows of the per-batch protocol remap the program is handed (the
+# caller's protocol order may differ batch to batch; a batch naming more
+# protocols than this takes the host path).
+_PROTO_ROWS = 32
+
+
+def _stream_doc_ids(doc_keys, doc_ids, addrs, fill: int):
+    """Store row of every address: `device_words._lookup_sorted` against
+    the device copy of the sorted document table (padded with the
+    largest key and `fill`), `fill` for an address it lacks."""
+    n = addrs.shape[0]
+    if n <= _DOCS_RUN or n % _DOCS_RUN:
+        return dw._lookup_sorted(doc_keys, doc_ids, addrs, fill)
+    return jax.lax.map(
+        lambda run: dw._lookup_sorted(doc_keys, doc_ids, run, fill),
+        addrs.reshape(n // _DOCS_RUN, _DOCS_RUN)).reshape(n)
+
+
+def _batch_doc_ids(doc_keys, doc_ids, sip, dip, n_valid):
+    """One batch's tokens, [sources | destinations] (2 x E): the store
+    row of each, which of them are real events' (the first `n_valid` of
+    either half), and which of those carry an address the table lacks."""
+    fill = doc_keys.shape[0] - 1
+    with device_scope("onix.stream.docs"):
+        did = _stream_doc_ids(doc_keys, doc_ids,
+                              jnp.concatenate([sip, dip]), fill)
+        valid = jax.lax.iota(jnp.int32, sip.shape[0]) < n_valid
+        valid2 = jnp.concatenate([valid, valid])
+        return did, valid2, (did == fill) & valid2
+
+
+@jax.jit
+def stream_docs_probe(doc_keys, doc_ids, sip, dip, n_valid):
+    """Per staged batch, how many of its tokens carry an address the
+    document table lacks, and which: the look-up's own hit test, run
+    when the batch is staged, so the host touches a batch's addresses
+    only where there is something to insert, and then only those."""
+    def misses(xs):
+        missed = _batch_doc_ids(doc_keys, doc_ids, *xs)[2]
+        return jnp.sum(missed), missed
+
+    return jax.lax.map(misses, (sip, dip, n_valid))
+
+
+@functools.partial(
+    jax.jit, donate_argnames=("store", "last_seen"),
+    static_argnames=("salt", "n_buckets", "tol", "max_results", "alpha",
+                     "eta", "tau0", "kappa", "local_iters",
+                     "meanchange_tol", "warm_iters", "estep_form"))
+def stream_svi_step(state: SVIState, store, last_seen, doc_keys, doc_ids,
+                    edges, cols, proto_remap, n_valid, corpus_docs,
+                    batch_no, *, salt: int, n_buckets: int, tol: float,
+                    max_results: int, **svi):
+    """S flow minibatches against the resident state, one after the
+    other (module docstring). `cols` holds the staged raw columns, each
+    [S, E]; `store` [cap, K] and `last_seen` [cap] are donated; row
+    `cap - 1` of the store is never a document's (padding tokens and
+    table misses point there, with weight 0).
+
+    Returns (state, store, last_seen, prev, out): `prev` is (lam,
+    store) as they stood before the LAST batch, and that batch's
+    document ids [2 E] (what a replay of that batch, or a reference,
+    starts from); `out` per batch the winners
+    (`scores`, `indices` [S, max_results], ascending, -1 where fewer
+    qualified), every event's score `events` [S, E], the E-step's
+    `stats` [S, 3] (lda_svi._run_e_step) and `misses` [S], the tokens
+    whose address the table lacked (0 when the host has done its part).
+    """
+    n_pad = cols["sip_u32"].shape[1]
+
+    def batch(carry, xs):
+        st, store, seen, _ = carry
+        c, remap, n, cdocs, bno = xs
+        lam0, store0 = st.lam, store
+        with device_scope("onix.words.bin"):
+            wid = dw.flow_stream_buckets(
+                dw.FlowStreamTables(*edges, remap), c["sport"], c["dport"],
+                c["proto_id"], c["hour"], c["ibyt"], c["ipkt"],
+                salt=salt, n_buckets=n_buckets)
+        did, valid2, missed = _batch_doc_ids(
+            doc_keys, doc_ids, c["sip_u32"], c["dip_u32"], n)
+        st, store, touched, tok, stats = svi_store_step(
+            st, store, did, jnp.concatenate([wid, wid]),
+            valid2.astype(jnp.float32), cdocs, **svi)
+        with device_scope("onix.svi.score"):
+            # [src|dst] tokens of the same events in order: the event's
+            # score is the smaller of its two tokens'.
+            ev = jnp.minimum(tok[:n_pad], tok[n_pad:])
+            kept = jnp.where(valid2[:n_pad] & (ev < tol), ev, jnp.inf)
+        top = scoring._scan_bottom_k(
+            (kept,), n_pad, lambda x: x, max_results=max_results,
+            chunk=_SELECT_CHUNK, merge_buffer=128)
+        seen = jnp.where(touched, bno, seen)
+        return (st, store, seen, (lam0, store0, did)), {
+            "scores": top.scores, "indices": top.indices, "events": ev,
+            "stats": jnp.stack(stats), "misses": jnp.sum(missed)}
+
+    (state, store, last_seen, prev), out = jax.lax.scan(
+        batch, (state, store, last_seen,
+                (state.lam, store, jnp.zeros((2 * n_pad,), jnp.int32))),
+        (cols, proto_remap, n_valid, corpus_docs, batch_no))
+    return state, store, last_seen, prev, out
 
 
 class DocTable:
@@ -262,14 +395,52 @@ class _Prep:
 
 
 @dataclasses.dataclass
-class BatchResult:
-    """Incremental scoring output for one minibatch."""
+class _Resident:
+    """The scorer's per-document state while it lives on the device."""
 
-    scores: np.ndarray        # float64 [n_events] per-event score
-    alerts: pd.DataFrame      # events with score < tol, ascending, enriched
-    n_events: int
-    n_new_docs: int
-    step: int                 # global SVI step after this batch
+    store: jax.Array          # float32 [cap, K] gamma; row cap-1 no doc's
+    last_seen: jax.Array      # int32 [cap] batch number of the last touch
+    doc_keys: jax.Array       # uint32 [cap] sorted addresses, padded
+    doc_ids: jax.Array        # int32 [cap] their store rows
+    n_docs: int               # documents in the device copy of the table
+
+
+@dataclasses.dataclass
+class _Staged:
+    """One group of flow minibatches on its way to the device."""
+
+    group: list               # [(table, cols)], cols converted
+    cols: dict                # name -> device array [S, E]
+    remap: jax.Array          # int32 [S, _PROTO_ROWS]
+    n_valid: np.ndarray       # int32 [S] events per batch
+    probe: tuple | None = None        # stream_docs_probe's answer ...
+    probe_docs: int = -1              # ... against a table of this size
+
+    def holds(self, group: list) -> bool:
+        """Whether this is `group`, frame for frame."""
+        return len(self.group) == len(group) and all(
+            a[0] is b[0] for a, b in zip(self.group, group))
+
+
+class BatchResult:
+    """Incremental scoring output for one minibatch. `scores` (float64
+    [n_events], the per-event score) may be handed over as a zero-argument
+    callable: the resident path leaves the array on the device until it
+    is read."""
+
+    def __init__(self, scores, alerts: pd.DataFrame, n_events: int,
+                 n_new_docs: int, step: int):
+        self._scores = scores
+        self.alerts = alerts      # events under tol, ascending, enriched
+        self.n_events = n_events
+        self.n_new_docs = n_new_docs
+        self.step = step          # global SVI step after this batch
+
+    @property
+    def scores(self) -> np.ndarray:
+        if callable(self._scores):
+            self._scores = self._scores()
+        return self._scores
 
 
 class StreamingScorer:
@@ -324,8 +495,8 @@ class StreamingScorer:
         self.max_docs = max_docs
         self._last_seen = np.zeros(self._gamma.shape[0], np.int64)
         self.pad_shapes: set[tuple[int, int]] = set()   # compile accounting
-        # Superstep program shapes (S, pad_to, pad_docs) — its own
-        # lattice dimension next to pad_shapes.
+        # Resident superstep program shapes (S, padded events, store
+        # rows) — its own lattice dimension next to pad_shapes.
         self.superstep_shapes: set[tuple[int, int, int]] = set()
         # Cumulative per-stage walls (seconds) — the r03 streaming rate
         # was 300x under the batch scan with the host path unprofiled
@@ -335,6 +506,7 @@ class StreamingScorer:
         # that ran hidden under the previous batch's step, wait = the
         # residual the consumer still blocked on.
         self.stage_walls = {"words": 0.0, "ids": 0.0, "minibatch": 0.0,
+                            "stage": 0.0, "doc_growth": 0.0,
                             "svi_update": 0.0, "score": 0.0, "emit": 0.0,
                             "prefetch_overlap": 0.0, "prefetch_wait": 0.0}
         # Which word path each batch rode (device fused vs host
@@ -367,6 +539,24 @@ class StreamingScorer:
         self.feedback_stats = {"applied": 0, "suppress_keys": 0,
                                "boost_keys": 0, "online_steps": 0}
         self._batch_no = 0
+        # The resident superstep (module docstring): the state on the
+        # device (None while the host arrays are the truth), the group
+        # staged ahead, the fitted edges as device arrays, and (lam,
+        # store) as they stood before the last batch a superstep ran.
+        self._res: _Resident | None = None
+        self._staged: _Staged | None = None
+        self._edges_dev = None
+        self.before_last_batch = None
+        self.last_estep_stats = None    # int [S, 3]: _run_e_step's stats
+        self._step_kw = dict(
+            salt=self._salt, n_buckets=self.n_buckets,
+            tol=float(cfg.pipeline.tol),
+            max_results=int(cfg.pipeline.max_results),
+            alpha=lda.alpha, eta=lda.eta, tau0=lda.svi_tau0,
+            kappa=lda.svi_kappa, local_iters=lda.svi_local_iters,
+            meanchange_tol=lda.svi_meanchange_tol,
+            warm_iters=max(lda.svi_warm_iters, 0),
+            estep_form=lda.stream_estep)
         self.checkpoint_dir = (pathlib.Path(checkpoint_dir)
                                if checkpoint_dir else None)
         if self.checkpoint_dir is not None and resume:
@@ -412,6 +602,7 @@ class StreamingScorer:
         from onix import checkpoint as ckpt
         if self.checkpoint_dir is None:
             return
+        self._pull_resident()
         edges = None
         if self.edges is not None:
             edges = {k: (v if isinstance(v, list) else np.asarray(v).tolist())
@@ -514,6 +705,7 @@ class StreamingScorer:
         bounded no matter how many distinct IPs it ever sees."""
         if self.max_docs is None or self.docs.n_docs <= self.max_docs:
             return 0
+        self._drop_resident()       # rows move: the host arrays decide
         n = self.docs.n_docs
         target = max(1, int(self.max_docs * 0.75))
         # Survivors = the `target` most recently seen (ties broken by
@@ -680,6 +872,7 @@ class StreamingScorer:
         pair build — shared by process() and process_many() so the
         per-batch and superstep arms cannot drift. Mutates scorer
         state in stream order (edge freeze, doc-table growth)."""
+        self._drop_resident()
         t_stage = time.perf_counter
         t0 = t_stage()
         dev = (self._device_words(table, cols)
@@ -1002,6 +1195,7 @@ class StreamingScorer:
         keep = weights > 0
         if not keep.any():
             return {"online_steps": 0}
+        self._drop_resident()
         if isinstance(self.docs, U32DocTable):
             if words.ip_u32 is None:
                 # One odd feedback frame (IPv6/malformed rows) must
@@ -1154,137 +1348,314 @@ class StreamingScorer:
             self.save_checkpoint()
         return res
 
-    def process_many(self, batches: list,
-                     superstep: int | None = None) -> list[BatchResult]:
+    def process_many(self, batches: list, superstep: int | None = None,
+                     stage_next: list | None = None) -> list[BatchResult]:
         """Process a list of (table, cols) minibatches in stream order.
 
         With superstep S > 1 (pipeline.stream_superstep, or the
-        explicit override), every group of S batches is ONE fused
-        device dispatch: E-step + natural-gradient λ-step +
-        incremental scoring for all S batches chained inside one
-        jitted program (lda_svi.svi_superstep), warm starts flowing
-        batch-to-batch through a device-resident union gamma store,
-        and the scores block fetched ONCE per group. S <= 1 degrades
-        to per-batch process() calls.
+        explicit override), every group of S batches that the resident
+        path takes is ONE device dispatch (`stream_svi_step`, module
+        docstring), the next group staged while it runs; `stage_next`
+        names the group a later call will bring, to be staged under
+        this call's last dispatch. A batch the resident path declines
+        goes through `_process_one` in its turn. S <= 1 degrades to
+        per-batch process() calls.
 
-        Semantics vs the per-batch path: identical E-step/λ-step/
-        scoring math per batch (winner-set parity asserted in tests);
-        eviction and checkpointing land on superstep boundaries, so
-        with max_docs set the doc bound gains up to S batches of
-        slack before the LRU sweep."""
+        Semantics vs the per-batch path: the same E-step, lambda step
+        and scoring per batch, over the tokens undeduped (winner-set
+        parity asserted in tests); addresses first seen anywhere in a
+        group enter the table before the group runs; eviction and
+        checkpointing land on superstep boundaries, so with max_docs
+        set the doc bound gains up to S batches of slack before the
+        LRU sweep."""
         s = self.superstep if superstep is None else max(1, superstep)
         if s <= 1:
             return [self.process(t, cols=c) for t, c in batches]
+        groups = [batches[i:i + s] for i in range(0, len(batches), s)]
         out: list[BatchResult] = []
-        for i in range(0, len(batches), s):
-            out.extend(self._process_superstep(batches[i:i + s]))
+        for gi, group in enumerate(groups):
+            ahead = groups[gi + 1] if gi + 1 < len(groups) else stage_next
+            out.extend(self._process_superstep(group, ahead))
         return out
 
-    def _process_superstep(self, group: list) -> list[BatchResult]:
-        from onix.utils import telemetry
+    def _process_superstep(self, group: list,
+                           stage_next: list | None) -> list[BatchResult]:
+        from onix.utils import faults
 
         # Per-group trace id, deterministic in the batch counter (the
-        # per-batch analog lives in process()); one fused dispatch =
-        # one stream.superstep span.
+        # per-batch analog lives in process()); one group = one
+        # stream.superstep span, with stream.stage (the next group),
+        # stream.doc_growth and stream.fetch under it.
         with telemetry.TRACER.trace(f"stream-s{self._batch_no + 1}"), \
                 telemetry.TRACER.span("stream.superstep",
                                       batches=len(group)):
-            return self._process_superstep_traced(group)
+            # All fault hooks fire BEFORE any scorer state mutates, so
+            # a caller retrying the group (run_stream does) replays it
+            # against unchanged state — same contract as process().
+            for _ in group:
+                faults.fire("stream", "batch")
+            staged = self._staged
+            if staged is not None and staged.holds(group):
+                group = staged.group        # with its converted columns
+            results: list[BatchResult] = []
+            run: list = []
+            for table, cols in group:
+                if cols is None and len(table):
+                    cols = self.convert_columns(table)
+                if self._resident_eligible(table, cols):
+                    run.append((table, cols))
+                    continue
+                # Maximal runs of eligible batches go resident; the
+                # batch between them takes the host path in its turn.
+                if run:
+                    results.extend(self._resident_superstep(run, None))
+                    run = []
+                results.append(self._process_one(table, cols))
+            if run:
+                results.extend(self._resident_superstep(run, stage_next))
+            return results
 
-    def _process_superstep_traced(self, group: list) -> list[BatchResult]:
-        from onix.utils import faults
+    # -- the resident superstep (module docstring) ------------------------
 
-        # All fault hooks fire BEFORE any scorer state mutates, so a
-        # caller retrying the group (run_stream does) replays it
-        # against unchanged state — same contract as process().
-        for _ in group:
-            faults.fire("stream", "batch")
-        results: list[BatchResult | None] = [None] * len(group)
-        live = []
-        for gi, (table, _) in enumerate(group):
-            if len(table) == 0:
-                results[gi] = BatchResult(np.empty(0),
-                                          table.iloc[0:0].copy(), 0, 0,
-                                          int(self.state.step))
+    def _resident_eligible(self, table, cols: dict | None) -> bool:
+        f = self.noise_filter
+        return (self.datatype == "flow" and len(table) > 0
+                and cols is not None and "ip_table" not in cols
+                and (f is None or f.empty_filter)
+                and len(cols["proto_classes"]) <= _PROTO_ROWS
+                and self._device_eligible())
+
+    def _push_table(self) -> None:
+        """The document table as the look-up wants it: addresses
+        ascending with their store rows, padded to the store's rows
+        with the largest key (a real document of that address sorts
+        first and answers)."""
+        r = self._res
+        cap, n = r.store.shape[0], self.docs.n_docs
+        order = np.argsort(self.docs.keys, kind="stable")
+        keys = np.full(cap, np.iinfo(np.uint32).max, np.uint32)
+        keys[:n] = self.docs.keys[order]
+        ids = np.full(cap, cap - 1, np.int32)
+        ids[:n] = order
+        r.doc_keys, r.doc_ids, r.n_docs = (jax.device_put(keys),
+                                           jax.device_put(ids), n)
+
+    def _push_resident(self) -> None:
+        """Host arrays -> device. Rows no document owns hold the cold
+        start, `alpha + 1`: a document inserted later starts from it."""
+        n = self.docs.n_docs
+        self._grow(n + 1)           # at least one row that is no doc's
+        store = self._gamma.copy()
+        store[n:] = self.cfg.lda.alpha + 1.0
+        self._res = _Resident(
+            jax.device_put(store),
+            jax.device_put(self._last_seen.astype(np.int32)), None, None, n)
+        self._push_table()
+
+    def _pull_resident(self) -> None:
+        """Device -> host arrays (checkpoints, eviction, the host
+        path); the device copy stays the truth."""
+        r = self._res
+        if r is None:
+            return
+        n = self.docs.n_docs
+        self._grow(r.store.shape[0])
+        self._gamma[:n] = np.asarray(r.store)[:n]
+        self._last_seen[:n] = np.asarray(r.last_seen)[:n]
+
+    def _drop_resident(self) -> None:
+        """Hand the state back to the host arrays: whatever touches
+        `_gamma`, `_last_seen` or the table's order calls this first."""
+        if self._res is not None:
+            self._pull_resident()
+            self._res = self._staged = None
+
+    def snapshot_resident(self) -> dict:
+        """Device copies of what a resident superstep changes (the
+        program donates the store): with `restore_resident`, the same
+        superstep can be run again from the same state."""
+        if self._res is None:
+            self._push_resident()
+        r = self._res
+        return {"state": self.state, "store": jnp.copy(r.store),
+                "last_seen": jnp.copy(r.last_seen),
+                "batch_no": self._batch_no}
+
+    def restore_resident(self, snap: dict) -> None:
+        r = self._res
+        self.state = snap["state"]
+        r.store, r.last_seen = (jnp.copy(snap["store"]),
+                                jnp.copy(snap["last_seen"]))
+        self._batch_no = snap["batch_no"]
+
+    def _stage(self, group: list, ahead: bool = False) -> _Staged:
+        """Cast, pad to a power of two of events and start the copies
+        of one group's raw columns, each [S, E]; a group staged ahead
+        of its turn also asks the device which of its tokens carry an
+        address the table lacks (`stream_docs_probe`, behind the
+        running superstep)."""
+        from onix.pipelines.words import _PROTO_UNK, proto_remap_codes
+
+        t0 = time.perf_counter()
+        s = len(group)
+        n_valid = np.asarray([len(t) for t, _ in group], np.int32)
+        with telemetry.TRACER.span("stream.stage", batches=s,
+                                   events=int(n_valid.sum())):
+            e_pad = _next_pow2(int(n_valid.max()))
+            dev = {}
+            for name, dtype in _FLOW_COLUMNS:
+                buf = np.zeros((s, e_pad), dtype)
+                for i, (_, cols) in enumerate(group):
+                    buf[i, :n_valid[i]] = cols[name]
+                # device_words._put under the stream's own span name
+                # (a span's name is a literal: the linter's contract).
+                with telemetry.TRACER.span("stream.h2d_put",
+                                           bytes=int(buf.nbytes)):
+                    dev[name] = jax.device_put(buf)
+            remap = np.zeros((s, _PROTO_ROWS), np.int32)
+            for i, (_, cols) in enumerate(group):
+                codes = proto_remap_codes(self.edges["proto_classes"],
+                                          list(cols["proto_classes"]),
+                                          _PROTO_UNK)
+                remap[i, :len(codes)] = codes
+            staged = _Staged(group, dev, jax.device_put(remap), n_valid)
+            r = self._res
+            if ahead and r is not None:
+                staged.probe = stream_docs_probe(
+                    r.doc_keys, r.doc_ids, dev["sip_u32"], dev["dip_u32"],
+                    n_valid)
+                staged.probe_docs = r.n_docs
+        self.stage_walls["stage"] += time.perf_counter() - t0
+        return staged
+
+    def _probe_on_host(self, staged: _Staged) -> None:
+        """The probe's answer for a group whose turn has come unprobed
+        (the stream's first resident group, one after a host-path
+        batch, a table that changed since): the device is idle then,
+        and the look-up against the host's own table needs no program
+        (the first one's compile would stand in the stream's way)."""
+        keys = np.sort(self.docs.keys)
+        e_pad = staged.cols["sip_u32"].shape[1]
+        masks = np.zeros((len(staged.group), 2 * e_pad), bool)
+        for i, (table, cols) in enumerate(staged.group):
+            n = len(table)
+            for at, name in ((0, "sip_u32"), (e_pad, "dip_u32")):
+                addr = np.asarray(cols[name], np.uint32)
+                if len(keys):
+                    pos = np.minimum(np.searchsorted(keys, addr),
+                                     len(keys) - 1)
+                    masks[i, at:at + n] = keys[pos] != addr
+                else:
+                    masks[i, at:at + n] = True
+        staged.probe = (masks.sum(axis=1), masks)
+        staged.probe_docs = self._res.n_docs
+
+    def _grow_docs(self, staged: _Staged) -> list[int]:
+        """Insert the group's unseen addresses (the tokens the probe
+        marked), batch by batch in stream order, and hand the device
+        the grown table (and a grown store, where the rows run out).
+        Returns the table's size after each batch: the running D of its
+        lambda step."""
+        misses = np.asarray(staged.probe[0])
+        if not misses.any():
+            return [self.docs.n_docs] * len(staged.group)
+        t0 = time.perf_counter()
+        with telemetry.TRACER.span("stream.doc_growth",
+                                   batches=int((misses > 0).sum())):
+            before, after = self.docs.n_docs, []
+            e_pad = staged.cols["sip_u32"].shape[1]
+            for i, (table, cols) in enumerate(staged.group):
+                if misses[i]:
+                    # The probe's mask names the tokens; their distinct
+                    # addresses enter in ascending order, as a batch's
+                    # do on the host path (U32DocTable.ids).
+                    mask, n = np.asarray(staged.probe[1][i]), len(table)
+                    self.docs.ids(np.concatenate([
+                        np.asarray(cols["sip_u32"], np.uint32)[mask[:n]],
+                        np.asarray(cols["dip_u32"], np.uint32)[
+                            mask[e_pad:e_pad + n]]]))
+                after.append(self.docs.n_docs)
+            if after[-1] >= self._res.store.shape[0]:
+                self._pull_resident()       # the rows have run out
+                self._push_resident()
             else:
-                live.append(gi)
-        if not live:
-            return results
-        if len(live) == 1:
-            gi = live[0]
-            results[gi] = self._process_one(*group[gi])
-            return results
+                self._push_table()
+            counters.inc("stream.new_docs", after[-1] - before)
+        self.stage_walls["doc_growth"] += time.perf_counter() - t0
+        return after
 
-        import jax.numpy as jnp
+    def _resident_superstep(self, run: list,
+                            stage_next: list | None) -> list[BatchResult]:
+        staged, self._staged = self._staged, None
+        if staged is None or not staged.holds(run):
+            staged = self._stage(run)
+        if self._res is None:
+            self._push_resident()
+        if staged.probe is None or staged.probe_docs != self._res.n_docs:
+            self._probe_on_host(staged)
+        docs_before = self.docs.n_docs
+        docs_after = self._grow_docs(staged)
+        r = self._res
+        if self._edges_dev is None:
+            self._edges_dev = tuple(dw.build_flow_stream_tables(
+                self.edges, [])[:3])
+        s = len(run)
+        self.superstep_shapes.add(
+            (s, staged.cols["sip_u32"].shape[1], r.store.shape[0]))
+        step0, bno = int(self.state.step), self._batch_no
 
-        from onix.models.lda_svi import SuperBatch, minibatch_arrays
-
-        preps = [self._prep_batch(*group[gi]) for gi in live]
-        t_stage = time.perf_counter
-        t0 = t_stage()
-        # One shared static shape for the whole group (the stream's
-        # equal-size batches land on one (pad_to, pad_docs) anyway).
-        pad_to, pad_docs = self._pick_pad(
-            max(p.t_rows for p in preps),
-            max(p.n_batch_docs for p in preps))
-        self.superstep_shapes.add((len(preps), pad_to, pad_docs))
-        k = self._gamma.shape[1]
-        arrs = [minibatch_arrays(p.did_b, p.wid_b, pad_to=pad_to,
-                                 pad_docs=pad_docs, weights=p.weights)
-                for p in preps]
-        doc_maps = [a[3] for a in arrs]
-        # Union of every global doc the group touches → the device
-        # warm-start store. Docs that existed before the superstep
-        # start from their live gamma; docs first seen inside the
-        # group start cold (alpha+1) exactly as the per-batch g0
-        # does — their creating batch is their first toucher, and
-        # later batches in the group warm-start from the store row
-        # that batch wrote on device.
-        union = np.unique(np.concatenate([dm[dm >= 0]
-                                          for dm in doc_maps]))
-        u = len(union)
-        u_pad = _next_pow2(u + 1, floor=64)   # +1: last row = pad dummy
-        gamma_union = np.full((u_pad, k), self.cfg.lda.alpha + 1.0,
-                              np.float32)
-        pre = union < preps[0].docs_before
-        gamma_union[:u][pre] = self._gamma[union[pre]]
-        dmu = np.full((len(live), pad_docs), -1, np.int32)
-        for i, dm in enumerate(doc_maps):
-            r = dm >= 0
-            dmu[i][r] = np.searchsorted(union, dm[r]).astype(np.int32)
-        sb = SuperBatch(
-            doc_ids=jnp.asarray(np.stack([a[0] for a in arrs])),
-            word_ids=jnp.asarray(np.stack([a[1] for a in arrs])),
-            mask=jnp.asarray(np.stack([a[2] for a in arrs])),
-            doc_map=jnp.asarray(dmu),
-            n_docs=pad_docs)
-        corpus = np.maximum(
-            np.asarray([p.n_docs_after for p in preps], np.float32), 2.0)
-        self.stage_walls["minibatch"] += t_stage() - t0
-
-        t0 = t_stage()
-        self.state, store, scores = self.model.update_superstep(
-            self.state, sb, gamma_union, corpus)
-        scores_h = np.asarray(scores)     # THE one fetch per superstep
-        store_h = np.asarray(store)
+        t0 = time.perf_counter()
+        self.state, r.store, r.last_seen, self.before_last_batch, out = \
+            stream_svi_step(
+                self.state, r.store, r.last_seen, r.doc_keys, r.doc_ids,
+                self._edges_dev, staged.cols, staged.remap, staged.n_valid,
+                np.maximum(np.asarray(docs_after, np.float32), 2.0),
+                np.arange(bno + 1, bno + s + 1, dtype=np.int32),
+                **self._step_kw)
         self.dispatches["superstep"] += 1
-        self.stage_walls["svi_update"] += t_stage() - t0
-        self._gamma[union] = store_h[:u]
+        if stage_next is not None:
+            ahead = [(t, self.convert_columns(t)
+                      if c is None and len(t) else c)
+                     for t, c in stage_next]
+            if all(self._resident_eligible(t, c) for t, c in ahead):
+                self._staged = self._stage(ahead, ahead=True)
+        # THE one fetch per superstep: the winners, and the counters
+        # the program returns beside them.
+        with telemetry.TRACER.span("stream.fetch", batches=s):
+            top_s, top_i, stats, missed = jax.device_get(
+                (out["scores"], out["indices"], out["stats"],
+                 out["misses"]))
+        self.stage_walls["svi_update"] += time.perf_counter() - t0
+        if missed.any():
+            raise RuntimeError(
+                f"resident superstep: {missed.tolist()} tokens per batch "
+                "carried an address the device's document table lacks")
 
-        bno_before = self._batch_no
-        for i, gi in enumerate(live):
-            p = preps[i]
-            dm = doc_maps[i]
-            r = dm >= 0
-            self._last_seen[dm[r]] = self._batch_no + 1
-            tok = scores_h[i][:p.t_rows]
-            if p.inv is not None:
-                tok = tok[p.inv]
-            results[gi] = self._emit(p, tok, evict=False)
+        t0 = time.perf_counter()
+        results = []
+        events = out["events"]
+        for i, (table, _) in enumerate(run):
+            n = len(table)
+            hit = top_i[i][top_i[i] >= 0]
+            alerts = table.iloc[hit].copy()
+            alerts.insert(0, "score", top_s[i][:len(hit)].astype(np.float64))
+            alerts.insert(1, "event_idx", hit)
+            results.append(BatchResult(
+                lambda i=i, n=n: np.asarray(events[i, :n]).astype(np.float64),
+                alerts, n, docs_after[i] - docs_before, step0 + i + 1))
+            docs_before = docs_after[i]
+            self.pair_rows += 2 * n
+            self.events_seen += n
+            self.words_mode_batches["device"] += 1
+        self.last_estep_stats = stats
+        counters.inc("stream.estep_iters", int(stats[:, :2].sum()))
+        counters.inc("stream.active_tokens", int(stats[:, 2].sum()))
+        self._batch_no += s
+        self.stage_walls["emit"] += time.perf_counter() - t0
         self._maybe_evict()
         every = self.cfg.lda.checkpoint_every
         if (self.checkpoint_dir is not None and every > 0
-                and self._batch_no // every != bno_before // every):
+                and self._batch_no // every != bno // every):
             self.save_checkpoint()
         return results
 
@@ -1591,10 +1962,10 @@ def run_stream(cfg: OnixConfig, datatype: str, paths: list[str],
                                           max_backoff_s=2.0,
                                           salvage_on_final=False)
 
-    def consume(meta, data):
+    def consume(meta, data, ahead=None):
         nonlocal total_events, total_alerts
         results = resilience.retry_call(
-            lambda strict: scorer.process_many(data),
+            lambda strict: scorer.process_many(data, stage_next=ahead),
             policy=batch_policy, counter_prefix="stream.batch",
             retry_on=InjectedFault)
         for (epoch, p), res in zip(meta, results):
@@ -1615,18 +1986,28 @@ def run_stream(cfg: OnixConfig, datatype: str, paths: list[str],
                   f"{len(res.alerts)} alerts, {res.n_new_docs} new docs, "
                   f"svi step {res.step}")
 
-    # Superstep grouping: S prefetched batches go through ONE fused
-    # dispatch (process_many). S=1 keeps the per-batch path; either
-    # way batches are consumed strictly in stream order.
+    # Superstep grouping: S prefetched batches go through ONE
+    # dispatch (process_many). A full group waits for the next one to
+    # fill, so that the scorer can stage it under this group's dispatch;
+    # S=1 keeps the per-batch path. Either way batches are consumed
+    # strictly in stream order.
     group_size = scorer.superstep
+    held = None
     meta_buf: list = []
     data_buf: list = []
     for (epoch, p, _), (table, cols) in zip(todo, prefetched):
         meta_buf.append((epoch, p))
         data_buf.append((table, cols))
         if len(data_buf) >= group_size:
-            consume(meta_buf, data_buf)
+            if held is not None:
+                consume(*held, ahead=data_buf)
+            held = (meta_buf, data_buf)
+            if group_size <= 1:
+                consume(*held)
+                held = None
             meta_buf, data_buf = [], []
+    if held is not None:
+        consume(*held, ahead=data_buf or None)
     if data_buf:
         consume(meta_buf, data_buf)
     sh = scorer.shape_stats

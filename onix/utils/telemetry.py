@@ -133,7 +133,11 @@ SPAN_REGISTRY: dict[str, str] = {
     "serve.score": "BankService.score body: cache lookups + bank dispatch for one batch",
     "serve.submit": "BankService.submit: one admitted request batch, queue wait + scoring",
     "stream.batch": "StreamingScorer.process: one streaming minibatch end-to-end",
-    "stream.superstep": "StreamingScorer: one fused S-batch superstep dispatch",
+    "stream.doc_growth": "StreamingScorer._grow_docs: the unseen addresses of a group's batches inserted into the document table on the host, the grown table (and store, where the rows run out) handed to the device (child of stream.superstep; only where the probe found a miss)",
+    "stream.fetch": "StreamingScorer._resident_superstep: one superstep's winners and counters to the host, blocked on the program (child of stream.superstep)",
+    "stream.h2d_put": "StreamingScorer._stage: jax.device_put of one staged [S, E] column (the host's side of the copy; child of stream.stage)",
+    "stream.stage": "StreamingScorer._stage: one group's casts into the padded [S, E] buffers, the start of their copies, the protocol remaps and the probe's dispatch (child of the stream.superstep it is staged under)",
+    "stream.superstep": "StreamingScorer.process_many: one group of S minibatches, dispatch of stream_svi_step to winners on the host (host-path batches of the group included)",
 }
 
 # ---------------------------------------------------------------------------

@@ -52,16 +52,19 @@ def test_stream_superstep_smoke():
     assert any_alerts, "feed produced no alerts — parity was vacuous"
 
     # The whole point: dispatch syncs collapse. Per-batch pays one
-    # svi_update + one score dispatch per batch; the fused arm pays
-    # one superstep dispatch per S batches and nothing else.
+    # svi_update + one score dispatch per batch; the resident arm pays
+    # them once, for the first batch (its edges fit on the host path),
+    # and then one superstep dispatch per run of eligible batches: the
+    # first group's other two, and the second group's three.
     assert per_batch.dispatches["svi_update"] == 6
     assert per_batch.dispatches["score"] == 6
     assert fused.dispatches["superstep"] == 2
-    assert fused.dispatches["svi_update"] == 0
-    assert fused.dispatches["score"] == 0
-    # One shared compiled shape per arm (static-shape contract).
+    assert fused.dispatches["svi_update"] == 1
+    assert fused.dispatches["score"] == 1
+    assert fused.words_mode_batches == {"device": 5, "host": 1}
+    # One host-path shape; one resident program per group length.
     assert len(fused.pad_shapes) == 1
-    assert len(fused.superstep_shapes) == 1
+    assert {s[0] for s in fused.superstep_shapes} == {2, 3}
 
 
 def test_stream_superstep_resume_cadence(tmp_path):

@@ -147,80 +147,100 @@ def test_warm_compacted_estep_warm_docs_frozen_cold_docs_converge():
                                atol=5e-3, rtol=2e-2)
 
 
-def test_superstep_matches_sequential_updates():
-    """svi_superstep (S chained updates + scoring in one program) must
-    reproduce the sequential svi_step chain: same final lambda, same
-    per-batch gamma in the union store, same per-token scores."""
+def _store_chain(cfg):
+    """Three batches over overlapping documents 0..11 of a 32-row store:
+    `svi_store_step` chained on the device, and `svi_step` per batch
+    with the store carried on the host. Returns both ends."""
     import jax.numpy as jnp
 
-    from onix.models.lda_svi import (SuperBatch, minibatch_arrays,
-                                     svi_superstep)
+    from onix.models.lda_svi import minibatch_arrays, svi_store_step
     from onix.models.scoring import score_events
 
     rng = np.random.default_rng(17)
-    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-4,
-                    svi_local_iters=30, svi_warm_iters=2, seed=3)
+    k = cfg.n_topics
     model = SVILda(cfg, n_vocab=50, corpus_docs=100)
     state = model.init()
-
-    # Three batches over overlapping global doc ids 0..11.
     gds = [rng.integers(0, 12, 200).astype(np.int32) for _ in range(3)]
     gws = [rng.integers(0, 50, 200).astype(np.int32) for _ in range(3)]
-    pad_to, pad_docs = 256, 16
-    arrs = [minibatch_arrays(d, w, pad_to=pad_to, pad_docs=pad_docs)
-            for d, w in zip(gds, gws)]
-    union = np.unique(np.concatenate([a[3][a[3] >= 0] for a in arrs]))
-    u = len(union)
-    u_pad = 32
-    store0 = np.full((u_pad, 4), cfg.alpha + 1.0, np.float32)
-    dmu = np.full((3, pad_docs), -1, np.int32)
-    for i, a in enumerate(arrs):
-        r = a[3] >= 0
-        dmu[i][r] = np.searchsorted(union, a[3][r]).astype(np.int32)
-    corpus = np.asarray([12.0, 12.0, 12.0], np.float32)
+    pad_to, pad_docs, cap = 256, 16, 32
+    store0 = np.full((cap, k), cfg.alpha + 1.0, np.float32)
 
     # Sequential reference: svi_step per batch, host-carried store.
-    seq_state = state
-    store_ref = store0.copy()
-    seq_scores = []
-    for i, a in enumerate(arrs):
-        batch = make_minibatch(gds[i], gws[i], pad_to=pad_to,
-                               pad_docs=pad_docs)
-        dm = a[3]
+    seq_state, store_ref, seq_scores = state, store0.copy(), []
+    for d, w in zip(gds, gws):
+        dm = minibatch_arrays(d, w, pad_to=pad_to, pad_docs=pad_docs)[3]
+        batch = make_minibatch(d, w, pad_to=pad_to, pad_docs=pad_docs)
         r = dm >= 0
-        g0 = np.full((pad_docs, 4), cfg.alpha + 1.0, np.float32)
-        g0[r] = store_ref[dmu[i][r]]
+        g0 = np.full((pad_docs, k), cfg.alpha + 1.0, np.float32)
+        g0[r] = store_ref[dm[r]]
         seq_state, gamma = model.update(seq_state, batch,
                                         corpus_docs=12.0, gamma0=g0)
         gm = np.asarray(gamma)
-        store_ref[dmu[i][r]] = gm[r]
+        store_ref[dm[r]] = gm[r]
         theta = np.where(r[:, None], gm / gm.sum(1, keepdims=True),
-                         0.25).astype(np.float32)
+                         1.0 / k).astype(np.float32)
         phi = seq_state.lam / seq_state.lam.sum(0, keepdims=True)
         seq_scores.append(np.asarray(score_events(
-            jnp.asarray(theta), phi, batch.doc_ids, batch.word_ids)))
+            jnp.asarray(theta), phi, batch.doc_ids, batch.word_ids))[:200])
 
-    sb = SuperBatch(
-        doc_ids=jnp.asarray(np.stack([a[0] for a in arrs])),
-        word_ids=jnp.asarray(np.stack([a[1] for a in arrs])),
-        mask=jnp.asarray(np.stack([a[2] for a in arrs])),
-        doc_map=jnp.asarray(dmu), n_docs=pad_docs)
-    new_state, store, scores = svi_superstep(
-        state, sb, jnp.asarray(store0), jnp.asarray(corpus),
-        alpha=cfg.alpha, eta=cfg.eta, tau0=cfg.svi_tau0,
-        kappa=cfg.svi_kappa, local_iters=cfg.svi_local_iters,
-        batch_docs=pad_docs, meanchange_tol=cfg.svi_meanchange_tol,
-        warm_iters=cfg.svi_warm_iters)
+    # The whole store on the device, tokens padded with weight 0 and
+    # pointed at the last row, which is no document's.
+    new_state, store, scores = state, jnp.asarray(store0), []
+    pad = pad_to - 200
+    for d, w in zip(gds, gws):
+        new_state, store, touched, sc, stats = svi_store_step(
+            new_state, store,
+            jnp.asarray(np.concatenate([d, np.full(pad, cap - 1, np.int32)])),
+            jnp.asarray(np.concatenate([w, np.zeros(pad, np.int32)])),
+            jnp.asarray(np.concatenate([np.ones(200, np.float32),
+                                        np.zeros(pad, np.float32)])),
+            jnp.float32(12.0), alpha=cfg.alpha, eta=cfg.eta,
+            tau0=cfg.svi_tau0, kappa=cfg.svi_kappa,
+            local_iters=cfg.svi_local_iters,
+            meanchange_tol=cfg.svi_meanchange_tol,
+            warm_iters=cfg.svi_warm_iters, estep_form=cfg.stream_estep)
+        assert set(np.flatnonzero(np.asarray(touched))) == set(np.unique(d))
+        assert int(stats[0]) == cfg.svi_warm_iters
+        scores.append(np.asarray(sc)[:200])
+    return new_state, np.asarray(store), scores, seq_state, store_ref, \
+        seq_scores
 
+
+def test_store_step_matches_sequential_updates():
+    """svi_store_step (one batch against the whole gamma store, what the
+    streaming scorer chains on the device) must reproduce the sequential
+    svi_step chain: same final lambda, same gamma rows, same per-token
+    scores; rows no batch touched keep what they held."""
+    new_state, store, scores, seq_state, store_ref, seq_scores = \
+        _store_chain(LDAConfig(n_topics=4, svi_meanchange_tol=1e-4,
+                               svi_local_iters=30, svi_warm_iters=2,
+                               seed=3))
     assert int(new_state.step) == int(seq_state.step)
     np.testing.assert_allclose(np.asarray(new_state.lam),
                                np.asarray(seq_state.lam), rtol=1e-5,
                                atol=1e-6)
-    np.testing.assert_allclose(np.asarray(store)[:u], store_ref[:u],
-                               rtol=1e-4, atol=1e-5)
-    for i in range(3):
-        np.testing.assert_allclose(np.asarray(scores)[i], seq_scores[i],
-                                   rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(store, store_ref, rtol=1e-4, atol=1e-5)
+    for got, want in zip(scores, seq_scores):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+
+
+def test_store_step_in_token_runs(monkeypatch):
+    """Past `_TOKEN_RUN` tokens every pass of svi_store_step (E-step,
+    lambda step, scores) works a run of tokens at a time, and the
+    compaction carries the columns through its sort: the same sums in
+    another order."""
+    from onix.models import lda_svi
+
+    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3)
+    whole = _store_chain(cfg)
+    monkeypatch.setattr(lda_svi, "_TOKEN_RUN", 64)     # 256 tokens: 4 runs
+    runs = _store_chain(cfg)
+    np.testing.assert_allclose(np.asarray(runs[0].lam),
+                               np.asarray(whole[0].lam), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(runs[1], whole[1], rtol=1e-4, atol=1e-5)
+    for got, want in zip(runs[2], whole[2]):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
 
 
 def test_warm_start_gamma_converges_to_same_fixed_point():
