@@ -115,12 +115,15 @@ from onix.models.compaction import (compact_front, ladder_index,  # noqa: E402
                                     pow2_ladder as _active_ladder)
 
 
-# With the words' table handed over, a pass over the tokens works
-# through them in runs of this many: a float32 [T, K] array is laid out
-# on the TPU with K padded to 128 lanes (4.3 GB at T = 2^23, K = 20, and
-# an E-step pass keeps two or three), a run's rows are 64 MB. The Gibbs
-# kernel's block: its scatter-add of 2^17 K-lane rows is what PERF.md's
-# unit costs were read at.
+# With the words' table handed over, a pass works through its rows in
+# runs of this many: a float32 [T, K] array is laid out on the TPU with
+# K padded to 128 lanes (4.3 GB at T = 2^23, K = 20, and an E-step pass
+# keeps two or three), a run's rows are 64 MB. The Gibbs kernel's block:
+# its scatter-add of 2^17 K-lane rows is what PERF.md's unit costs were
+# read at. The rows of `svi_store_step`'s E-step and lambda step are the
+# batch's unique (document, word) pairs (`_unique_pairs`), in front of
+# the token axis, and those passes stop after the runs that hold pairs;
+# its scores go over every token slot.
 _TOKEN_RUN = 1 << 17
 
 
@@ -135,23 +138,78 @@ def _in_token_runs(fn, init, arrays: tuple, n_runs=None):
     """`fn(carry, *arrays) -> (carry, y)` over the token axis, the whole
     of it at once or a run at a time (`_token_runs`; the y's joined
     again). `n_runs` (traced) stops after that many runs, for a pass
-    whose tokens of weight sit in front; such a pass has no y."""
+    whose rows of weight sit in front; the y's of the runs behind them
+    read 0."""
     n, runs = arrays[0].shape[0], _token_runs(arrays[0].shape[0])
     if runs == 1:
         return fn(init, *arrays)
     arrays = tuple(a.reshape(runs, _TOKEN_RUN) for a in arrays)
-    if n_runs is not None:
-        return jax.lax.fori_loop(
-            0, n_runs, lambda i, c: fn(c, *(a[i] for a in arrays))[0],
-            init), None
-    carry, ys = jax.lax.scan(lambda c, xs: fn(c, *xs), init, arrays)
+    if n_runs is None:
+        carry, ys = jax.lax.scan(lambda c, xs: fn(c, *xs), init, arrays)
+    else:
+        def run(i, c):
+            carry, y = fn(c[0], *(a[i] for a in arrays))
+            return carry, jax.tree.map(lambda ys, v: ys.at[i].set(v), c[1], y)
+
+        carry, ys = jax.lax.fori_loop(0, n_runs, run, (init, jax.tree.map(
+            lambda y: jnp.zeros((runs, *y.shape), y.dtype),
+            jax.eval_shape(lambda: fn(init, *(a[0] for a in arrays))[1]))))
     return carry, jax.tree.map(lambda y: y.reshape(n, *y.shape[2:]), ys)
+
+
+def _runs_holding(n_rows, n: int):
+    """The runs of a pass over `n` slots that hold its first `n_rows`
+    (traced) rows, for `_in_token_runs`; None where the pass is made at
+    once or the count is not known."""
+    if n_rows is None or _token_runs(n) == 1:
+        return None
+    return -(-n_rows // _TOKEN_RUN)
+
+
+def _unique_pairs(doc_ids, word_ids, mask):
+    """A batch's token columns reduced to its unique (document, word)
+    pairs, each with the sum of its tokens' weights: what
+    `StreamingScorer._prep_batch` makes on the host with `np.unique`,
+    made on the device with two sorts and a running sum. Returns
+    (doc_ids, word_ids, weights, n_pairs): columns as long as the token
+    columns, the `n_pairs` (int32 scalar) pairs in front, word by word
+    and within a word by document, every row behind them of weight 0
+    (and pointing at some document and word of the batch, or at 0).
+
+    A token of weight 0 belongs to no pair. Weights are multiplicities:
+    the running sum is float32, exact for whole numbers while the
+    batch's weights sum to less than 2^24.
+
+    `doc * n_words + word` does not fit 32 bits at the stream's shapes,
+    so the first sort has two keys; pairs are found by their last token
+    (the one whose successor differs), whose running sum less the pair
+    before's is the pair's weight once the second, stable, sort has put
+    the last tokens side by side."""
+    from onix.pipelines.device_words import _running_sum
+
+    t = doc_ids.shape[0]
+    big = jnp.iinfo(jnp.int32).max
+    real = mask > 0.0
+    # Weightless tokens sort behind every pair, under a word no token has.
+    w, d, m = jax.lax.sort(
+        (jnp.where(real, word_ids, big), doc_ids, jnp.where(real, mask, 0.0)),
+        num_keys=2, is_stable=False)
+    last = jnp.concatenate([(w[1:] != w[:-1]) | (d[1:] != d[:-1]),
+                            jnp.ones((1,), bool)]) & (w != big)
+    n_pairs = last.sum(dtype=jnp.int32)
+    _, d, w, upto = jax.lax.sort((~last, d, w, _running_sum(m)),
+                                 num_keys=1, is_stable=True)
+    held = jax.lax.iota(jnp.int32, t) < n_pairs
+    weights = jnp.where(
+        held, upto - jnp.concatenate([jnp.zeros((1,), upto.dtype),
+                                      upto[:-1]]), 0.0)
+    return d, jnp.where(w == big, 0, w), weights, n_pairs
 
 
 def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
                 local_iters: int, meanchange_tol: float,
                 warm_iters: int, estep_form: str = "svi",
-                with_stats: bool = False, elog_beta=None):
+                with_stats: bool = False, elog_beta=None, n_rows=None):
     """The local E-step over one minibatch's tokens. Returns gamma; with
     `with_stats` (static) also what ran, as int32 scalars: the passes
     over the full padded block, the passes of the extended loop, and the
@@ -160,7 +218,13 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
     With `elog_beta` ([V, K]) given, `elog_beta_t` holds the tokens'
     WORD IDS in place of their rows: every pass gathers the rows it
     needs a run of tokens at a time (`_TOKEN_RUN`) and no [T, K] array
-    is ever whole; the sums are the same sums in another order.
+    is ever whole; the sums are the same sums in another order. The
+    rows may then be weighted (document, word) pairs with those of
+    weight in front (`_unique_pairs`), `n_rows` (traced) of them: every
+    pass stops after the runs that hold them. The stats are five: the
+    tokens of the active set are the sum of its rows' weights, and
+    after them stand `n_rows` (the length of the axis if not given) and
+    the rows of the active set.
 
     `estep_form` picks the update family (static):
 
@@ -220,21 +284,29 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         return alpha + _in_token_runs(add, zero, (d_ids, eb_t, m),
                                       n_runs)[0]
 
-    def out(gamma, full, ext=0, n_act=0):
+    t = doc_ids.shape[0]
+    # The runs every pass over the whole axis stops after (ids mode).
+    all_runs = _runs_holding(n_rows, t)
+
+    def out(gamma, full, ext=0, n_act=0, act_rows=0):
         if not with_stats:
             return gamma
-        return gamma, (jnp.int32(full), jnp.int32(ext), jnp.int32(n_act))
+        stats = (jnp.int32(full), jnp.int32(ext), jnp.int32(n_act))
+        if elog_beta is not None:
+            stats += (jnp.int32(t if n_rows is None else n_rows),
+                      jnp.int32(act_rows))
+        return gamma, stats
 
     if meanchange_tol <= 0.0:
         return out(jax.lax.fori_loop(
             0, local_iters,
-            lambda _, g: e_step(g, doc_ids, elog_beta_t, mask), gamma0),
-            local_iters)
+            lambda _, g: e_step(g, doc_ids, elog_beta_t, mask, all_runs),
+            gamma0), local_iters)
 
     if warm_iters <= 0:
         def body(carry):
             gamma, _, i = carry
-            g2 = e_step(gamma, doc_ids, elog_beta_t, mask)
+            g2 = e_step(gamma, doc_ids, elog_beta_t, mask, all_runs)
             # Per-DOCUMENT convergence, as in Hoffman's rule: iterate
             # until EVERY doc's mean |Δgamma| is under tol. A
             # batch-global mean would let a majority of converged
@@ -252,13 +324,12 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             cond, body, (gamma0, jnp.float32(jnp.inf), jnp.int32(0)))
         return out(gamma, i)
 
-    t = doc_ids.shape[0]
     warm = min(int(warm_iters), int(local_iters))
     rem_iters = int(local_iters) - warm
 
     def warm_body(_, carry):
         g, _ = carry
-        g2 = e_step(g, doc_ids, elog_beta_t, mask)
+        g2 = e_step(g, doc_ids, elog_beta_t, mask, all_runs)
         return g2, jnp.abs(g2 - g).mean(axis=1)
 
     gamma, delta_d = jax.lax.fori_loop(
@@ -268,7 +339,12 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         return out(gamma, warm)
 
     active_d = delta_d > meanchange_tol              # [Bd]
-    act_tok = active_d[doc_ids] & (mask > 0.0)       # [T]
+    if elog_beta is None:
+        act_tok = active_d[doc_ids] & (mask > 0.0)   # [T]
+    else:
+        act_tok = _in_token_runs(
+            lambda _, d, m: (None, active_d[d] & (m > 0.0)), None,
+            (doc_ids, mask), all_runs)[1]
     n_act = act_tok.sum()
     # Stable compaction: active docs' tokens to the front, order kept.
     if elog_beta is None:
@@ -283,6 +359,10 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         _, c_doc, c_eb, c_mask = jax.lax.sort(
             (~act_tok, doc_ids, elog_beta_t, jnp.where(act_tok, mask, 0.0)),
             num_keys=1, is_stable=True)
+    # The loop's bound counts rows; the stats count tokens too, which a
+    # row of weight w holds w of.
+    act_tokens = n_act if elog_beta is None else c_mask.astype(
+        jnp.int32).sum()
 
     def make_branch(size, n_runs=None):
         d_ids = jax.lax.slice_in_dim(c_doc, 0, size)
@@ -317,16 +397,16 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
 
     if elog_beta is not None and _token_runs(t) > 1:
         # A pass made in runs needs no ladder: the one loop stops after
-        # the runs that hold the active tokens.
-        gamma, ext = make_branch(t, -(-n_act // _TOKEN_RUN))(gamma)
-        return out(gamma, warm, ext, n_act)
+        # the runs that hold the active rows.
+        gamma, ext = make_branch(t, _runs_holding(n_act, t))(gamma)
+        return out(gamma, warm, ext, act_tokens, n_act)
     sizes = _active_ladder(t)
     # Smallest rung that still holds every active token (compaction
     # preserves order, so the first n_act compacted slots are exactly
     # the active tokens).
     idx = ladder_index(n_act, sizes)
     gamma, ext = jax.lax.switch(idx, [make_branch(s) for s in sizes], gamma)
-    return out(gamma, warm, ext, n_act)
+    return out(gamma, warm, ext, act_tokens, n_act)
 
 
 def svi_step(
@@ -437,6 +517,14 @@ def svi_store_step(
     the device and donates it); there is no per-batch document re-index
     and no union of a group's documents to build.
 
+    The E-step and the lambda step run over the batch's unique
+    (document, word) pairs, each weighted by the sum of its tokens'
+    weights (`_unique_pairs`: made here, on the device, for every
+    batch; by `MiniBatch`'s contract a pair of weight w contributes
+    what w identical tokens would), and stop after the runs of
+    `_TOKEN_RUN` that hold pairs; the scores are per token, over
+    `doc_ids` and `word_ids` as they came, in their order.
+
     A document the batch does not touch sits in the E-step as a padding
     row does in `svi_step` (it holds `alpha` and no token reaches it)
     and keeps its stored gamma; a row never written holds whatever the
@@ -445,7 +533,10 @@ def svi_store_step(
     touches none). Padding tokens may point at any row.
 
     Returns (state, store, touched bool [cap], scores float32 [T],
-    stats), `stats` as `_run_e_step(with_stats=True)` gives it."""
+    stats), `stats` as `_run_e_step(with_stats=True)` gives it: the
+    passes over every pair, the passes of the extended loop, the TOKENS
+    of its active set (the sum of its pairs' weights), the batch's
+    pairs, the active set's pairs."""
     from onix.models.scoring import score_events
     from onix.utils.obs import device_scope
 
@@ -459,14 +550,21 @@ def svi_store_step(
         return _e_log_dirichlet(x, axis=axis)
 
     with device_scope("onix.svi.estep"):
-        touched = jnp.zeros((store.shape[0],), jnp.float32).at[
-            doc_ids].add(mask) > 0.0
+        with device_scope("onix.svi.estep.pairs"):
+            p_doc, p_word, p_weight, n_pairs = _unique_pairs(
+                doc_ids, word_ids, mask)
+        pair_runs = _runs_holding(n_pairs, p_doc.shape[0])
+        touched = _in_token_runs(
+            lambda acc, d, m: (acc.at[d].add(m), None),
+            jnp.zeros((store.shape[0],), jnp.float32), (p_doc, p_weight),
+            pair_runs)[0] > 0.0
         elog_beta = elog_rows(lam, 0)
         gamma, stats = _run_e_step(
-            jnp.where(touched[:, None], store, alpha), word_ids,
-            doc_ids, mask, alpha=alpha, local_iters=local_iters,
+            jnp.where(touched[:, None], store, alpha), p_word,
+            p_doc, p_weight, alpha=alpha, local_iters=local_iters,
             meanchange_tol=meanchange_tol, warm_iters=warm_iters,
-            estep_form=estep_form, with_stats=True, elog_beta=elog_beta)
+            estep_form=estep_form, with_stats=True, elog_beta=elog_beta,
+            n_rows=n_pairs)
     with device_scope("onix.svi.lambda"):
         elog_theta = elog_rows(gamma, 1)
 
@@ -476,7 +574,7 @@ def svi_store_step(
             return acc.at[w].add(phi * m[:, None]), None
 
         sstats, _ = _in_token_runs(add, jnp.zeros_like(lam),
-                                   (doc_ids, word_ids, mask))
+                                   (p_doc, p_word, p_weight), pair_runs)
         n_real = touched.sum().astype(jnp.float32)
         scale = corpus_docs / jnp.maximum(n_real, 1.0)
         rho = (tau0 + state.step.astype(jnp.float32)) ** (-kappa)

@@ -60,8 +60,12 @@ Streaming-specific design (vs the batch path in pipelines/run.py):
   a group whose turn comes unprobed is looked up against the host's own
   table), eviction, alert rows, checkpoints. `self._gamma` and `self._last_seen` are filled from the
   device at checkpoints, at eviction and when a batch takes the host
-  path. Tokens run undeduped, each with weight 1: by `make_minibatch`'s
-  contract the same update as the deduped weighted pairs.
+  path. The program is handed every token with weight 1 and reduces
+  them itself to the batch's unique (document, bucket) pairs with their
+  counts (`lda_svi._unique_pairs`, on the device: what `_prep_batch`
+  makes on the host), over which the E-step and the lambda step run; by
+  `make_minibatch`'s contract the same update. `pair_rows` counts the
+  pairs on either path, the counter `stream.pair_rows` this path's.
   `BatchResult.scores` is the device's per-event array, fetched when it
   is read.
 - **The per-batch host path.** `process`, S <= 1, and every batch the
@@ -235,7 +239,7 @@ def stream_svi_step(state: SVIState, store, last_seen, doc_keys, doc_ids,
     starts from); `out` per batch the winners
     (`scores`, `indices` [S, max_results], ascending, -1 where fewer
     qualified), every event's score `events` [S, E], the E-step's
-    `stats` [S, 3] (lda_svi._run_e_step) and `misses` [S], the tokens
+    `stats` [S, 5] (lda_svi.svi_store_step) and `misses` [S], the tokens
     whose address the table lacked (0 when the host has done its part).
     """
     n_pad = cols["sip_u32"].shape[1]
@@ -547,7 +551,7 @@ class StreamingScorer:
         self._staged: _Staged | None = None
         self._edges_dev = None
         self.before_last_batch = None
-        self.last_estep_stats = None    # int [S, 3]: _run_e_step's stats
+        self.last_estep_stats = None    # int [S, 5]: svi_store_step's stats
         self._step_kw = dict(
             salt=self._salt, n_buckets=self.n_buckets,
             tol=float(cfg.pipeline.tol),
@@ -1644,12 +1648,15 @@ class StreamingScorer:
                 lambda i=i, n=n: np.asarray(events[i, :n]).astype(np.float64),
                 alerts, n, docs_after[i] - docs_before, step0 + i + 1))
             docs_before = docs_after[i]
-            self.pair_rows += 2 * n
             self.events_seen += n
             self.words_mode_batches["device"] += 1
         self.last_estep_stats = stats
         counters.inc("stream.estep_iters", int(stats[:, :2].sum()))
         counters.inc("stream.active_tokens", int(stats[:, 2].sum()))
+        pairs = int(stats[:, 3].sum())
+        counters.inc("stream.pair_rows", pairs)
+        counters.inc("stream.active_pairs", int(stats[:, 4].sum()))
+        self.pair_rows += pairs
         self._batch_no += s
         self.stage_walls["emit"] += time.perf_counter() - t0
         self._maybe_evict()

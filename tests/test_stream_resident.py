@@ -2,8 +2,8 @@
 the document table and the selection on the device) held to the host
 path of the same scorer, `process` per batch, on the same batches at a
 tiny shape: same winners, lambda and gamma within the float32 tolerance
-of a different summation order (the resident path runs the tokens
-undeduped, the host path the deduped weighted pairs)."""
+of a different summation order (the resident path dedupes the tokens
+on the device, pairs word by word; the host path with `np.unique`)."""
 
 import dataclasses as dc
 
@@ -85,6 +85,38 @@ def test_resident_matches_host_path(estep):
     assert res.dispatches["superstep"] == 3
     assert [r.n_new_docs for r in res_a] == [r.n_new_docs for r in res_b]
     assert res.events_seen == host.events_seen == 3500
+
+
+def test_resident_counts_the_pairs_the_host_path_counts():
+    """The resident superstep dedupes a batch's tokens on the device:
+    `pair_rows` and the counter `stream.pair_rows` read what the host
+    path's `np.unique` reads for the same batches, fewer than the
+    tokens; `stream.active_pairs` the rows of the extended loops."""
+    from onix.utils.obs import counters
+
+    chunks = _chunks(7)
+    host = StreamingScorer(_cfg(0), "flow", n_buckets=N_BUCKETS)
+    host.process(chunks[0])
+    first = host.pair_rows
+    for c in chunks[1:]:
+        host.process(c)
+    assert first < host.pair_rows < 2 * 3500    # duplicates in the feed
+
+    res = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)
+    at = {c: counters.get(c) for c in ("stream.pair_rows",
+                                       "stream.active_pairs",
+                                       "stream.active_tokens")}
+    res.process_many([(c, None) for c in chunks])
+    assert res.dispatches["superstep"] == 3
+    assert res.pair_rows == host.pair_rows
+    got = {c: counters.get(c) - v for c, v in at.items()}
+    # The first batch took the host path: the counters are the resident
+    # path's own.
+    assert got["stream.pair_rows"] == host.pair_rows - first
+    assert res.last_estep_stats.shape == (1, 5)     # the last group of one
+    assert 0 < got["stream.active_pairs"] <= got["stream.pair_rows"]
+    assert got["stream.active_pairs"] < got["stream.active_tokens"] \
+        <= 2 * 3000
 
 
 def test_resident_in_runs(monkeypatch):

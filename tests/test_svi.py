@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from onix.config import LDAConfig
 from onix.corpus import synthetic_lda_corpus
@@ -259,3 +260,217 @@ def test_warm_start_gamma_converges_to_same_fixed_point():
     _, g_warm = model.update(s0, batch, gamma0=g0)
     np.testing.assert_allclose(np.asarray(g_warm), np.asarray(g_cold),
                                atol=5e-3, rtol=1e-2)
+
+
+# -- svi_store_step over the batch's unique (document, word) pairs --------
+
+_PAIR_V, _PAIR_CAP, _PAIR_T = 50, 32, 256
+
+
+def _pair_batch(kind: str):
+    """(doc_ids, word_ids, mask) of 256 token slots over documents 0..11
+    of a 32-row store and 50 words."""
+    rng = np.random.default_rng(29)
+    d = np.full(_PAIR_T, _PAIR_CAP - 1, np.int32)
+    w = np.zeros(_PAIR_T, np.int32)
+    m = np.zeros(_PAIR_T, np.float32)
+    if kind == "no_duplicate":
+        pairs = rng.choice(12 * _PAIR_V, 200, replace=False)
+        d[:200], w[:200], m[:200] = pairs // _PAIR_V, pairs % _PAIR_V, 1.0
+    elif kind == "one_document_half":
+        # Document 3 holds 100 of 200 tokens in five pairs.
+        d[:200] = np.where(np.arange(200) % 2 == 0, 3,
+                           rng.integers(0, 12, 200))
+        w[:200] = np.where(np.arange(200) % 2 == 0,
+                           rng.integers(0, 5, 200),
+                           rng.integers(0, _PAIR_V, 200))
+        m[:200] = 1.0
+    elif kind == "weightless_scattered":
+        # Tokens of weight 0 all through the batch, pointing at any row:
+        # at documents with tokens of weight, and at rows with none.
+        d[:] = rng.integers(0, 12, _PAIR_T)
+        w[:] = rng.integers(0, 20, _PAIR_T)
+        m[:] = rng.random(_PAIR_T) < 0.7
+        d[m == 0] = rng.integers(0, _PAIR_CAP, int((m == 0).sum()))
+    else:
+        raise ValueError(kind)
+    return d, w, m
+
+
+def _store_step(cfg, state, store, d, w, m):
+    import jax.numpy as jnp
+
+    from onix.models.lda_svi import svi_store_step
+
+    return svi_store_step(
+        state, jnp.asarray(store), jnp.asarray(d), jnp.asarray(w),
+        jnp.asarray(m), jnp.float32(12.0), alpha=cfg.alpha, eta=cfg.eta,
+        tau0=cfg.svi_tau0, kappa=cfg.svi_kappa,
+        local_iters=cfg.svi_local_iters,
+        meanchange_tol=cfg.svi_meanchange_tol,
+        warm_iters=cfg.svi_warm_iters, estep_form=cfg.stream_estep)
+
+
+@pytest.mark.parametrize("kind", ["no_duplicate", "one_document_half",
+                                  "weightless_scattered"])
+@pytest.mark.parametrize("estep", ["svi", "scvb0"])
+def test_store_step_dedupes_as_the_host_path_does(estep, kind):
+    """svi_store_step, handed one row a token, leaves the lambda, the
+    store, the touched mask and the per-token scores that `svi_step`
+    leaves when fed the batch's unique (document, word) pairs with their
+    counts as weights, as `StreamingScorer._prep_batch` builds them."""
+    import jax.numpy as jnp
+
+    from onix.models.lda_svi import minibatch_arrays
+    from onix.models.scoring import score_events
+
+    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3, stream_estep=estep)
+    k = cfg.n_topics
+    model = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100)
+    state = model.init()
+    store0 = (cfg.alpha + np.random.default_rng(5).gamma(
+        2.0, 2.0, (_PAIR_CAP, k))).astype(np.float32)
+    d, w, m = _pair_batch(kind)
+
+    real = m > 0
+    uniq, inv, cnt = np.unique(d[real].astype(np.int64) * _PAIR_V + w[real],
+                               return_inverse=True, return_counts=True)
+    if kind == "no_duplicate":
+        assert len(uniq) == real.sum()
+    else:
+        assert len(uniq) < real.sum()
+    did_b, wid_b = (uniq // _PAIR_V).astype(np.int32), \
+        (uniq % _PAIR_V).astype(np.int32)
+    pad_docs = 16
+    dm = minibatch_arrays(did_b, wid_b, pad_to=_PAIR_T, pad_docs=pad_docs)[3]
+    batch = make_minibatch(did_b, wid_b, pad_to=_PAIR_T, pad_docs=pad_docs,
+                           weights=cnt.astype(np.float32))
+    r = dm >= 0
+    g0 = np.full((pad_docs, k), cfg.alpha + 1.0, np.float32)
+    g0[r] = store0[dm[r]]
+    want_state, gamma = model.update(state, batch, corpus_docs=12.0,
+                                     gamma0=g0)
+    gm = np.asarray(gamma)
+    want_store = store0.copy()
+    want_store[dm[r]] = gm[r]
+    theta = np.where(r[:, None], gm / gm.sum(1, keepdims=True),
+                     1.0 / k).astype(np.float32)
+    phi = want_state.lam / want_state.lam.sum(0, keepdims=True)
+    want_scores = np.asarray(score_events(
+        jnp.asarray(theta), phi, batch.doc_ids,
+        batch.word_ids))[:len(uniq)][inv]
+
+    got_state, store, touched, scores, stats = _store_step(
+        cfg, state, store0, d, w, m)
+    assert set(np.flatnonzero(np.asarray(touched))) == set(np.unique(did_b))
+    np.testing.assert_allclose(np.asarray(got_state.lam),
+                               np.asarray(want_state.lam), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(store), want_store, rtol=1e-4,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(scores)[real], want_scores,
+                               rtol=1e-5, atol=1e-7)
+    assert int(stats[3]) == len(uniq)
+
+
+def test_unique_pairs_equal_numpy_unique(monkeypatch):
+    """The pairs the device makes are `np.unique`'s of the weighted
+    tokens: ids, counts, their number; in front, the rows behind them
+    weightless; at ids whose packed key passes 32 bits, and past one
+    run of the passes that follow."""
+    import jax.numpy as jnp
+
+    from onix.models import lda_svi
+
+    n_words, cap = 1 << 15, 1 << 18
+    rng = np.random.default_rng(41)
+    t = 512
+    d = rng.choice([0, 1, 7, 1000, cap - 2, cap - 1], t).astype(np.int32)
+    w = rng.choice(np.concatenate([rng.integers(0, n_words, 40),
+                                   [0, n_words - 1]]), t).astype(np.int32)
+    m = (rng.random(t) < 0.8).astype(np.float32)
+    real = m > 0
+    uniq, cnt = np.unique(d[real].astype(np.int64) * n_words + w[real],
+                          return_counts=True)
+    monkeypatch.setattr(lda_svi, "_TOKEN_RUN", 64)
+    assert len(uniq) > 64 and len(uniq) < real.sum()
+
+    p_doc, p_word, p_weight, n_pairs = lda_svi._unique_pairs(
+        jnp.asarray(d), jnp.asarray(w), jnp.asarray(m))
+    n = int(n_pairs)
+    assert n == len(uniq)
+    key = np.asarray(p_doc).astype(np.int64) * n_words + np.asarray(p_word)
+    order = np.argsort(key[:n])
+    np.testing.assert_array_equal(key[:n][order], uniq)
+    np.testing.assert_array_equal(np.asarray(p_weight)[:n][order], cnt)
+    assert (np.asarray(p_weight)[n:] == 0).all()
+    assert p_doc.shape == p_word.shape == p_weight.shape == (t,)
+    assert (np.asarray(p_doc) >= 0).all() and (np.asarray(p_doc) < cap).all()
+    assert (np.asarray(p_word) >= 0).all() and \
+        (np.asarray(p_word) < n_words).all()
+    assert int(lda_svi._runs_holding(n_pairs, t)) == -(-n // 64)
+
+
+def test_store_step_stats_count_tokens_and_pairs():
+    """`stats[2]` is the active set's TOKENS (the sum of its pairs'
+    weights), `stats[4]` its pairs, `stats[3]` the batch's pairs; the
+    batch's pairs handed over as weighted rows read the same."""
+    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-6, svi_local_iters=30,
+                    svi_warm_iters=1, seed=3)
+    model = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100)
+    state = model.init()
+    store0 = np.full((_PAIR_CAP, cfg.n_topics), cfg.alpha + 1.0, np.float32)
+    d, w, m = _pair_batch("one_document_half")
+    real = m > 0
+    uniq, cnt = np.unique(d[real].astype(np.int64) * _PAIR_V + w[real],
+                          return_counts=True)
+
+    stats = np.asarray(_store_step(cfg, state, store0, d, w, m)[4])
+    assert stats.shape == (5,)
+    # One cold pass leaves every touched document moving.
+    assert stats[0] == 1 and stats[1] > 0
+    assert stats[2] == real.sum() == 200
+    assert stats[3] == stats[4] == len(uniq) < 200
+
+    pad = _PAIR_T - len(uniq)
+    weighted = np.asarray(_store_step(
+        cfg, state, store0,
+        np.concatenate([(uniq // _PAIR_V).astype(np.int32),
+                        np.full(pad, _PAIR_CAP - 1, np.int32)]),
+        np.concatenate([(uniq % _PAIR_V).astype(np.int32),
+                        np.zeros(pad, np.int32)]),
+        np.concatenate([cnt.astype(np.float32),
+                        np.zeros(pad, np.float32)]))[4])
+    np.testing.assert_array_equal(weighted, stats)
+
+    # Some documents at rest: the active set's tokens and pairs are
+    # theirs alone, and still tokens and pairs.
+    cfg2 = LDAConfig(n_topics=4, svi_meanchange_tol=5e-2, svi_local_iters=30,
+                     svi_warm_iters=3, seed=3)
+    some = np.asarray(_store_step(cfg2, state, store0, d, w, m)[4])
+    assert 0 < some[4] < some[3] == len(uniq)
+    assert some[4] < some[2] < 200
+
+
+def test_store_step_dedupe_has_its_own_scope_inside_the_estep():
+    """The dedupe's ops are named `onix.svi.estep.pairs` inside
+    `onix.svi.estep`: the benchmark's readers match a scope by prefix,
+    so the E-step's metrics go on charging it, and a trace shows it
+    alone (an op is booked to its innermost scope)."""
+    import functools
+
+    import jax
+
+    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3)
+    state = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100).init()
+    text = jax.jit(functools.partial(_store_step, cfg)).lower(
+        state, np.ones((_PAIR_CAP, 4), np.float32),
+        *_pair_batch("one_document_half")).compile().as_text()
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    inside = [ln for ln in sorts
+              if "onix.svi.estep/onix.svi.estep.pairs/" in ln]
+    assert len(inside) >= 2                 # the dedupe's two
+    assert len(sorts) > len(inside)         # the compaction's is not in it
+    assert all("onix.svi.estep" in ln for ln in sorts)
