@@ -603,14 +603,19 @@ class SVILda:
         # default, unchanged) or the SCVB0 collapsed minibatch arm
         # (svi_step docstring). Static — one compiled program per form.
         estep = config.stream_estep
-        self._step = jax.jit(functools.partial(
+        step = functools.partial(
             svi_step,
             alpha=config.alpha, eta=config.eta,
             tau0=config.svi_tau0, kappa=config.svi_kappa,
             local_iters=config.svi_local_iters,
             meanchange_tol=config.svi_meanchange_tol,
             warm_iters=warm, estep_form=estep,
-        ), static_argnames=("batch_docs",))
+        )
+        # JAX names the compiled program after the function: a partial
+        # has no name, and a trace or a `jit.compile` span would read
+        # `<unknown>`.
+        step.__name__ = svi_step.__name__
+        self._step = jax.jit(step, static_argnames=("batch_docs",))
 
     def init(self) -> SVIState:
         return init_state(self.n_vocab, self.config.n_topics, self.config.seed)

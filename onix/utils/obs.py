@@ -44,6 +44,7 @@ COUNTER_NAMESPACES: dict[str, str] = {
     "host": "multi-host fit fabric events (heartbeats, death detection, shard quarantine, restart/rebalance; parallel/hostfabric.py)",
     "feedback": "analyst feedback loop events (rescored events, skipped nudges)",
     "ingest": "watcher/mpingest retry + quarantine events",
+    "jit": "programs JAX compiled or loaded from its persistent cache, as jax.monitoring reports them: compiles, cache_misses, compile_us (utils/telemetry.py watch_compiles; the jit.compile span beside them)",
     "resilience": "RetryPolicy/Deadline events (utils/resilience.py)",
     "salvage": "salvage-mode decode skip tallies, per format",
     "scale": "scale-runner resume/discard events (pipelines/scale.py)",
@@ -245,8 +246,11 @@ def enable_compile_cache() -> None:
     and a threshold near their compile time makes a warm process's
     compile count — and its cache writes — vary run to run. Safe to
     call repeatedly; the one place in the tree that sets the
-    directory."""
+    directory. From here on every compile is a `jit.compile` span
+    whose `cache` attribute says whether this cache answered
+    (`telemetry.watch_compiles`)."""
     import jax
+    _telemetry.watch_compiles()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         path = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
         path.mkdir(parents=True, exist_ok=True)
@@ -270,6 +274,7 @@ def device_scope(name: str):
     where only a line number moved; file names enter the key relative
     to the checkout, so a checkout that moves still hits."""
     import jax
+    _telemetry.watch_compiles()
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if not jax.config.jax_hlo_source_file_canonicalization_regex:
         root = pathlib.Path(__file__).resolve().parents[2]

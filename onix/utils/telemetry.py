@@ -11,14 +11,20 @@ the live layer, four pieces sharing one discipline (near-zero cost when
 off, no device-program changes ever — telemetry off is asserted
 bit-identical in tier-1, tests/test_telemetry.py):
 
-* **Spans** (`Tracer`) — monotonic-clock spans carrying a `trace_id`
+* **Spans** (`Tracer`) — monotonic-clock spans (each keeps its open
+  time `t0` on `time.monotonic()`, the clock the benchmark's harness
+  and drivers stamp their windows with) carrying a `trace_id`
   propagated through `contextvars` end-to-end: HTTP `X-Request-Id` on
   `/score` → `BankService.submit` → admission queue wait → bank wave
   dispatch; campaign stages and streaming batches get per-item trace
   ids. The hot path is LOCK-FREE: a disabled or sampled-out span takes
   no lock and allocates nothing beyond the context manager; a recorded
-  span-close pays one ring append (GIL-atomic `deque.append`) plus the
-  histogram observe. Spans FEED `OccupancyClock` accounting when given
+  span-close pays two appends (GIL-atomic `deque.append`: the span
+  store that `Tracer.spans()` reads, `SPAN_STORE` records, and the
+  flight ring) plus the histogram observe. Every program JAX compiles
+  is a span too (`jit.compile`, from `jax.monitoring`'s events:
+  `watch_compiles`), a child of whatever span asked for it. Spans
+  FEED `OccupancyClock` accounting when given
   a clock (`span(..., clock=, clock_name=)` enters `clock.busy`
   unconditionally — occupancy numbers never depend on telemetry being
   on) instead of duplicating it. Every recorded span is also written
@@ -46,7 +52,9 @@ bit-identical in tier-1, tests/test_telemetry.py):
   `GET /metrics` on `onix serve` (oa/serve.py) is the live endpoint.
 
 * **Flight recorder** (`FlightRecorder`) — a bounded ring of recent
-  span-close / counter-delta / fault events (counter deltas arrive via
+  span-close / counter-delta / fault events, and beside it the store
+  of closed spans, which counter deltas cannot push out (counter
+  deltas arrive via
   the observer hook this module installs on `obs.counters` at import).
   `dump(reason)` writes the ring + a full counter snapshot to a JSON
   artifact; the wired triggers are: any fault-plan site firing
@@ -118,6 +126,7 @@ SPAN_REGISTRY: dict[str, str] = {
     "fleet.refit": "fleet supervisor: the day's fused fleet refit — stacked warm/cold class dispatches plus the drift-gated cold second pass",
     "host.fit": "hostfabric coordinator: one multi-host fit end-to-end (spawn, monitor, deaths + restarts, result assembly)",
     "host.superstep": "hostfabric worker: one fused superstep segment dispatch, collective deadline + retry wrapper included",
+    "jit.compile": "telemetry.watch_compiles: one program JAX compiled or loaded from its persistent cache, closed as its backend compile ends (jax.monitoring); attributes program, trace_s, lower_s, backend_s, cache (hit, miss, off); a child of the span that asked for the program",
     "run.fit": "pipelines/run.py: the day's model fit, whichever engine",
     "run.score": "pipelines/run.py: scoring and selection of the day's events",
     "scan.checkpoint": "scale._stream_score: one chunk's progress checkpoint (_save_progress)",
@@ -316,24 +325,41 @@ histograms = HistogramRegistry()
 # ---------------------------------------------------------------------------
 
 
+#: Closed spans kept for `Tracer.spans()`, apart from the ring: a
+#: benchmark run records 54 to 390 of them, 15 to 29 of those compiles
+#: (PERF.md section 3 has the count cell by cell); a day of `onix
+#: serve` overwrites the oldest. Some 7 MB when full.
+SPAN_STORE = 16384
+
+
 class FlightRecorder:
     """Bounded ring of recent telemetry events (span closes, counter
-    deltas, fault firings). `record` is lock-free — `deque.append` with
+    deltas, fault firings), and the store of closed spans beside it.
+    `record` is lock-free — `deque.append` with
     a maxlen is GIL-atomic, and losing strict ordering between racing
     threads is acceptable for a postmortem buffer (each event carries
     its own monotonic stamp). `dump` snapshots the ring plus a full
     counter snapshot into a JSON artifact; dumps are capped per process
     (`max_dumps`) so a fault storm cannot fill a disk, and are counted
     either way (`telemetry.recorder_dumps` /
-    `telemetry.recorder_dump_skipped` / `..._unrouted`)."""
+    `telemetry.recorder_dump_skipped` / `..._unrouted`).
 
-    #: Dump bookkeeping is the only locked state; the ring itself is
-    #: deliberately lock-free (see class docstring).
+    The ring (`capacity` events) is what a dump writes. The span store
+    (`SPAN_STORE` records, whatever the ring's size) is what
+    `Tracer.spans()` reads: a span leaves it only when `SPAN_STORE`
+    later spans have closed, never because counters moved, and
+    `telemetry.spans_recorded` less the store's length says how many
+    have left."""
+
+    #: Dump bookkeeping is the only locked state; the ring and the span
+    #: store are deliberately lock-free (see class docstring).
     GUARDED_BY = {"_dumps": "_dump_lock"}
 
     def __init__(self, capacity: int = 1024, out_dir=None,
                  max_dumps: int = 32):
         self._ring: collections.deque = collections.deque(maxlen=capacity)
+        self._spans: collections.deque = collections.deque(
+            maxlen=SPAN_STORE)
         self.out_dir = pathlib.Path(out_dir) if out_dir else None
         self.max_dumps = max_dumps
         self._dump_lock = threading.Lock()
@@ -351,11 +377,24 @@ class FlightRecorder:
                            "t": round(time.time(), 3),
                            "kind": kind, **fields})
 
+    def record_span(self, rec: "SpanRecord") -> None:
+        """A closed span into the store and, as a `span` event with its
+        open time `t0`, into the ring."""
+        self._spans.append(rec)
+        self.record("span", name=rec.name, trace_id=rec.trace_id,
+                    span_id=rec.span_id, parent_id=rec.parent_id,
+                    t0=round(rec.t0, 6), dur_s=round(rec.dur_s, 6),
+                    error=rec.error, **rec.attrs)
+
     def events(self) -> list[dict]:
         return list(self._ring)
 
+    def spans(self) -> list["SpanRecord"]:
+        return list(self._spans)
+
     def clear(self) -> None:
         self._ring.clear()
+        self._spans.clear()
         with self._dump_lock:
             self._dumps = 0
 
@@ -414,12 +453,18 @@ class FlightRecorder:
 
 @dataclasses.dataclass
 class SpanRecord:
-    """One closed span (what the ring and `Tracer.spans()` hold)."""
+    """One closed span (what the span store and `Tracer.spans()` hold).
+
+    `t0` is the open time on `time.monotonic()`, the clock
+    `benchmark/harness.py` and its drivers stamp `t_start` and their
+    windows with, so a reader can tell a span of set-up from one of the
+    window; the span closed at `t0 + dur_s`. Durations are taken on
+    `time.perf_counter()` for its resolution."""
     name: str
     trace_id: str
     span_id: int
     parent_id: int | None
-    t0: float               # perf_counter at open
+    t0: float               # time.monotonic() at open
     dur_s: float
     error: str | None = None
     attrs: dict = dataclasses.field(default_factory=dict)
@@ -462,7 +507,114 @@ def _annotation(name: str, **meta):
     jax = sys.modules.get("jax")
     if jax is None:
         return contextlib.nullcontext()
+    if not _watching_compiles:
+        watch_compiles()
     return jax.profiler.TraceAnnotation("onix." + name, **meta)
+
+
+# ---------------------------------------------------------------------------
+# Compiles: one `jit.compile` span a compiled program.
+# ---------------------------------------------------------------------------
+
+#: `jax.monitoring` duration events of one compilation, in the order
+#: they come on the compiling thread (read on jax 0.9.0): the trace of
+#: the function and, before it, of every function it calls
+#: (`fun_name` "my_prog", "multiply", ...), the lowering
+#: (`fun_name` "jit(my_prog)"), then - after `cache_hits` or
+#: `cache_misses` where a persistent cache is configured, neither
+#: where none is - the backend compile, which on a hit is the load.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+
+_watching_compiles = False
+_watch_lock = threading.Lock()
+
+
+class _Compiling(threading.local):
+    """What this thread's compilation has reported so far."""
+
+    def __init__(self):
+        self.trace_s: dict[str, float] = {}
+        self.lowered: tuple[str, float] = ("", 0.0)
+        self.cache = "off"
+
+
+_compiling = _Compiling()
+
+
+def program_name(fun_name: str) -> str:
+    """`jit(my_prog)` as `my_prog`: the name the function has in the
+    source, which is also what its trace event carries."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name or "unknown"
+
+
+def _on_compile_duration(event: str, duration: float, fun_name: str = "",
+                         **_) -> None:
+    if not TRACER.enabled:
+        return
+    st = _compiling
+    if event == _TRACE_EVENT:
+        st.trace_s[fun_name] = duration
+    elif event == _LOWER_EVENT:
+        st.lowered, st.cache = (fun_name, duration), "off"
+    elif event == _BACKEND_EVENT:
+        program = program_name(fun_name)
+        # The trace events come for the inner functions too: the
+        # program's own is the last one under its name, not their sum.
+        trace_s = st.trace_s.get(program, 0.0)
+        lower_s = st.lowered[1] if st.lowered[0] == fun_name else 0.0
+        cache = st.cache
+        st.trace_s, st.lowered, st.cache = {}, ("", 0.0), "off"
+        dur_s = trace_s + lower_s + duration
+        counters.inc("jit.compiles")
+        counters.inc("jit.compile_us", int(dur_s * 1e6))
+        if cache == "miss":
+            counters.inc("jit.cache_misses")
+        TRACER.observe("jit.compile", dur_s, program=program,
+                       trace_s=trace_s, lower_s=lower_s,
+                       backend_s=duration, cache=cache)
+
+
+def _on_compile_event(event: str, **_) -> None:
+    verdict = _CACHE_EVENTS.get(event)
+    if verdict is not None and TRACER.enabled:
+        _compiling.cache = verdict
+
+
+def watch_compiles() -> None:
+    """Listen to `jax.monitoring` from now on, once a process: every
+    program JAX compiles (or loads from its persistent cache) becomes a
+    closed span `jit.compile` when its backend compile ends, a child of
+    the span open on the compiling thread, with attributes `program`
+    (`program_name`), `trace_s`, `lower_s`, `backend_s` (`dur_s` is the
+    three together) and `cache` ("hit", "miss", or "off" where no
+    persistent cache is configured); and counts under `jit.compiles`,
+    `jit.cache_misses`, `jit.compile_us`. A listener cannot be taken
+    off again, so the callbacks themselves honour `TRACER.enabled` (and
+    `observe` the sampling): with telemetry off nothing is recorded.
+
+    Does nothing in a process that has not imported jax - this module
+    is not what imports it. Called where jax is in hand:
+    `obs.enable_compile_cache`, `obs.device_scope`, and the first
+    recorded span after jax's import; programs compiled before any of
+    the three are not seen."""
+    global _watching_compiles
+    if _watching_compiles or "jax" not in sys.modules:
+        return
+    import importlib
+    monitoring = importlib.import_module("jax.monitoring")
+    with _watch_lock:
+        if _watching_compiles:
+            return
+        monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        monitoring.register_event_listener(_on_compile_event)
+        _watching_compiles = True
 
 
 class Tracer:
@@ -542,8 +694,9 @@ class Tracer:
         parent_id = _PARENT.get()       # the span current BEFORE this one
         parent_tok = _PARENT.set(span_id)
         rec = SpanRecord(name=name, trace_id=ctx.trace_id, span_id=span_id,
-                         parent_id=parent_id, t0=time.perf_counter(),
+                         parent_id=parent_id, t0=time.monotonic(),
                          dur_s=0.0, attrs=attrs)
+        opened = time.perf_counter()
         err: str | None = None
         try:
             with _annotation(name):
@@ -553,7 +706,7 @@ class Tracer:
             raise
         finally:
             _PARENT.reset(parent_tok)
-            rec.dur_s = time.perf_counter() - rec.t0
+            rec.dur_s = time.perf_counter() - opened
             rec.error = err
             self._close(rec)
             if clock_cm is not None:
@@ -564,9 +717,14 @@ class Tracer:
     def observe(self, name: str, dur_s: float, **attrs) -> None:
         """Synthesize a closed span of known duration (a wall measured
         inline, e.g. the admission queue wait) — same ring + histogram
-        path as `span`, without restructuring the measured code."""
+        path as `span`, without restructuring the measured code. The
+        span ends now and so opened `dur_s` ago; outside any trace it
+        is a trace of its own, as a root `span` is."""
         ctx = _TRACE.get()
-        if ctx is None or not ctx.sampled:
+        if ctx is None:
+            tid = new_trace_id()
+            ctx = _TraceCtx(tid, self._sampled(tid))
+        if not ctx.sampled:
             return
         # The wall was measured before this call, so the trace gets a
         # mark at the close that carries the duration.
@@ -574,35 +732,21 @@ class Tracer:
             pass
         self._close(SpanRecord(
             name=name, trace_id=ctx.trace_id, span_id=next(_span_seq),
-            parent_id=_PARENT.get(None), t0=time.perf_counter() - dur_s,
+            parent_id=_PARENT.get(None), t0=time.monotonic() - dur_s,
             dur_s=dur_s, attrs=attrs))
 
     def _close(self, rec: SpanRecord) -> None:
-        RECORDER.record("span", name=rec.name, trace_id=rec.trace_id,
-                        span_id=rec.span_id, parent_id=rec.parent_id,
-                        dur_s=round(rec.dur_s, 6), error=rec.error,
-                        **rec.attrs)
+        RECORDER.record_span(rec)
         histograms.observe(f"span.{rec.name}", rec.dur_s)
         counters.inc("telemetry.spans_recorded")
 
     def spans(self, trace_id: str | None = None) -> list[SpanRecord]:
-        """Recently closed spans (from the flight ring), optionally for
-        one trace — what the end-to-end propagation tests assert on."""
-        out = []
-        for ev in RECORDER.events():
-            if ev.get("kind") != "span":
-                continue
-            if trace_id is not None and ev.get("trace_id") != trace_id:
-                continue
-            out.append(SpanRecord(
-                name=ev["name"], trace_id=ev["trace_id"],
-                span_id=ev["span_id"], parent_id=ev.get("parent_id"),
-                t0=0.0, dur_s=ev["dur_s"], error=ev.get("error"),
-                attrs={k: v for k, v in ev.items()
-                       if k not in ("mono", "t", "kind", "name", "trace_id",
-                                    "span_id", "parent_id", "dur_s",
-                                    "error")}))
-        return out
+        """Closed spans, oldest first (the recorder's span store: the
+        last `SPAN_STORE` of them), optionally for one trace. Where
+        `telemetry.spans_recorded` has passed the length of what this
+        returns, the store has dropped its oldest."""
+        return [s for s in RECORDER.spans()
+                if trace_id is None or s.trace_id == trace_id]
 
 
 #: Process-global singletons. `apply_config` (or `configure`) retunes

@@ -7,6 +7,7 @@ with per-program dispatch counts unchanged."""
 import http.client
 import json
 import math
+import time
 
 import numpy as np
 import pytest
@@ -253,7 +254,13 @@ def test_score_endpoint_propagates_x_request_id(tmp_path):
         assert r.status == 200 and out["ok"]
         assert out["trace_id"] == "req-abc-123"
         assert r.headers["X-Request-Id"] == "req-abc-123"
-        spans = {s.name for s in telemetry.TRACER.spans("req-abc-123")}
+        # `serve.request` closes when the handler returns, which is
+        # after the client has its response: give the thread a moment.
+        for _ in range(200):
+            spans = {s.name for s in telemetry.TRACER.spans("req-abc-123")}
+            if "serve.request" in spans:
+                break
+            time.sleep(0.01)
         # End-to-end: HTTP handler -> admission -> scoring -> wave.
         assert {"serve.request", "serve.submit", "serve.queue_wait",
                 "serve.score", "bank.score_wave"} <= spans
@@ -469,3 +476,174 @@ def test_snapshot_shape_and_zeros_included():
     assert "span.serve.submit" in full["histograms"]
     assert "buckets" in full["histograms"]["span.serve.submit"]
     assert full["counters"]["telemetry.spans_recorded"] == 1
+
+
+# -- a span's place in time, and the span store ----------------------------
+
+def test_spans_keep_their_place_in_time():
+    """`t0` is the open time on `time.monotonic()`: inside the test's
+    own bracket, children inside their parents, an observed wall
+    opened `dur_s` before it was reported."""
+    before = time.monotonic()
+    with telemetry.TRACER.span("serve.submit"):
+        with telemetry.TRACER.span("serve.score"):
+            time.sleep(0.002)
+        reported = time.monotonic()
+        telemetry.TRACER.observe("serve.queue_wait", 0.001)
+    after = time.monotonic()
+    spans = {s.name: s for s in telemetry.TRACER.spans()}
+    for s in spans.values():
+        assert before <= s.t0 <= s.t0 + s.dur_s <= after + 1e-6, s
+    outer, inner, wait = (spans[n] for n in (
+        "serve.submit", "serve.score", "serve.queue_wait"))
+    for child in (inner, wait):
+        assert child.parent_id == outer.span_id
+        assert outer.t0 <= child.t0
+        assert child.t0 + child.dur_s <= outer.t0 + outer.dur_s + 1e-6
+    assert inner.dur_s >= 0.002
+    assert wait.t0 == pytest.approx(reported - 0.001, abs=5e-4)
+    # The ring's span event carries the open time too (the dumps).
+    events = [e for e in telemetry.RECORDER.events() if e["kind"] == "span"]
+    assert [e["t0"] for e in events] == [
+        round(s.t0, 6) for s in telemetry.TRACER.spans()]
+
+
+def test_span_store_outlives_counter_deltas_and_says_what_it_dropped(
+        monkeypatch):
+    with telemetry.TRACER.span("fit.prepare", tokens=7):
+        pass
+    for _ in range(2000):               # the ring holds 1024 events
+        counters.inc("stream.batches")
+    assert not [e for e in telemetry.RECORDER.events()
+                if e["kind"] == "span"]
+    (kept,) = telemetry.TRACER.spans()
+    assert kept.name == "fit.prepare" and kept.attrs == {"tokens": 7}
+    assert telemetry.RECORDER._spans.maxlen == telemetry.SPAN_STORE
+    # Past its own bound the store drops its oldest, and the counter
+    # beside it says so (what the benchmark's span readers check).
+    import collections
+    monkeypatch.setattr(telemetry.RECORDER, "_spans",
+                        collections.deque(maxlen=3))
+    for i in range(5):
+        telemetry.TRACER.observe("fit.notify", 0.1, sweep=i)
+    assert [s.attrs["sweep"] for s in telemetry.TRACER.spans()] == [2, 3, 4]
+    assert counters.get("telemetry.spans_recorded") == 6
+    # The ring's size is the ring's alone.
+    telemetry.RECORDER.reconfigure(capacity=16)
+    assert telemetry.RECORDER._spans.maxlen == 3
+    telemetry.RECORDER.reconfigure(capacity=1024)
+
+
+# -- every compile a span ---------------------------------------------------
+
+@pytest.fixture
+def own_compile_cache(tmp_path):
+    """JAX's persistent compile cache in a directory of the test's own,
+    every program cached; put back as it was afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    kept = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path / "jax_cache"), 0.0, 0)):
+        jax.config.update(k, v)
+    cc.reset_cache()
+    yield
+    for k, v in kept.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def _fresh_program():
+    """A jitted function no cache in memory knows: a new function
+    object every call, the same program (and source lines) every
+    time."""
+    import jax
+    import jax.numpy as jnp
+    telemetry.watch_compiles()          # whatever ran before this test
+
+    @jax.jit
+    def probe_prog(x):
+        return jnp.sort(x * 3.0)[::2].sum()
+    return probe_prog, jnp.arange(24.0)
+
+
+def _compiles(program="probe_prog"):
+    return [s for s in telemetry.TRACER.spans()
+            if s.name == "jit.compile" and s.attrs["program"] == program]
+
+
+def test_compile_is_a_child_span_with_its_program_and_the_caches_verdict(
+        own_compile_cache):
+    """First to an empty directory, then - the same program from the
+    same lines, to a process that has forgotten it - answered by the
+    directory. (One loop, because the source lines of the callers are
+    part of the cache's key once `obs.device_scope` has run.)"""
+    import jax
+    for want in ("miss", "hit"):
+        jax.clear_caches()
+        fn, x = _fresh_program()
+        with telemetry.TRACER.span("fit.init_state") as asked:
+            fn(x).block_until_ready()
+        span = _compiles()[-1]
+        assert span.parent_id == asked.span_id
+        assert span.trace_id == asked.trace_id
+        a = span.attrs
+        assert a["cache"] == want and a["backend_s"] > 0
+        assert a["trace_s"] > 0 and a["lower_s"] > 0
+        assert span.dur_s == pytest.approx(
+            a["trace_s"] + a["lower_s"] + a["backend_s"])
+        assert asked.t0 <= span.t0 + span.dur_s <= asked.t0 + asked.dur_s
+        fn(x).block_until_ready()           # compiled: nothing new
+    assert len(_compiles()) == 2
+    n = counters.get("jit.compiles")
+    assert n == len([s for s in telemetry.TRACER.spans()
+                     if s.name == "jit.compile"])
+    assert 1 <= counters.get("jit.cache_misses") < n
+    assert counters.get("jit.compile_us") > 0
+    assert telemetry.histograms.get("span.jit.compile").n == n
+    fams = telemetry.parse_prometheus_text(
+        telemetry.render_prometheus(counters.snapshot()))
+    assert fams["onix_jit_compiles"]["samples"][0][2] == n
+    assert "onix_span_jit_compile_seconds" in fams
+
+
+def test_compile_outside_any_trace_is_kept():
+    fn, x = _fresh_program()
+    fn(x).block_until_ready()
+    (span,) = _compiles()
+    assert span.parent_id is None and span.trace_id
+    assert span.attrs["cache"] in ("off", "hit", "miss")
+
+
+@pytest.mark.parametrize("how", ["env", "enabled", "sample"])
+def test_compiles_are_not_recorded_with_telemetry_off(how, monkeypatch):
+    fn, x = _fresh_program()
+    fn(x).block_until_ready()           # the listeners are on
+    assert _compiles()
+    if how == "env":
+        monkeypatch.setenv("ONIX_TELEMETRY", "0")
+        telemetry.configure(enabled=True)
+        assert not telemetry.TRACER.enabled
+    elif how == "enabled":
+        telemetry.configure(enabled=False)
+    else:
+        telemetry.configure(sample=0.0)
+    spans = counters.get("telemetry.spans_recorded")
+    jit = counters.snapshot("jit.")
+    ring = len(telemetry.RECORDER.events())
+    fn, x = _fresh_program()
+    fn(x).block_until_ready()
+    assert counters.get("telemetry.spans_recorded") == spans
+    assert len(_compiles()) == 1
+    if how != "sample":                 # sampled out, still counted
+        assert counters.snapshot("jit.") == jit
+        assert len(telemetry.RECORDER.events()) == ring
+
+
+def test_program_name_is_spelled_one_way():
+    assert telemetry.program_name("jit(superstep)") == "superstep"
+    assert telemetry.program_name("jit(<lambda>)") == "<lambda>"
+    assert telemetry.program_name("pmap(step)") == "pmap(step)"
+    assert telemetry.program_name("") == "unknown"
