@@ -45,6 +45,10 @@ dp×mp, and dcn×dp×mp meshes.
 from __future__ import annotations
 
 import bisect
+import contextvars
+import logging
+import threading
+from concurrent.futures import Future
 from typing import NamedTuple
 
 import jax
@@ -57,7 +61,7 @@ from onix.corpus import Corpus
 from onix.models import lda_gibbs
 from onix.parallel.mesh import MP_AXIS, data_axes_of, make_mesh
 from onix.utils import telemetry
-from onix.utils.obs import device_scope
+from onix.utils.obs import counters, device_scope
 
 
 class ShardedCorpus(NamedTuple):
@@ -80,6 +84,29 @@ class ShardedCorpus(NamedTuple):
     n_vocab_local: int        # Vc = ceil(V / M)
 
 
+class ShardPlan(NamedTuple):
+    """What `shard_corpus` knows of its layout before it deals a token:
+    every number the fit's programs are shaped by (the blocks are
+    `[n_data, n_mp, nb, block]`), and those a checkpoint's fingerprint
+    is made of."""
+
+    n_data: int
+    n_mp: int
+    nb: int
+    block: int
+    n_docs_local: int
+    n_vocab: int
+    n_vocab_local: int
+    n_tokens: int
+
+
+def plan_of(sc: ShardedCorpus, n_tokens: int) -> ShardPlan:
+    """The plan a finished layout was made to."""
+    n_data, n_mp, nb, block = sc.doc_blocks.shape
+    return ShardPlan(n_data, n_mp, nb, block, sc.n_docs_local,
+                     sc.n_vocab, sc.n_vocab_local, n_tokens)
+
+
 # A token of a bucket on its way into the blocks: local doc id and
 # chunk word row as one 8-byte item, so one in-place shuffle deals both.
 _TOKEN_PAIR = np.dtype([("d", np.int32), ("w", np.int32)])
@@ -87,11 +114,14 @@ _TOKEN_PAIR = np.dtype([("d", np.int32), ("w", np.int32)])
 
 def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
                  seed: int = 0, n_mp: int = 1,
-                 n_groups: int = 1) -> ShardedCorpus:
+                 n_groups: int = 1, on_plan=None) -> ShardedCorpus:
     """Partition documents (greedy balance) over data shards and tokens
     over vocabulary chunks; lay out every bucket in blocked form.
     `n_groups` pads the block count to a multiple so the sweep can
-    synchronize counts after every group (cfg.sync_splits)."""
+    synchronize counts after every group (cfg.sync_splits).
+    `on_plan(ShardPlan)` is called once the shapes are known, before
+    the costly half (the takes and the shuffles): what depends on the
+    shapes alone can start there (`ShardedGibbsLDA.fit` compiles)."""
     n_docs = corpus.n_docs
     # Snake round-robin over docs sorted by length (desc): near-optimal
     # load balance, fully vectorized — no per-document Python loop (the
@@ -145,6 +175,10 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
     nb = -(-max_tokens // block)
     nb = -(-nb // n_groups) * n_groups     # sync groups need equal splits
     padded_len = nb * block
+    n_vocab_local = -(-corpus.n_vocab // n_mp)
+    if on_plan is not None:
+        on_plan(ShardPlan(n_data, n_mp, nb, block, d_local, corpus.n_vocab,
+                          n_vocab_local, corpus.n_tokens))
 
     doc_blocks = np.zeros((n_data, n_mp, padded_len), np.int32)
     word_blocks = np.zeros((n_data, n_mp, padded_len), np.int32)
@@ -181,7 +215,7 @@ def shard_corpus(corpus: Corpus, n_data: int, block_size: int,
         doc_map=doc_map,
         n_docs_local=d_local,
         n_vocab=corpus.n_vocab,
-        n_vocab_local=-(-corpus.n_vocab // n_mp),
+        n_vocab_local=n_vocab_local,
     )
 
 
@@ -272,6 +306,108 @@ class ShardedGibbsState(NamedTuple):
     acc_ndk: jax.Array   # float32 [P, C, Dl, K]
     acc_nwk: jax.Array   # float32 [M, C, Vc, K]
     n_acc: jax.Array     # int32 []
+
+
+class ProgramsAhead:
+    """The executables a fit builds ahead of their first call.
+
+    `build(key)` hands back the executable of one program. It is called
+    for every key in turn, in the order the fit will ask for them, on a
+    thread of this object's own, in a copy of the caller's context (so
+    the thread's span, `fit.precompile`, and the `jit.compile` spans
+    under it are the caller's trace's). `call` then runs a program
+    through its executable where that is there and takes the arguments
+    it is given, and through the jitted function otherwise: a program
+    nobody planned, a build that raised (the jitted call meets the
+    fault again and reports it where it always did), arguments of
+    another shape or sharding than planned (an executable refuses them
+    before it runs or donates anything). `fit.precompile.hit` and
+    `.miss` count the two."""
+
+    def __init__(self, keys, build):
+        self._futures = {key: Future() for key in keys}
+        self._taken: dict = {}
+        self._closed = False
+        self._thread = threading.Thread(
+            target=contextvars.copy_context().run,
+            args=(self._build_all, build), name="onix-fit-precompile")
+        self._thread.start()
+
+    def _build_all(self, build) -> None:
+        """The thread: only it settles the futures, and it leaves none
+        unsettled for a caller to wait on."""
+        futures = self._futures
+        try:
+            with telemetry.TRACER.span(
+                    "fit.precompile", count=len(futures),
+                    programs=[str(key) for key in futures]) as span:
+                for key, fut in futures.items():
+                    if self._closed:
+                        break
+                    try:
+                        fut.set_result(build(key))
+                    except Exception as e:
+                        # The thread's boundary: the fit goes on through
+                        # the jitted function, which raises this again,
+                        # on the caller's thread, if it was the
+                        # program's fault.
+                        logging.getLogger(__name__).warning(
+                            "fit.precompile: %s not built ahead: %r", key, e)
+                        if span is not None:
+                            span.attrs.setdefault("failed", []).append(
+                                str(key))
+                        fut.set_exception(e)
+        finally:
+            for fut in futures.values():
+                if not fut.done():
+                    fut.set_exception(RuntimeError("not built ahead"))
+
+    def _take(self, key, program: str):
+        """The executable of `key`, waited for under `fit.compile_wait`
+        the first time it is asked for; None where there is none."""
+        if key not in self._taken:
+            exe, fut = None, self._futures.get(key)
+            if fut is not None:
+                with telemetry.TRACER.span("fit.compile_wait",
+                                           program=program, key=str(key),
+                                           ready=fut.done()):
+                    if fut.exception() is None:
+                        exe = fut.result()
+            self._taken[key] = exe
+        return self._taken[key]
+
+    def call(self, key, jitted, *args, **static):
+        exe = self._take(key, jitted.__name__)
+        if exe is not None:
+            try:
+                out = exe(*args)
+            except (TypeError, ValueError):
+                # Not the arguments it was built for.
+                self._taken[key] = None
+            else:
+                counters.inc("fit.precompile.hit")
+                return out
+        counters.inc("fit.precompile.miss")
+        return jitted(*args, **static)
+
+    def close(self) -> None:
+        """Nothing of it outlives the fit: programs not begun are
+        dropped, the one being compiled is waited for."""
+        self._closed = True
+        self._thread.join()
+
+
+class _FitPlan(NamedTuple):
+    """What `ShardedGibbsLDA.fit` decides once the layout's plan is
+    known (`plan_fit` there)."""
+
+    plan: ShardPlan
+    fingerprint: str
+    checkpoint_dir: object      # <checkpoint_dir>/<fingerprint>, or None
+    saved: object               # the checkpoint to restore, or None
+    start: int
+    segments: list
+    ahead: ProgramsAhead
 
 
 def _local_sweep(z, n_dk, n_wk, n_k, key, docs, words, mask, *,
@@ -913,6 +1049,11 @@ class ShardedGibbsLDA:
             wrapped_superstep,
             static_argnames=("n_steps", "with_initial_ll"))
         self._mp_axis = M
+        # Where a fit's `prepare` reports the layout's plan to
+        # (`shard_corpus`'s `on_plan`); None outside a fit. On the
+        # instance, not an argument of `prepare`: callers put functions
+        # of the corpus alone in its place (the benchmark's drivers).
+        self._on_plan = None
         self._init = jax.jit(
             init_fn, static_argnames=("n_docs_local", "n_vocab_local"),
             donate_argnames=("z",),
@@ -931,13 +1072,24 @@ class ShardedGibbsLDA:
 
     # -- state construction ----------------------------------------------
 
+    def _chain_keys(self, p: int, m: int) -> jax.Array:
+        """Independent per-device/per-chain streams: split, never
+        adjacent raw seeds (seed and seed+1 would otherwise share most
+        streams)."""
+        C = self.config.n_chains
+        return jax.random.split(jax.random.PRNGKey(self.config.seed),
+                                p * m * C).reshape(p, m, C, -1)
+
     def init_state(self, sc: ShardedCorpus,
                    init_phi: np.ndarray | None = None,
-                   device_blocks=None) -> ShardedGibbsState:
+                   device_blocks=None,
+                   ahead: ProgramsAhead | None = None) -> ShardedGibbsState:
         """The chain's first state, drawn and counted on the device
         (`init_fn`): no table crosses the host link, and on the cold
         path no assignment either. `device_blocks` are `sc`'s blocks
-        where the caller has put them already (`device_corpus`)."""
+        where the caller has put them already (`device_corpus`);
+        `ahead` holds `init_fn`'s executable where `fit` has built it
+        ahead."""
         cfg = self.config
         k = cfg.n_topics
         C = cfg.n_chains
@@ -982,15 +1134,13 @@ class ShardedGibbsLDA:
                                 k - 1).astype(np.int32)
             z = put_global(z.reshape(p, m, C, nb, b), self.mesh,
                            specs["z"])
-        # Independent per-device/per-chain streams: split, never adjacent
-        # raw seeds (seed and seed+1 would otherwise share most streams).
-        keys = jax.random.split(jax.random.PRNGKey(cfg.seed),
-                                p * m * C).reshape(p, m, C, -1)
+        keys = put_global(self._chain_keys(p, m), self.mesh, specs["keys"])
         docs, words, mask = device_blocks or self.device_corpus(sc)
-        arrays = self._init(put_global(keys, self.mesh, specs["keys"]),
-                            docs, words, mask, z,
-                            n_docs_local=sc.n_docs_local,
-                            n_vocab_local=sc.n_vocab_local)
+        run = self._init if ahead is None else (
+            lambda *a, **static: ahead.call("init", self._init, *a, **static))
+        arrays = run(keys, docs, words, mask, z,
+                     n_docs_local=sc.n_docs_local,
+                     n_vocab_local=sc.n_vocab_local)
         # n_acc's None spec means "leave uncommitted" single-process; a
         # process-spanning mesh needs every jit input globally placed,
         # so it rides an explicitly replicated P() there.
@@ -1013,7 +1163,68 @@ class ShardedGibbsLDA:
     def prepare(self, corpus: Corpus) -> ShardedCorpus:
         return shard_corpus(corpus, self.n_data, self.config.block_size,
                             self.config.seed, n_mp=self.n_mp,
-                            n_groups=self.config.sync_splits)
+                            n_groups=self.config.sync_splits,
+                            on_plan=self._on_plan)
+
+    # -- programs built ahead ---------------------------------------------
+
+    def _abstract_args(self, plan: ShardPlan, warm: bool):
+        """What `init_fn` and the superstep will be handed, as shapes
+        with their shardings, from the layout's plan alone: the chains'
+        keys, the token blocks as `device_corpus` puts them, the warm
+        start's assignments (`warm`; None cold) and the state as
+        `init_fn` returns it. -> (keys, (docs, words, mask), z, state)"""
+        specs = self._specs()
+        tok_at = NamedSharding(self.mesh, specs["z"])
+        shape = (plan.n_data, plan.n_mp, plan.nb, plan.block)
+        ids = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=tok_at)
+        blocks = (ids, ids,
+                  jax.ShapeDtypeStruct(shape, jnp.float32, sharding=tok_at))
+        keys = jax.eval_shape(
+            lambda: self._chain_keys(plan.n_data, plan.n_mp))
+        keys = jax.ShapeDtypeStruct(
+            keys.shape, keys.dtype,
+            sharding=NamedSharding(self.mesh, specs["keys"]))
+        z = None
+        if warm:
+            z = jax.ShapeDtypeStruct(
+                shape[:2] + (self.config.n_chains,) + shape[2:], jnp.int32,
+                sharding=tok_at)
+        arrays = self._init.eval_shape(
+            keys, *blocks, z, n_docs_local=plan.n_docs_local,
+            n_vocab_local=plan.n_vocab_local)
+        # n_acc as init_state and restore_state leave it.
+        n_acc = jax.ShapeDtypeStruct(
+            (), jnp.int32,
+            sharding=(None if jax.process_count() == 1
+                      else NamedSharding(self.mesh, P())))
+        return keys, blocks, z, ShardedGibbsState(*arrays, n_acc=n_acc)
+
+    def _build_ahead(self, plan: ShardPlan, warm: bool):
+        """`ProgramsAhead`'s `build` for a fit on `plan`: trace, lower
+        and compile `init_fn` (key "init") or the superstep of a key
+        `(n_steps, with_initial_ll)` from the plan's shapes. All three
+        steps of a program on the one thread that asks: `telemetry.
+        watch_compiles` gathers a compile's events by thread, and its
+        `jit.compile` span hangs under the span open on that thread."""
+        args = []
+
+        def build(key):
+            if not args:
+                args.extend(self._abstract_args(plan, warm))
+            keys, blocks, z, state = args
+            if key == "init":
+                lowered = self._init.lower(
+                    keys, *blocks, z, n_docs_local=plan.n_docs_local,
+                    n_vocab_local=plan.n_vocab_local)
+            else:
+                n_steps, with_initial_ll = key
+                lowered = self._superstep.lower(
+                    state, *blocks, jax.ShapeDtypeStruct((), jnp.int32),
+                    n_steps=n_steps, with_initial_ll=with_initial_ll)
+            return lowered.compile()
+
+        return build
 
     def device_corpus(self, sc: ShardedCorpus):
         D = self.data_axes
@@ -1054,7 +1265,16 @@ class ShardedGibbsLDA:
         supervisor's warm refit. A warm chain is a DIFFERENT chain from
         the cold one, so the prior's content digest joins the checkpoint
         fingerprint: a cold resume can never continue a warm run or
-        vice versa, and two different priors never share checkpoints."""
+        vice versa, and two different priors never share checkpoints.
+
+        The programs are built ahead of their first call: as soon as
+        `prepare` reports the layout's plan (`shard_corpus`'s `on_plan`,
+        before the shuffle) the fit decides what it restores and which
+        segments it runs, and a thread of its own compiles `init_fn`
+        and every superstep they name while this one lays the corpus
+        out, puts it and makes the state (`ProgramsAhead`). No switch:
+        where the layout is short the first call waits for its
+        executable as long as it would have compiled it."""
         import os
 
         from onix import checkpoint as ckpt
@@ -1067,22 +1287,6 @@ class ShardedGibbsLDA:
         cfg = self.config
         n_sweeps = cfg.n_sweeps if n_sweeps is None else n_sweeps
         S_step = cfg.superstep or SUPERSTEP_DEFAULT
-        with telemetry.TRACER.span("fit.prepare", tokens=corpus.n_tokens,
-                                   docs=corpus.n_docs) as span:
-            sc = self.prepare(corpus)
-            if span is not None:
-                # The shards' balance: the sweep ends with its fullest.
-                per_shard = bucket_tokens(sc).sum(axis=1)
-                span.attrs.update(
-                    shards=self.n_data,
-                    tokens_max_shard=int(per_shard.max()),
-                    tokens_min_shard=int(per_shard.min()),
-                    pad_slots=int(sc.mask_blocks.size - per_shard.sum()))
-        with telemetry.TRACER.span(
-                "fit.device_corpus",
-                bytes=sum(int(a.nbytes) for a in (
-                    sc.doc_blocks, sc.word_blocks, sc.mask_blocks))):
-            docs, words, mask = self.device_corpus(sc)
         # layout=4: the fused-superstep layout — the jitted carry holds
         # the accumulator state, checkpoints land only at superstep
         # boundaries, and the superstep size joins the identity
@@ -1101,48 +1305,109 @@ class ShardedGibbsLDA:
             hh = hashlib.sha256(repr(a.shape).encode())
             hh.update(a.tobytes())
             warm_extra["warm_init"] = hh.hexdigest()[:16]
-        fp = ckpt.fingerprint(cfg,
-                              sc.doc_map.shape[0] * sc.n_docs_local,
-                              sc.n_vocab, corpus.n_tokens,
-                              extra={"mesh": list(self.mesh.shape.values()),
-                                     "layout": 4,
-                                     **warm_extra,
-                                     # RESOLVED sampler arm: a resume
-                                     # across an arm change is refused
-                                     # (GibbsLDA.fit has the same rule).
-                                     **lda_gibbs.sampler_fingerprint(
-                                         self.sampler_form,
-                                         self.sparse_active,
-                                         cfg.sparse_mh),
-                                     # RESOLVED merge form (r14): τ>0
-                                     # is a different chain, and even
-                                     # the bit-identical τ=0 async arm
-                                     # refuses a cross-form resume by
-                                     # spec; sync contributes nothing
-                                     # so pre-r14 checkpoints resume.
-                                     **lda_gibbs.merge_fingerprint(
-                                         self.merge_form,
-                                         self.merge_tau)},
-                              superstep=S_step)
-        if checkpoint_dir is not None:
-            import pathlib
-            checkpoint_dir = pathlib.Path(checkpoint_dir) / fp
-        start = 0
-        state = None
+
+        def plan_fit(plan: ShardPlan) -> _FitPlan:
+            """Everything the fit decides from the layout's plan alone,
+            and so before a token is dealt: the checkpoint's identity
+            and whether one is restored, the segments, and the programs
+            they will call, which start to compile here."""
+            fp = ckpt.fingerprint(
+                cfg, plan.n_data * plan.n_docs_local, plan.n_vocab,
+                plan.n_tokens,
+                extra={"mesh": list(self.mesh.shape.values()),
+                       "layout": 4,
+                       **warm_extra,
+                       # RESOLVED sampler arm: a resume across an arm
+                       # change is refused (GibbsLDA.fit has the same
+                       # rule).
+                       **lda_gibbs.sampler_fingerprint(
+                           self.sampler_form, self.sparse_active,
+                           cfg.sparse_mh),
+                       # RESOLVED merge form (r14): τ>0 is a different
+                       # chain, and even the bit-identical τ=0 async
+                       # arm refuses a cross-form resume by spec; sync
+                       # contributes nothing so pre-r14 checkpoints
+                       # resume.
+                       **lda_gibbs.merge_fingerprint(
+                           self.merge_form, self.merge_tau)},
+                superstep=S_step)
+            fp_dir = saved = None
+            if checkpoint_dir is not None:
+                import pathlib
+                fp_dir = pathlib.Path(checkpoint_dir) / fp
+                if resume:
+                    saved = ckpt.load_latest(fp_dir)
+                    if (saved is not None
+                            and saved.meta.get("fingerprint") != fp):
+                        saved = None
+            start = 0 if saved is None else saved.sweep + 1
+            segments = plan_segments(
+                start, n_sweeps, S_step,
+                checkpoint_every=(cfg.checkpoint_every
+                                  if fp_dir is not None else 0),
+                fault_sweep=fault_inject_sweep,
+                per_sweep=callback is not None)
+            # The programs in the order the fit asks for them: the
+            # state's where none is restored, then a superstep for every
+            # distinct (n_steps, with_initial_ll) of the segments.
+            keys = dict.fromkeys(
+                (() if saved is not None else ("init",))
+                + tuple((n, i == 0) for i, (_, n) in enumerate(segments)))
+            ahead = ProgramsAhead(
+                keys, self._build_ahead(plan, init_phi is not None))
+            return _FitPlan(plan, fp, fp_dir, saved, start, segments, ahead)
+
+        plans: list[_FitPlan] = []
+        self._on_plan = lambda plan: plans.append(plan_fit(plan))
+        try:
+            with telemetry.TRACER.span("fit.prepare", tokens=corpus.n_tokens,
+                                       docs=corpus.n_docs) as span:
+                sc = self.prepare(corpus)
+                if span is not None:
+                    # The shards' balance: the sweep ends with its fullest.
+                    per_shard = bucket_tokens(sc).sum(axis=1)
+                    span.attrs.update(
+                        shards=self.n_data,
+                        tokens_max_shard=int(per_shard.max()),
+                        tokens_min_shard=int(per_shard.min()),
+                        pad_slots=int(sc.mask_blocks.size - per_shard.sum()))
+            # A `prepare` that is not `shard_corpus`'s own (it reported
+            # no plan, or not this layout's) is planned for now.
+            plan = plan_of(sc, corpus.n_tokens)
+            if not plans or plans[-1].plan != plan:
+                plans.append(plan_fit(plan))
+            return self._fit_planned(corpus, sc, plans[-1], n_sweeps,
+                                     callback, fault_inject_sweep, init_phi)
+        finally:
+            self._on_plan = None
+            for planned in plans:
+                planned.ahead.close()
+
+    def _fit_planned(self, corpus: Corpus, sc: ShardedCorpus,
+                     planned: _FitPlan, n_sweeps: int, callback,
+                     fault_inject_sweep, init_phi) -> dict:
+        """`fit` from the laid-out corpus on: transfer, state, sweeps,
+        estimates."""
+        from onix import checkpoint as ckpt
+        from onix.models.lda_gibbs import run_fit_segments
+
+        cfg = self.config
+        _, fp, checkpoint_dir, saved, start, segments, ahead = planned
+        with telemetry.TRACER.span(
+                "fit.device_corpus",
+                bytes=sum(int(a.nbytes) for a in (
+                    sc.doc_blocks, sc.word_blocks, sc.mask_blocks))):
+            docs, words, mask = self.device_corpus(sc)
         with telemetry.TRACER.span("fit.init_state") as span:
-            if checkpoint_dir is not None and resume:
-                saved = ckpt.load_latest(checkpoint_dir)
-                if (saved is not None
-                        and saved.meta.get("fingerprint") == fp):
-                    state = self.restore_state(saved.arrays)
-                    start = saved.sweep + 1
-            resumed = state is not None
-            if not resumed:
+            resumed = saved is not None
+            if resumed:
+                state = self.restore_state(saved.arrays)
+            else:
                 # The span ends when the device has made the state, not
                 # when the host has asked for it.
                 state = jax.block_until_ready(self.init_state(
                     sc, init_phi=init_phi,
-                    device_blocks=(docs, words, mask)))
+                    device_blocks=(docs, words, mask), ahead=ahead))
             if span is not None:
                 nbytes = sum(int(a.nbytes) for a in state)
                 span.attrs.update(resumed=resumed, bytes=nbytes)
@@ -1157,13 +1422,6 @@ class ShardedGibbsLDA:
                 else:
                     span.attrs.update(draw="device", counts="device",
                                       h2d_bytes=0)
-        from onix.models.lda_gibbs import run_fit_segments
-        segments = plan_segments(
-            start, n_sweeps, S_step,
-            checkpoint_every=(cfg.checkpoint_every
-                              if checkpoint_dir is not None else 0),
-            fault_sweep=fault_inject_sweep,
-            per_sweep=callback is not None)
         # What one sweep's merge moves per chip: every chain's n_wk
         # chunk and n_k (n_dk's rows too where mp shards them).
         merge_bytes = (int(state.n_wk.nbytes) // self.n_mp
@@ -1180,8 +1438,11 @@ class ShardedGibbsLDA:
                                        sampler_form=self.sampler_form)):
             state, ll_history = run_fit_segments(
                 state, start, segments,
-                superstep_fn=lambda st, s0, n, init: self._superstep(
-                    st, docs, words, mask, s0, n_steps=n,
+                # The start sweep as the int32 scalar the executables
+                # built ahead were lowered for.
+                superstep_fn=lambda st, s0, n, init: ahead.call(
+                    (n, init), self._superstep,
+                    st, docs, words, mask, np.int32(s0), n_steps=n,
                     with_initial_ll=init),
                 initial_ll_fn=lambda st: self._ll(st, docs, words, mask),
                 checkpoint_every=cfg.checkpoint_every,

@@ -40,6 +40,7 @@ COUNTER_NAMESPACES: dict[str, str] = {
     "ckpt": "checkpoint/model integrity events (digest mismatches)",
     "daily": "continuous-operation supervisor events (warm/cold refits, drift fallbacks, ledger refusals, poison-day rollbacks; pipelines/daily.py)",
     "faults": "injected chaos-plan firings, as faults.<stage>.<point>",
+    "fit": "the sharded fit's programs built ahead of their first call: precompile.hit (a call served by an executable compiled on the fit's thread) and precompile.miss (a call that went through jax.jit: unplanned, failed to compile, or other arguments; parallel/sharded_gibbs.py ProgramsAhead)",
     "fleet": "fleet-batched refit supervisor events (warm/cold tenant-days, drift cold refits, per-tenant quarantines, nudge applications; pipelines/fleet.py)",
     "host": "multi-host fit fabric events (heartbeats, death detection, shard quarantine, restart/rebalance; parallel/hostfabric.py)",
     "feedback": "analyst feedback loop events (rescored events, skipped nudges)",

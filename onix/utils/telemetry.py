@@ -114,10 +114,12 @@ SPAN_REGISTRY: dict[str, str] = {
     "daily.day": "daily supervisor: one simulated day end-to-end (campaign + model save + ledger write)",
     "daily.refit": "daily supervisor: one datatype's warm/cold refit decision — warm fit, drift check, and any drift-forced cold refit",
     "fit.checkpoint": "run_fit_segments: one checkpoint save at a superstep boundary (state to the host, then to disk)",
+    "fit.compile_wait": "ShardedGibbsLDA.fit (ProgramsAhead): the main thread's first take of a program built ahead, waiting for its compile where that has not ended; attributes program, key (its static arguments), ready (False where it had to wait): the seconds the overlap with the layout did not hide",
     "fit.device_corpus": "ShardedGibbsLDA.fit: the blocked corpus to the device(s) (device_corpus)",
     "fit.estimates": "ShardedGibbsLDA.fit: final counts to the host and theta/phi in global order (estimates)",
     "fit.init_state": "ShardedGibbsLDA.fit: the chain's first state, drawn (cold: on the device; warm: on the host) and counted on the device, or restored from a checkpoint",
     "fit.notify": "run_fit_segments: the caller's per-boundary callback",
+    "fit.precompile": "ShardedGibbsLDA._build_ahead, on the fit's own thread from the moment the layout's plan is known (under fit.prepare in time, and its child): trace, lowering and compile of init_fn and of every superstep the segments will call, in that order; attributes programs, count, failed; the parent of those programs' jit.compile spans",
     "fit.prepare": "ShardedGibbsLDA.fit: host layout of the corpus into shard blocks (prepare)",
     "fit.superstep": "run_fit_segments: the dispatch of one fused superstep program (returns with the device still running)",
     "fit.supersteps": "ShardedGibbsLDA.fit: the whole sweep loop (run_fit_segments) under one span; attributes say what one sweep's cross-chip merge moves (merge_bytes_per_sweep)",
@@ -126,7 +128,7 @@ SPAN_REGISTRY: dict[str, str] = {
     "fleet.refit": "fleet supervisor: the day's fused fleet refit — stacked warm/cold class dispatches plus the drift-gated cold second pass",
     "host.fit": "hostfabric coordinator: one multi-host fit end-to-end (spawn, monitor, deaths + restarts, result assembly)",
     "host.superstep": "hostfabric worker: one fused superstep segment dispatch, collective deadline + retry wrapper included",
-    "jit.compile": "telemetry.watch_compiles: one program JAX compiled or loaded from its persistent cache, closed as its backend compile ends (jax.monitoring); attributes program, trace_s, lower_s, backend_s, cache (hit, miss, off); a child of the span that asked for the program",
+    "jit.compile": "telemetry.watch_compiles: one program JAX compiled or loaded from its persistent cache, closed as its backend compile ends (jax.monitoring); attributes program, trace_s, lower_s, backend_s, cache (hit, miss, off); a child of the span open on the thread that compiled it (fit.precompile for the fit's programs, else the span that asked for the program)",
     "run.fit": "pipelines/run.py: the day's model fit, whichever engine",
     "run.score": "pipelines/run.py: scoring and selection of the day's events",
     "scan.checkpoint": "scale._stream_score: one chunk's progress checkpoint (_save_progress)",
