@@ -24,6 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from onix.config import LDAConfig
+from onix.models import lda_gibbs
+from onix.utils.obs import device_scope
 
 
 class SVIState(NamedTuple):
@@ -206,6 +208,62 @@ def _unique_pairs(doc_ids, word_ids, mask):
     return d, jnp.where(w == big, 0, w), weights, n_pairs
 
 
+# With the words' table handed over, the E-step carries gamma, E[log
+# theta] and the new gamma's accumulator packed G documents a 128-lane
+# row, as the fit carries n_dk (`lda_gibbs.pack_ndk`: document d in lanes
+# (d % G) * K onward of row d // G; G = 4 at K = 20): a [cap, K] float32
+# array is laid out with K padded to 128 lanes on the chip, so every row
+# it gathers and adds to is 84% padding, and the tables are four times
+# the bytes (the chip keeps the packed E[log theta] in fast memory, the
+# [cap, K] one not). Packed once on entry, unpacked once at the end. A
+# pass works on whole 128-lane rows: E[log beta] is laid G times along
+# the lanes, a pair's row of E[log theta] plus its word's row is
+# softmaxed over its document's K lanes (the others masked to -inf, so
+# they come out 0), and that row is what the accumulator adds: no lanes
+# are picked out or put back (on the chip that cost more than the packed
+# gather saved: PERF.md section 6). The form is the fit's, chosen by
+# `lda_gibbs.select_ndk_form` from the backend and K: packed on the chip;
+# one document a row (G = 1, the [cap, K] rows as the rows mode carries
+# its batch's gamma) where K is over 64, and on the CPU, where a
+# 128-lane softmax costs 128 / K times the work of a K-lane one.
+
+
+def _gamma_group(k: int) -> int:
+    """The documents a packed row of the E-step holds (1: unpacked)."""
+    return lda_gibbs.select_ndk_form(backend=jax.default_backend(),
+                                     k_topics=k)[1]
+
+
+def _lane_docs(width: int, k: int):
+    """[1, width]: the document slot of each lane of a packed row
+    (`width // k` and above for the lanes past the last slot)."""
+    return jax.lax.iota(jnp.int32, width)[None, :] // k
+
+
+def _doc_sums(x, k: int, group: int):
+    """Packed rows [R, width] -> [R * group]: each document's sum over
+    its K lanes, in document order."""
+    if group == 1:
+        return x.sum(axis=1)
+    slot = _lane_docs(x.shape[1], k)
+    return jnp.stack([jnp.where(slot == j, x, 0).sum(axis=1)
+                      for j in range(group)], axis=1).reshape(-1)
+
+
+def _doc_lanes(v, k: int, group: int, width: int):
+    """`_doc_sums`' reverse for a value a document, [R * group] ->
+    [R, width]: each in its document's K lanes (a [R, 1] column where a
+    row is one document)."""
+    if group == 1:
+        return v[:, None]
+    v = v.reshape(-1, group)
+    slot = _lane_docs(width, k)
+    out = jnp.zeros((v.shape[0], width), v.dtype)
+    for j in range(group):
+        out = jnp.where(slot == j, v[:, j:j + 1], out)
+    return out
+
+
 def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
                 local_iters: int, meanchange_tol: float,
                 warm_iters: int, estep_form: str = "svi",
@@ -261,7 +319,28 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
       Extended iterations therefore cost O(T_active · K), not
       O(T · K) — the r6 loop charged every token until the SLOWEST
       doc converged.
+
+    In ids mode gamma is carried packed `_gamma_group(K)` documents a
+    128-lane row where that is more than one (the comment above
+    `_gamma_group`): the same sums, equal to float32 rounding; the rows
+    mode carries its [Bd, K] rows.
     """
+    n_docs, k = gamma0.shape
+    group = 1 if elog_beta is None else _gamma_group(k)
+    width = k if group == 1 else lda_gibbs._NDK_LANES
+    elog_beta_lanes = elog_beta
+    if group > 1:
+        with device_scope("onix.svi.estep.pack"):
+            # The tail documents and the lanes past the last one hold
+            # alpha, as a row no pair touches does.
+            gamma0 = jnp.where(
+                _lane_docs(width, k) < group, lda_gibbs.pack_ndk(jnp.pad(
+                    gamma0, ((0, -n_docs % group), (0, 0)),
+                    constant_values=alpha), group), alpha)
+            elog_beta_lanes = jnp.pad(
+                jnp.tile(elog_beta, (1, group)),
+                ((0, 0), (0, width - group * k)))
+
     def e_step(gamma, d_ids, eb_t, m, n_runs=None):
         if estep_form == "scvb0":
             # Collapsed zeroth-order responsibilities: gamma holds
@@ -269,14 +348,25 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             # rows, so softmax(log gamma + log phi_hat) is exactly the
             # normalized (N_theta + alpha) · phi_hat of SCVB0.
             elog_theta = jnp.log(gamma)                  # [Bd,K]
-        else:
+        elif group == 1:
             elog_theta = _e_log_dirichlet(gamma, axis=1)  # [Bd,K]
+        else:
+            digamma = jax.scipy.special.digamma
+            elog_theta = digamma(gamma) - _doc_lanes(
+                digamma(_doc_sums(gamma, k, group)), k, group, width)
 
         def add(acc, d, e, mm):
-            logp = elog_theta[d] + (e if elog_beta is None
-                                    else elog_beta[e])   # [T,K]
+            if elog_beta is None:
+                logp = elog_theta[d] + e                 # [T,K]
+                phi = jax.nn.softmax(logp, axis=-1) * mm[:, None]
+                return acc.at[d].add(phi), None
+            row = d // group
+            logp = elog_theta[row] + elog_beta_lanes[e]  # [T,width]
+            if group > 1:
+                logp = jnp.where(_lane_docs(width, k) == (d % group)[:, None],
+                                 logp, -jnp.inf)
             phi = jax.nn.softmax(logp, axis=-1) * mm[:, None]
-            return acc.at[d].add(phi), None
+            return acc.at[row].add(phi), None
 
         zero = jnp.zeros_like(gamma)
         if elog_beta is None:
@@ -284,11 +374,20 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         return alpha + _in_token_runs(add, zero, (d_ids, eb_t, m),
                                       n_runs)[0]
 
+    def change(g2, g):
+        """Each document's mean |change| over its K topics."""
+        if group == 1:
+            return jnp.abs(g2 - g).mean(axis=1)
+        return _doc_sums(jnp.abs(g2 - g), k, group) / k
+
     t = doc_ids.shape[0]
     # The runs every pass over the whole axis stops after (ids mode).
     all_runs = _runs_holding(n_rows, t)
 
     def out(gamma, full, ext=0, n_act=0, act_rows=0):
+        if group > 1:
+            with device_scope("onix.svi.estep.pack"):
+                gamma = lda_gibbs.unpack_ndk(gamma, n_docs, k, group)
         if not with_stats:
             return gamma
         stats = (jnp.int32(full), jnp.int32(ext), jnp.int32(n_act))
@@ -314,7 +413,7 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             # still-moving first-seen docs the rarity detector needs
             # converged. Padding rows collapse to alpha after one
             # iteration and stop contributing.
-            return g2, jnp.abs(g2 - gamma).mean(axis=1).max(), i + 1
+            return g2, change(g2, gamma).max(), i + 1
 
         def cond(carry):
             _, delta, i = carry
@@ -330,11 +429,12 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
     def warm_body(_, carry):
         g, _ = carry
         g2 = e_step(g, doc_ids, elog_beta_t, mask, all_runs)
-        return g2, jnp.abs(g2 - g).mean(axis=1)
+        return g2, change(g2, g)
 
     gamma, delta_d = jax.lax.fori_loop(
         0, warm, warm_body,
-        (gamma0, jnp.full((gamma0.shape[0],), jnp.inf, jnp.float32)))
+        (gamma0, jnp.full((gamma0.shape[0] * group,), jnp.inf,
+                          jnp.float32)))
     if rem_iters <= 0:
         return out(gamma, warm)
 
@@ -376,9 +476,8 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
             # exact (every token of an active doc sits inside the
             # compacted slice — activity is per-doc, and the slice is
             # chosen to cover n_act).
-            g2 = jnp.where(active_d[:, None], g2, g)
-            delta = jnp.where(active_d,
-                              jnp.abs(g2 - g).mean(axis=1), 0.0).max()
+            g2 = jnp.where(_doc_lanes(active_d, k, group, width), g2, g)
+            delta = jnp.where(active_d, change(g2, g), 0.0).max()
             return g2, delta, i + 1
 
         def cond(carry):
@@ -538,7 +637,6 @@ def svi_store_step(
     of its active set (the sum of its pairs' weights), the batch's
     pairs, the active set's pairs."""
     from onix.models.scoring import score_events
-    from onix.utils.obs import device_scope
 
     lam = state.lam
     scvb0 = estep_form == "scvb0"

@@ -1,0 +1,204 @@
+"""The stream's E-step on the chip, a pass at a time, with gamma one
+document a K-lane row ("rows", `[cap, K]`) and four documents a 128-lane
+row ("packed", `[cap / 4, 128]`, `lda_svi._gamma_group`), at the shapes of
+the benchmark's `flow-stream-catchup` cell.
+
+    python3 scripts/exp_stream_pack.py --out <dir>     # on a TPU host
+
+A batch is made as the cell's program sees it after the dedupe: 2^23 pair
+slots, 2,340,000 weighted (document, word) pairs in front of them, word by
+word and within a word by document (`lda_svi._unique_pairs`' order), over
+a store of 262144 rows: 196,103 client documents with about twelve pairs
+each and 24 server documents with the rest; 32768 words, K = 20.
+`lda_svi._run_e_step` in ids mode runs it, in each form, with a tolerance
+no document meets (every pair stays active) for `--short` and for
+`--long` passes: one warm pass, the compaction, the extended loop (the
+gathers of E[log theta] and E[log beta] rows, the softmax, the
+scatter-add into the new gamma, each document's change, the freeze). A
+pass costs the difference of the two programs' device times over the
+difference of the pair rows they worked, which takes the pack, the
+unpack, the compaction's sort and the first pass out.
+
+Each program runs once to compile, `--reps` times on the host's clock,
+and once alone under the profiler (device time of the whole execution,
+its longest ops). The compiled text goes to `--out`: which arrays the
+compiler keeps in fast memory, `S(1)`, is printed. On a CPU the script
+runs (a rehearsal, with `--scale`) and says so; its times mean nothing
+there.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import shutil
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+CAP, K, N_WORDS, SLOTS = 262144, 20, 32768, 1 << 23
+N_PAIRS, CLIENTS, SERVERS, SERVER_PAIRS = 2_340_000, 196_103, 24, 30_000
+
+
+def batch(seed: int, scale: float):
+    """(store [cap, K], elog_beta [V, K], docs, words, weights [slots],
+    n_pairs) at the cell's shapes times `scale`."""
+    rng = np.random.default_rng(seed)
+    cap = max(64, int(CAP * scale))
+    slots = max(1024, int(SLOTS * scale))
+    n_pairs = int(N_PAIRS * scale)
+    clients = max(8, int(CLIENTS * scale))
+    server_pairs = int(SERVER_PAIRS * scale)
+    words_n = max(64, int(N_WORDS * scale))
+    d = np.concatenate([
+        rng.integers(0, clients, n_pairs - server_pairs),
+        clients + rng.integers(0, SERVERS, server_pairs)]).astype(np.int32)
+    w = rng.integers(0, words_n, n_pairs).astype(np.int32)
+    order = np.lexsort((d, w))
+    pad = slots - n_pairs
+    docs = np.concatenate([d[order], np.full(pad, cap - 1, np.int32)])
+    words = np.concatenate([w[order], np.zeros(pad, np.int32)])
+    weights = np.concatenate([rng.integers(1, 6, n_pairs).astype(np.float32),
+                              np.zeros(pad, np.float32)])
+    store = (1.2 + rng.gamma(2.0, 2.0, (cap, K))).astype(np.float32)
+    lam = (0.01 + rng.gamma(0.5, 20.0, (words_n, K))).astype(np.float32)
+    from scipy.special import digamma
+    elog_beta = (digamma(lam) - digamma(lam.sum(0, keepdims=True))
+                 ).astype(np.float32)
+    return store, elog_beta, docs, words, weights, n_pairs
+
+
+def program(form: str, passes: int):
+    """`_run_e_step` in ids mode, traced in `form`: "packed" as the chip
+    takes it at K=20, "rows" one document a row (as K over 64, and the
+    CPU, run)."""
+    from unittest import mock
+
+    from onix.models import lda_gibbs, lda_svi
+
+    def run(store, elog_beta, docs, words, weights, n_pairs):
+        return lda_svi._run_e_step(
+            store, words, docs, weights, alpha=1.2, local_iters=passes,
+            meanchange_tol=1e-30, warm_iters=1, with_stats=True,
+            elog_beta=elog_beta, n_rows=n_pairs)
+
+    run.__name__ = f"unit_{form}_{passes}"
+    patch = mock.patch.object(
+        lda_svi, "_gamma_group",
+        (lambda k: 1) if form == "rows" else lda_gibbs._ndk_group)
+    return run, patch
+
+
+def fast_memory(hlo: str) -> list[str]:
+    """The distinct shapes of the compiled program laid out in fast
+    memory (`S(1)`), largest first."""
+    shapes = set(re.findall(r"([a-z0-9]+\[[0-9,]+\])\{[^}]*S\(1\)", hlo))
+
+    def size(s):
+        dims = s[s.index("[") + 1:-1].split(",")
+        return int(np.prod([int(x) for x in dims]))
+    return sorted(shapes, key=lambda s: -size(s))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--out", default=".bench_trace/stream_pack")
+    ap.add_argument("--forms", default="rows,packed")
+    ap.add_argument("--short", type=int, default=3)
+    ap.add_argument("--long", type=int, default=9)
+    ap.add_argument("--reps", type=int, default=2)
+    ap.add_argument("--seed", type=int, default=4000100101)
+    ap.add_argument("--scale", type=float, default=1.0,
+                    help="shrink the batch (a CPU rehearsal)")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import jax.profiler
+
+    from benchmark import harness, tracered
+
+    os.makedirs(args.out, exist_ok=True)
+    dev = jax.devices()[0]
+    on_chip = dev.platform == "tpu"
+    t0 = time.monotonic()
+    host = batch(harness.fold_seed(args.seed), args.scale)
+    operands = tuple(jnp.asarray(a) for a in host[:5]) + (
+        jnp.int32(host[5]),)
+    result = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+              "times_are_device_times": on_chip,
+              "store_rows": host[0].shape[0], "slots": host[2].shape[0],
+              "pairs": host[5], "batch_s": time.monotonic() - t0,
+              "programs": {}}
+    # A profiler session's first program may come back without its
+    # module's events: one session ahead of the readings.
+    jax.profiler.start_trace(os.path.join(args.out, "trace.warm"))
+    jax.block_until_ready(jnp.zeros(8) + 1)
+    jax.profiler.stop_trace()
+    shutil.rmtree(os.path.join(args.out, "trace.warm"), ignore_errors=True)
+    gammas = {}
+    for form in args.forms.split(","):
+        for passes in (args.short, args.long):
+            fn, patch = program(form, passes)
+            name = fn.__name__
+            t0 = time.monotonic()
+            with patch:
+                compiled = jax.jit(fn).lower(*operands).compile()
+            compile_s = time.monotonic() - t0
+            hlo = compiled.as_text()
+            with open(os.path.join(args.out, f"{name}.hlo.txt"), "w") as fh:
+                fh.write(hlo)
+            gamma, stats = jax.block_until_ready(compiled(*operands))
+            walls = []
+            for _ in range(args.reps):
+                t0 = time.monotonic()
+                jax.block_until_ready(compiled(*operands))
+                walls.append(time.monotonic() - t0)
+            stats = [int(s) for s in stats]
+            rec = {"compile_s": compile_s, "wall_s": min(walls),
+                   "stats": stats,
+                   # warm passes x pairs + extended passes x active rows
+                   "pair_rows": stats[0] * stats[3] + stats[1] * stats[4],
+                   "fast_memory": fast_memory(hlo)}
+            trace_dir = os.path.join(args.out, f"trace.{name}")
+            jax.profiler.start_trace(trace_dir)
+            with jax.profiler.TraceAnnotation("bench.trace_open"):
+                pass
+            jax.block_until_ready(compiled(*operands))
+            with jax.profiler.TraceAnnotation("bench.trace_close"):
+                pass
+            jax.profiler.stop_trace()
+            if on_chip:
+                red = tracered.reduce_trace(trace_dir)
+                mods = [m for n, m in red["modules"].items() if name in n]
+                rec["device_s"] = sum(m["whole_seconds"] for m in mods)
+                rec["top_ops"] = red["device_ops"][:8]
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            gammas[name] = np.asarray(gamma)
+            result["programs"][name] = rec
+            print(json.dumps({name: rec}), flush=True)
+    for form in args.forms.split(","):
+        a = result["programs"][f"unit_{form}_{args.short}"]
+        b = result["programs"][f"unit_{form}_{args.long}"]
+        rows = b["pair_rows"] - a["pair_rows"]
+        for key in ("device_s", "wall_s"):
+            if rows and a.get(key) and b.get(key):
+                result[f"{form}_ns_per_pair_row_pass.{key}"] = (
+                    (b[key] - a[key]) / rows * 1e9)
+    forms = args.forms.split(",")
+    if len(forms) == 2:
+        result["max_abs_gamma_diff"] = float(np.abs(
+            gammas[f"unit_{forms[0]}_{args.long}"]
+            - gammas[f"unit_{forms[1]}_{args.long}"]).max())
+    with open(os.path.join(args.out, "stream_pack.json"), "w") as fh:
+        json.dump(result, fh, indent=1)
+    print(json.dumps(result))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

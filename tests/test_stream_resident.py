@@ -141,6 +141,43 @@ def test_resident_in_runs(monkeypatch):
     _assert_same_state(whole, runs)
 
 
+@pytest.mark.parametrize("estep", ["svi", "scvb0"])
+def test_resident_packed_matches_rows_form(monkeypatch, estep):
+    """The resident superstep with the E-step's gamma packed G documents
+    a 128-lane row (16 at K=6, the chip's form) and with it one document
+    a row (the CPU's): the same winners, the same passes, lambda and
+    gamma to float32 rounding, and the store it carries and hands back
+    [cap, K]."""
+    from onix.models import lda_gibbs, lda_svi
+    from onix.utils.obs import counters
+
+    def passes(scorer):
+        at = counters.get("stream.estep_iters")
+        out = scorer.process_many([(c, None) for c in chunks])
+        return out, counters.get("stream.estep_iters") - at
+
+    assert lda_gibbs._ndk_group(6) == 16
+    chunks = _chunks(7)
+    monkeypatch.setattr(lda_svi, "_gamma_group", lda_gibbs._ndk_group)
+    packed = StreamingScorer(_cfg(3, estep), "flow", n_buckets=N_BUCKETS)
+    # Another tolerance makes another program (and not one that another
+    # test traces): the patched form is read when it is traced.
+    packed._step_kw["tol"] = 0.25 + 3e-9
+    res_a, passes_a = passes(packed)
+    stats_a = packed.last_estep_stats.copy()
+    cap = packed._res.store.shape[0]
+    assert packed._res.store.shape == (cap, 6)
+    monkeypatch.setattr(lda_svi, "_gamma_group", lambda k: 1)
+    rows = StreamingScorer(_cfg(3, estep), "flow", n_buckets=N_BUCKETS)
+    rows._step_kw["tol"] = 0.25 + 2e-9
+    res_b, passes_b = passes(rows)
+    _assert_same_stream(res_a, res_b)
+    assert passes_a == passes_b > 0
+    np.testing.assert_array_equal(rows.last_estep_stats, stats_a)
+    assert rows._res.store.shape == (cap, 6)
+    _assert_same_state(packed, rows)
+
+
 def test_resident_scores_are_fetched_when_read():
     chunks = _chunks(4)
     res = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)

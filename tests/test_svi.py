@@ -474,3 +474,165 @@ def test_store_step_dedupe_has_its_own_scope_inside_the_estep():
     assert len(inside) >= 2                 # the dedupe's two
     assert len(sorts) > len(inside)         # the compaction's is not in it
     assert all("onix.svi.estep" in ln for ln in sorts)
+
+
+# -- the ids mode's gamma packed G documents a 128-lane row -----------------
+
+_PK_K, _PK_CAP, _PK_V, _PK_T, _PK_RUN, _PK_ROWS = 20, 30, 50, 512, 128, 300
+
+
+def _packed_case(estep: str, active: str):
+    """A store of 30 rows at K=20 (G=4: documents in every lane slot, the
+    last packed row half full), 300 weighted (document, word) pairs word
+    by word in front of 512 slots, in runs of 128 (the last run holds
+    rows of weight that a pass must not reach: `n_rows` stops it), and
+    the tolerance that leaves no document, some, or every one moving
+    after the warm passes."""
+    rng = np.random.default_rng(53)
+    key = np.sort(rng.choice(_PK_CAP * _PK_V, _PK_ROWS, replace=False))
+    d = np.zeros(_PK_T, np.int32)
+    w = np.zeros(_PK_T, np.int32)
+    m = np.zeros(_PK_T, np.float32)
+    order = np.lexsort((key // _PK_V, key % _PK_V))
+    d[:_PK_ROWS], w[:_PK_ROWS] = (key // _PK_V)[order], (key % _PK_V)[order]
+    m[:_PK_ROWS] = rng.integers(1, 6, _PK_ROWS)
+    # Past the runs that hold the pairs: weight, at every document.
+    d[3 * _PK_RUN:] = rng.integers(0, _PK_CAP, _PK_T - 3 * _PK_RUN)
+    w[3 * _PK_RUN:] = rng.integers(0, _PK_V, _PK_T - 3 * _PK_RUN)
+    m[3 * _PK_RUN:] = 7.0
+    assert set(d[:_PK_ROWS]) == set(range(_PK_CAP))
+    lam = 0.01 + rng.gamma(0.5, 20.0, (_PK_V, _PK_K))
+    if estep == "scvb0":
+        elog_beta = np.log(lam / lam.sum(0, keepdims=True))
+    else:
+        from scipy.special import digamma
+        elog_beta = digamma(lam) - digamma(lam.sum(0, keepdims=True))
+    store = 1.2 + rng.gamma(2.0, 2.0, (_PK_CAP, _PK_K))
+    tol, warm, iters = {"none": (1e9, 2, 30), "some": (0.1, 4, 30),
+                        "all": (1e-30, 1, 6)}[active]
+    return ((store.astype(np.float32), elog_beta.astype(np.float32), d, w,
+             m), dict(alpha=1.2, local_iters=iters, meanchange_tol=tol,
+                      warm_iters=warm, estep_form=estep))
+
+
+def _chip_group(k: int) -> int:
+    """What `lda_svi._gamma_group` reads on the chip (the CPU's is 1)."""
+    from onix.models import lda_gibbs
+    return lda_gibbs._ndk_group(k)
+
+
+def _ids_e_step(case, monkeypatch, packed: bool):
+    import jax.numpy as jnp
+
+    from onix.models import lda_svi
+
+    (store, elog_beta, d, w, m), kw = case
+    with monkeypatch.context() as mp:
+        mp.setattr(lda_svi, "_TOKEN_RUN", _PK_RUN)
+        mp.setattr(lda_svi, "_gamma_group",
+                   _chip_group if packed else (lambda k: 1))
+        gamma, stats = lda_svi._run_e_step(
+            jnp.asarray(store), jnp.asarray(w), jnp.asarray(d),
+            jnp.asarray(m), with_stats=True,
+            elog_beta=jnp.asarray(elog_beta), n_rows=jnp.int32(_PK_ROWS),
+            **kw)
+    return np.asarray(gamma), [int(s) for s in stats]
+
+
+@pytest.mark.parametrize("active", ["none", "some", "all"])
+@pytest.mark.parametrize("estep", ["svi", "scvb0"])
+def test_packed_e_step_matches_rows_form(monkeypatch, estep, active):
+    """The ids mode's E-step with gamma packed four documents a 128-lane
+    row gives the rows form's gamma, to float32 rounding, and the same
+    passes and active set; the rows the pass must not reach are left out
+    of both; the gamma handed back is [cap, K]."""
+    assert _chip_group(_PK_K) == 4
+    case = _packed_case(estep, active)
+    got, got_stats = _ids_e_step(case, monkeypatch, packed=True)
+    want, want_stats = _ids_e_step(case, monkeypatch, packed=False)
+    assert got.shape == want.shape == (_PK_CAP, _PK_K)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert got_stats == want_stats
+    full, ext, _, n_rows, act_rows = got_stats
+    assert n_rows == _PK_ROWS
+    if active == "none":
+        assert ext == act_rows == 0
+    elif active == "some":
+        assert 0 < act_rows < _PK_ROWS and ext > 0
+    else:
+        assert act_rows == _PK_ROWS and full + ext == 6
+
+    # The rows past `n_rows` weigh nothing: the same E-step without them.
+    (store, elog_beta, d, w, m), kw = case
+    m = m.copy()
+    m[_PK_ROWS:] = 0.0
+    alone, alone_stats = _ids_e_step(((store, elog_beta, d, w, m), kw),
+                                     monkeypatch, packed=True)
+    np.testing.assert_array_equal(alone, got)
+    assert alone_stats == got_stats
+
+
+@pytest.mark.parametrize("estep", ["svi", "scvb0"])
+def test_store_step_packed_matches_rows_form(monkeypatch, estep):
+    """`svi_store_step` through the packed E-step and through the rows
+    form: the same lambda, store and scores to float32 rounding, the
+    same passes, and the store handed back [cap, K]."""
+    from onix.models import lda_svi
+
+    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3, stream_estep=estep)
+    state = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100).init()
+    store0 = (cfg.alpha + np.random.default_rng(5).gamma(
+        2.0, 2.0, (_PAIR_CAP, cfg.n_topics))).astype(np.float32)
+    batch = _pair_batch("one_document_half")
+    monkeypatch.setattr(lda_svi, "_gamma_group", _chip_group)
+    packed = _store_step(cfg, state, store0, *batch)
+    monkeypatch.setattr(lda_svi, "_gamma_group", lambda k: 1)
+    rows = _store_step(cfg, state, store0, *batch)
+    assert packed[1].shape == rows[1].shape == (_PAIR_CAP, cfg.n_topics)
+    np.testing.assert_allclose(np.asarray(packed[0].lam),
+                               np.asarray(rows[0].lam), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(packed[1]), np.asarray(rows[1]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(packed[2]), np.asarray(rows[2]))
+    np.testing.assert_allclose(np.asarray(packed[3]), np.asarray(rows[3]),
+                               rtol=1e-5, atol=1e-7)
+    np.testing.assert_array_equal(np.asarray(packed[4]), np.asarray(rows[4]))
+
+
+@pytest.mark.parametrize("backend,k,group", [
+    ("tpu", 20, 4), ("tpu", 6, 16), ("tpu", 4, 32), ("tpu", 64, 2),
+    ("tpu", 80, 1), ("tpu", 256, 1), ("cpu", 20, 1), ("gpu", 20, 1)])
+def test_estep_packs_where_the_fit_packs(backend, k, group):
+    """The ids mode's E-step takes the fit's form of n_dk, from the
+    backend and K (`lda_gibbs.select_ndk_form`): the chip packs the
+    largest power of two of K-lane documents a 128-lane row; K over 64,
+    and a backend without a reading, keep one document a row."""
+    from unittest import mock
+
+    from onix.models import lda_svi
+
+    with mock.patch.object(lda_svi.jax, "default_backend",
+                           return_value=backend):
+        assert lda_svi._gamma_group(k) == group
+
+
+def test_store_step_pack_has_its_own_scope_inside_the_estep(monkeypatch):
+    """The E-step's pack on entry and unpack at the end are named
+    `onix.svi.estep.pack` inside `onix.svi.estep`, so that a trace shows
+    them alone and `estep_s_per_batch` (by prefix) goes on charging the
+    E-step for them."""
+    import functools
+
+    import jax
+
+    from onix.models import lda_svi
+
+    monkeypatch.setattr(lda_svi, "_gamma_group", _chip_group)
+    cfg = LDAConfig(n_topics=20, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3)
+    state = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100).init()
+    text = jax.jit(functools.partial(_store_step, cfg)).lower(
+        state, np.ones((_PAIR_CAP, 20), np.float32),
+        *_pair_batch("one_document_half")).compile().as_text()
+    assert "onix.svi.estep/onix.svi.estep.pack/" in text
