@@ -215,7 +215,10 @@ def _unique_pairs(doc_ids, word_ids, mask):
 # array is laid out with K padded to 128 lanes on the chip, so every row
 # it gathers and adds to is 84% padding, and the tables are four times
 # the bytes (the chip keeps the packed E[log theta] in fast memory, the
-# [cap, K] one not). Packed once on entry, unpacked once at the end. A
+# [cap, K] one not). Packed once on entry, unpacked once at the end for
+# the store; the lambda step after it takes the packed gamma as it is,
+# and the scores pack the updated store once a batch (theta's rows, read
+# at every token slot). A
 # pass works on whole 128-lane rows: E[log beta] is laid G times along
 # the lanes, a pair's row of E[log theta] plus its word's row is
 # softmaxed over its document's K lanes (the others masked to -inf, so
@@ -264,14 +267,85 @@ def _doc_lanes(v, k: int, group: int, width: int):
     return out
 
 
+def _pack_rows(x, group: int, fill: float):
+    """[D, K] -> `pack_ndk`'s [ceil(D / group), 128], with `fill` in the
+    tail documents and in the lanes past the last one (x itself where a
+    row is one document)."""
+    if group == 1:
+        return x
+    n_docs, k = x.shape
+    return jnp.where(
+        _lane_docs(lda_gibbs._NDK_LANES, k) < group,
+        lda_gibbs.pack_ndk(jnp.pad(x, ((0, -n_docs % group), (0, 0)),
+                                   constant_values=fill), group), fill)
+
+
+def _word_lanes(x, group: int, width: int):
+    """A word's [V, K] row laid `group` times along a packed row's lanes,
+    [V, width], the lanes past the last document 0."""
+    if group == 1:
+        return x
+    return jnp.pad(jnp.tile(x, (1, group)),
+                   ((0, 0), (0, width - group * x.shape[1])))
+
+
+def _fold_lanes(x, k: int, group: int):
+    """[V, width] laid as `_word_lanes` lays a row -> [V, K]: the sum of
+    its `group` lane groups."""
+    out = x[:, :k]
+    for j in range(1, group):
+        out = out + x[:, j * k:(j + 1) * k]
+    return out
+
+
+def _elog_theta(gamma, k: int, group: int, estep_form: str):
+    """E[log theta] of every document of gamma's rows, in their lanes
+    (log gamma for scvb0: see `_run_e_step`)."""
+    if estep_form == "scvb0":
+        return jnp.log(gamma)
+    if group == 1:
+        return _e_log_dirichlet(gamma, axis=1)
+    digamma = jax.scipy.special.digamma
+    return digamma(gamma) - _doc_lanes(
+        digamma(_doc_sums(gamma, k, group)), k, group, gamma.shape[1])
+
+
+def _own_lanes(d, k: int, group: int, width: int):
+    """[T, width]: the lanes of document `d`'s slot in its packed row."""
+    return _lane_docs(width, k) == (d % group)[:, None]
+
+
+def _doc_softmax(logp, d, k: int, group: int):
+    """Each row's softmax over its document's K lanes (document `d`'s
+    slot of a packed row); the other lanes 0."""
+    if group > 1:
+        logp = jnp.where(_own_lanes(d, k, group, logp.shape[1]), logp,
+                         -jnp.inf)
+    return jax.nn.softmax(logp, axis=-1)
+
+
+def _doc_scores(theta, phi_lanes, d, w, k: int, group: int):
+    """sum_k theta[d, k] phi[w, k] a token, from theta's rows as `_pack_rows`
+    lays them and phi's as `_word_lanes` does: one row of each, multiplied
+    and summed over the document's K lanes (`scoring.score_events` where
+    a row is one document)."""
+    prod = theta[d // group] * phi_lanes[w]
+    if group > 1:
+        prod = jnp.where(_own_lanes(d, k, group, prod.shape[1]), prod, 0.0)
+    return prod.sum(axis=-1)
+
+
 def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
                 local_iters: int, meanchange_tol: float,
                 warm_iters: int, estep_form: str = "svi",
-                with_stats: bool = False, elog_beta=None, n_rows=None):
+                with_stats: bool = False, elog_beta=None, n_rows=None,
+                with_carried: bool = False):
     """The local E-step over one minibatch's tokens. Returns gamma; with
     `with_stats` (static) also what ran, as int32 scalars: the passes
     over the full padded block, the passes of the extended loop, and the
-    tokens of the compacted active set that loop worked on.
+    tokens of the compacted active set that loop worked on; with
+    `with_carried` too (static) last the gamma as the passes carried it
+    (packed, below; gamma itself where a row is one document).
 
     With `elog_beta` ([V, K]) given, `elog_beta_t` holds the tokens'
     WORD IDS in place of their rows: every pass gathers the rows it
@@ -333,27 +407,15 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         with device_scope("onix.svi.estep.pack"):
             # The tail documents and the lanes past the last one hold
             # alpha, as a row no pair touches does.
-            gamma0 = jnp.where(
-                _lane_docs(width, k) < group, lda_gibbs.pack_ndk(jnp.pad(
-                    gamma0, ((0, -n_docs % group), (0, 0)),
-                    constant_values=alpha), group), alpha)
-            elog_beta_lanes = jnp.pad(
-                jnp.tile(elog_beta, (1, group)),
-                ((0, 0), (0, width - group * k)))
+            gamma0 = _pack_rows(gamma0, group, alpha)
+            elog_beta_lanes = _word_lanes(elog_beta, group, width)
 
     def e_step(gamma, d_ids, eb_t, m, n_runs=None):
-        if estep_form == "scvb0":
-            # Collapsed zeroth-order responsibilities: gamma holds
-            # alpha + N_theta (> 0 always), eb_t holds log(phi_hat)
-            # rows, so softmax(log gamma + log phi_hat) is exactly the
-            # normalized (N_theta + alpha) · phi_hat of SCVB0.
-            elog_theta = jnp.log(gamma)                  # [Bd,K]
-        elif group == 1:
-            elog_theta = _e_log_dirichlet(gamma, axis=1)  # [Bd,K]
-        else:
-            digamma = jax.scipy.special.digamma
-            elog_theta = digamma(gamma) - _doc_lanes(
-                digamma(_doc_sums(gamma, k, group)), k, group, width)
+        # scvb0: collapsed zeroth-order responsibilities. gamma holds
+        # alpha + N_theta (> 0 always), eb_t holds log(phi_hat) rows, so
+        # softmax(log gamma + log phi_hat) is exactly the normalized
+        # (N_theta + alpha) · phi_hat of SCVB0.
+        elog_theta = _elog_theta(gamma, k, group, estep_form)
 
         def add(acc, d, e, mm):
             if elog_beta is None:
@@ -362,10 +424,7 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
                 return acc.at[d].add(phi), None
             row = d // group
             logp = elog_theta[row] + elog_beta_lanes[e]  # [T,width]
-            if group > 1:
-                logp = jnp.where(_lane_docs(width, k) == (d % group)[:, None],
-                                 logp, -jnp.inf)
-            phi = jax.nn.softmax(logp, axis=-1) * mm[:, None]
+            phi = _doc_softmax(logp, d, k, group) * mm[:, None]
             return acc.at[row].add(phi), None
 
         zero = jnp.zeros_like(gamma)
@@ -385,6 +444,7 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
     all_runs = _runs_holding(n_rows, t)
 
     def out(gamma, full, ext=0, n_act=0, act_rows=0):
+        carried = gamma
         if group > 1:
             with device_scope("onix.svi.estep.pack"):
                 gamma = lda_gibbs.unpack_ndk(gamma, n_docs, k, group)
@@ -394,7 +454,7 @@ def _run_e_step(gamma0, elog_beta_t, doc_ids, mask, *, alpha: float,
         if elog_beta is not None:
             stats += (jnp.int32(t if n_rows is None else n_rows),
                       jnp.int32(act_rows))
-        return gamma, stats
+        return (gamma, stats, carried) if with_carried else (gamma, stats)
 
     if meanchange_tol <= 0.0:
         return out(jax.lax.fori_loop(
@@ -635,18 +695,13 @@ def svi_store_step(
     stats), `stats` as `_run_e_step(with_stats=True)` gives it: the
     passes over every pair, the passes of the extended loop, the TOKENS
     of its active set (the sum of its pairs' weights), the batch's
-    pairs, the active set's pairs."""
-    from onix.models.scoring import score_events
+    pairs, the active set's pairs.
 
+    The lambda step and the scores work the rows the E-step carries
+    gamma in (the comment above `_gamma_group`): the lambda step the
+    E-step's own packed gamma, the scores the updated store packed once;
+    the same sums in another order."""
     lam = state.lam
-    scvb0 = estep_form == "scvb0"
-
-    def elog_rows(x, axis):
-        if scvb0:
-            return jnp.log(x / x.sum(axis=0, keepdims=True) if axis == 0
-                           else x)
-        return _e_log_dirichlet(x, axis=axis)
-
     with device_scope("onix.svi.estep"):
         with device_scope("onix.svi.estep.pairs"):
             p_doc, p_word, p_weight, n_pairs = _unique_pairs(
@@ -656,36 +711,72 @@ def svi_store_step(
             lambda acc, d, m: (acc.at[d].add(m), None),
             jnp.zeros((store.shape[0],), jnp.float32), (p_doc, p_weight),
             pair_runs)[0] > 0.0
-        elog_beta = elog_rows(lam, 0)
-        gamma, stats = _run_e_step(
+        elog_beta = (jnp.log(lam / lam.sum(axis=0, keepdims=True))
+                     if estep_form == "scvb0"
+                     else _e_log_dirichlet(lam, axis=0))
+        gamma, stats, gamma_rows = _run_e_step(
             jnp.where(touched[:, None], store, alpha), p_word,
             p_doc, p_weight, alpha=alpha, local_iters=local_iters,
             meanchange_tol=meanchange_tol, warm_iters=warm_iters,
             estep_form=estep_form, with_stats=True, elog_beta=elog_beta,
-            n_rows=n_pairs)
+            n_rows=n_pairs, with_carried=True)
     with device_scope("onix.svi.lambda"):
-        elog_theta = elog_rows(gamma, 1)
-
-        def add(acc, d, w, m):
-            # Final responsibilities under the converged gamma.
-            phi = jax.nn.softmax(elog_theta[d] + elog_beta[w], axis=-1)
-            return acc.at[w].add(phi * m[:, None]), None
-
-        sstats, _ = _in_token_runs(add, jnp.zeros_like(lam),
-                                   (p_doc, p_word, p_weight), pair_runs)
+        sstats = _pair_sstats(gamma_rows, elog_beta, p_doc, p_word, p_weight,
+                              pair_runs, estep_form=estep_form)
         n_real = touched.sum().astype(jnp.float32)
         scale = corpus_docs / jnp.maximum(n_real, 1.0)
         rho = (tau0 + state.step.astype(jnp.float32)) ** (-kappa)
         lam = (1.0 - rho) * lam + rho * (eta + scale * sstats)
         store = jnp.where(touched[:, None], gamma, store)
     with device_scope("onix.svi.score"):
-        theta = store / store.sum(axis=1, keepdims=True)
-        phi_wk = lam / lam.sum(axis=0, keepdims=True)
-        _, scores = _in_token_runs(
-            lambda _, d, w: (None, score_events(theta, phi_wk, d, w)),
-            None, (doc_ids, word_ids))
+        scores = _slot_scores(store, lam, doc_ids, word_ids)
     return (SVIState(lam=lam, step=state.step + 1), store, touched, scores,
             stats)
+
+
+def _pair_sstats(gamma_rows, elog_beta, p_doc, p_word, p_weight,
+                 n_runs=None, *, estep_form: str = "svi"):
+    """The lambda step's statistics, [V, K]: each weighted pair's final
+    responsibilities under the converged gamma, summed by word. `gamma_rows`
+    is gamma as `_run_e_step` carried it (`with_carried`): the pairs read
+    their rows of E[log theta] there and their words' rows of E[log beta]
+    laid as the E-step lays them, and add into the words' rows laid alike,
+    whose lane groups are folded at the end. `n_runs` as
+    `_in_token_runs`."""
+    k = elog_beta.shape[1]
+    width = gamma_rows.shape[1]
+    group = 1 if width == k else _gamma_group(k)
+    elog_theta = _elog_theta(gamma_rows, k, group, estep_form)
+    elog_beta_lanes = _word_lanes(elog_beta, group, width)
+
+    def add(acc, d, w, m):
+        phi = _doc_softmax(elog_theta[d // group] + elog_beta_lanes[w], d, k,
+                           group)
+        return acc.at[w].add(phi * m[:, None]), None
+
+    acc, _ = _in_token_runs(
+        add, jnp.zeros((elog_beta.shape[0], width), elog_beta.dtype),
+        (p_doc, p_word, p_weight), n_runs)
+    return _fold_lanes(acc, k, group)
+
+
+def _slot_scores(store, lam, doc_ids, word_ids):
+    """Every token slot's score under the updated store [cap, K] and
+    lambda: the store packed once as the E-step packs gamma
+    (`_gamma_group`), theta and phi laid out alike, one row of each a
+    slot (`_doc_scores`), in runs of `_TOKEN_RUN`."""
+    k = store.shape[1]
+    group = _gamma_group(k)
+    # No slot reads a tail document or a lane past the last one (the
+    # lanes `_doc_scores` sums are its own document's).
+    rows = _pack_rows(store, group, 1.0)
+    width = rows.shape[1]
+    theta = rows / _doc_lanes(_doc_sums(rows, k, group), k, group, width)
+    phi_lanes = _word_lanes(lam / lam.sum(axis=0, keepdims=True), group,
+                            width)
+    return _in_token_runs(
+        lambda _, d, w: (None, _doc_scores(theta, phi_lanes, d, w, k, group)),
+        None, (doc_ids, word_ids))[1]
 
 
 class SVILda:

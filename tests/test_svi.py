@@ -572,24 +572,54 @@ def test_packed_e_step_matches_rows_form(monkeypatch, estep, active):
     assert alone_stats == got_stats
 
 
+def _shared_rows_batch():
+    """(doc_ids, word_ids, mask) of 256 token slots over a store of 30
+    rows at K=20 (G=4: 30 is no multiple of it, so the last packed row,
+    documents 28 and 29, has two empty slots): the touched documents
+    share packed rows (0-3 one, 8-10 another, 13, 26); weightless tokens
+    point at untouched rows sharing those packed rows (11, 24, 25), at
+    a packed row no touched document is in (5, 6) and at the last packed
+    row (28, 29)."""
+    rng = np.random.default_rng(61)
+    touched = np.array([0, 1, 2, 3, 8, 9, 10, 13, 26], np.int32)
+    d = rng.choice(touched, _PAIR_T).astype(np.int32)
+    w = rng.integers(0, _PAIR_V, _PAIR_T).astype(np.int32)
+    m = rng.integers(1, 4, _PAIR_T).astype(np.float32)
+    pad = np.arange(_PAIR_T) >= 180
+    d[pad] = rng.choice([5, 6, 11, 24, 25, 28, 29], int(pad.sum()))
+    m[pad] = 0.0
+    return d, w, m
+
+
+@pytest.mark.parametrize("case", ["one_document_half", "shared_rows"])
 @pytest.mark.parametrize("estep", ["svi", "scvb0"])
-def test_store_step_packed_matches_rows_form(monkeypatch, estep):
-    """`svi_store_step` through the packed E-step and through the rows
-    form: the same lambda, store and scores to float32 rounding, the
-    same passes, and the store handed back [cap, K]."""
+def test_store_step_packed_matches_rows_form(monkeypatch, estep, case):
+    """`svi_store_step` with gamma packed (the E-step, the lambda step on
+    the E-step's packed rows, the scores on the updated store packed)
+    and in the rows form: the same lambda, store and scores to float32
+    rounding, every token slot's score among them, the same passes, and
+    the store handed back [cap, K]. `shared_rows` packs four documents a
+    row into a store whose last packed row is half empty, in runs of 64
+    token slots."""
     from onix.models import lda_svi
 
-    cfg = LDAConfig(n_topics=4, svi_meanchange_tol=1e-4, svi_local_iters=30,
+    k, cap, batch = {
+        "one_document_half": (4, _PAIR_CAP, _pair_batch("one_document_half")),
+        "shared_rows": (20, 30, _shared_rows_batch())}[case]
+    cfg = LDAConfig(n_topics=k, svi_meanchange_tol=1e-4, svi_local_iters=30,
                     svi_warm_iters=2, seed=3, stream_estep=estep)
     state = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100).init()
     store0 = (cfg.alpha + np.random.default_rng(5).gamma(
-        2.0, 2.0, (_PAIR_CAP, cfg.n_topics))).astype(np.float32)
-    batch = _pair_batch("one_document_half")
+        2.0, 2.0, (cap, cfg.n_topics))).astype(np.float32)
+    if case == "shared_rows":
+        assert _chip_group(k) == 4 and cap % 4
+        monkeypatch.setattr(lda_svi, "_TOKEN_RUN", 64)
     monkeypatch.setattr(lda_svi, "_gamma_group", _chip_group)
     packed = _store_step(cfg, state, store0, *batch)
     monkeypatch.setattr(lda_svi, "_gamma_group", lambda k: 1)
     rows = _store_step(cfg, state, store0, *batch)
-    assert packed[1].shape == rows[1].shape == (_PAIR_CAP, cfg.n_topics)
+    assert packed[1].shape == rows[1].shape == (cap, cfg.n_topics)
+    assert np.isfinite(np.asarray(packed[3])).all()
     np.testing.assert_allclose(np.asarray(packed[0].lam),
                                np.asarray(rows[0].lam), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(packed[1]), np.asarray(rows[1]),
@@ -636,3 +666,35 @@ def test_store_step_pack_has_its_own_scope_inside_the_estep(monkeypatch):
         state, np.ones((_PAIR_CAP, 20), np.float32),
         *_pair_batch("one_document_half")).compile().as_text()
     assert "onix.svi.estep/onix.svi.estep.pack/" in text
+
+
+def test_store_step_packed_lambda_and_scores_keep_their_scopes(monkeypatch):
+    """Packed, the lambda step's ops (its 128-lane row gathers, its
+    scatter-add into the words' `[V, 128]` rows) are named
+    `onix.svi.lambda`, and the scores' (the 128-lane row gathers of theta
+    and phi) `onix.svi.score`, so that `lambda_s_per_batch` and a reader
+    of the scores' scope go on booking them; no K-lane row of theta is
+    gathered any more."""
+    import functools
+
+    import jax
+
+    from onix.models import lda_svi
+
+    monkeypatch.setattr(lda_svi, "_gamma_group", _chip_group)
+    cfg = LDAConfig(n_topics=20, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3)
+    state = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100).init()
+    text = jax.jit(functools.partial(_store_step, cfg)).lower(
+        state, np.ones((_PAIR_CAP, 20), np.float32),
+        *_pair_batch("one_document_half")).compile().as_text()
+    lines = text.splitlines()
+
+    def ops(op, scope, shape):
+        return [ln for ln in lines if f" {op}(" in ln and shape in ln
+                and f"/{scope}/" in ln]
+
+    assert ops("scatter", "onix.svi.lambda", f"f32[{_PAIR_V},128]")
+    assert ops("gather", "onix.svi.lambda", "slice_sizes={1,128}")
+    assert len(ops("gather", "onix.svi.score", "slice_sizes={1,128}")) >= 1
+    assert not ops("gather", "onix.svi.score", "slice_sizes={1,20}")
