@@ -488,7 +488,10 @@ class StreamingScorer:
         self.superstep = max(1, int(cfg.pipeline.stream_superstep))
         self.max_shapes = max(1, int(cfg.pipeline.stream_max_shapes))
         k = cfg.lda.n_topics
-        self._gamma = np.full((_next_pow2(1), k), cfg.lda.alpha, np.float32)
+        # Rows no document owns hold the cold start, alpha + 1: a
+        # document inserted later starts from it.
+        self._gamma = np.full((_next_pow2(1), k), cfg.lda.alpha + 1.0,
+                              np.float32)
         # Eviction bound on per-doc state: a long-lived stream sees an
         # unbounded IP population, so gamma/doc-table growth must have a
         # ceiling. When n_docs crosses `max_docs`, the least-recently-
@@ -664,7 +667,7 @@ class StreamingScorer:
         n = self.docs.n_docs
         cap = _next_pow2(max(n, 1))
         k = saved.arrays["gamma"].shape[1]
-        self._gamma = np.full((cap, k), self.cfg.lda.alpha, np.float32)
+        self._gamma = np.full((cap, k), self.cfg.lda.alpha + 1.0, np.float32)
         self._gamma[:n] = saved.arrays["gamma"]
         self._last_seen = np.zeros(cap, np.int64)
         self._last_seen[:n] = saved.arrays["last_seen"]
@@ -695,7 +698,7 @@ class StreamingScorer:
             return
         new_cap = _next_pow2(n_docs, floor=cap)
         grown = np.full((new_cap, self._gamma.shape[1]),
-                        self.cfg.lda.alpha, np.float32)
+                        self.cfg.lda.alpha + 1.0, np.float32)
         grown[:cap] = self._gamma
         self._gamma = grown
         seen = np.zeros(new_cap, np.int64)
@@ -709,23 +712,28 @@ class StreamingScorer:
         bounded no matter how many distinct IPs it ever sees."""
         if self.max_docs is None or self.docs.n_docs <= self.max_docs:
             return 0
-        self._drop_resident()       # rows move: the host arrays decide
         n = self.docs.n_docs
         target = max(1, int(self.max_docs * 0.75))
-        # Survivors = the `target` most recently seen (ties broken by
-        # doc id: older docs go first, matching LRU intent).
-        order = np.lexsort((np.arange(n), -self._last_seen[:n]))
-        keep = np.zeros(n, bool)
-        keep[order[:target]] = True
-        old_ids = self.docs.compact(keep)
-        n_new = len(old_ids)
-        cap = _next_pow2(max(n_new, 1))
-        gamma = np.full((cap, self._gamma.shape[1]),
-                        self.cfg.lda.alpha, np.float32)
-        gamma[:n_new] = self._gamma[old_ids]
-        seen = np.zeros(cap, np.int64)
-        seen[:n_new] = self._last_seen[old_ids]
-        self._gamma, self._last_seen = gamma, seen
+        with telemetry.TRACER.span("stream.evict", before=n, after=target,
+                                   cap=_next_pow2(target)):
+            self._drop_resident()   # rows move: the host arrays decide
+            # Survivors = the `target` most recently seen; among the
+            # documents last seen in the same batch, the lower ids (the
+            # earlier first sightings) are kept.
+            order = np.lexsort((np.arange(n), -self._last_seen[:n]))
+            keep = np.zeros(n, bool)
+            keep[order[:target]] = True
+            old_ids = self.docs.compact(keep)
+            n_new = len(old_ids)
+            cap = _next_pow2(max(n_new, 1))
+            gamma = np.full((cap, self._gamma.shape[1]),
+                            self.cfg.lda.alpha + 1.0, np.float32)
+            gamma[:n_new] = self._gamma[old_ids]
+            seen = np.zeros(cap, np.int64)
+            seen[:n_new] = self._last_seen[old_ids]
+            self._gamma, self._last_seen = gamma, seen
+        counters.inc("stream.evictions")
+        counters.inc("stream.evicted_docs", n - n_new)
         return n - n_new
 
     def _pick_pad(self, t_rows: int, n_docs: int) -> tuple[int, int]:
@@ -1445,14 +1453,12 @@ class StreamingScorer:
                                            jax.device_put(ids), n)
 
     def _push_resident(self) -> None:
-        """Host arrays -> device. Rows no document owns hold the cold
-        start, `alpha + 1`: a document inserted later starts from it."""
+        """Host arrays -> device (a copy: the host path writes `_gamma`
+        while the device holds it)."""
         n = self.docs.n_docs
         self._grow(n + 1)           # at least one row that is no doc's
-        store = self._gamma.copy()
-        store[n:] = self.cfg.lda.alpha + 1.0
         self._res = _Resident(
-            jax.device_put(store),
+            jax.device_put(self._gamma.copy()),
             jax.device_put(self._last_seen.astype(np.int32)), None, None, n)
         self._push_table()
 
@@ -1475,21 +1481,36 @@ class StreamingScorer:
             self._res = self._staged = None
 
     def snapshot_resident(self) -> dict:
-        """Device copies of what a resident superstep changes (the
-        program donates the store): with `restore_resident`, the same
-        superstep can be run again from the same state."""
+        """Copies of what a resident superstep changes (the program
+        donates the store) and of the document table it grows and may
+        evict from: with `restore_resident`, the same superstep can be
+        run again from the same state."""
         if self._res is None:
             self._push_resident()
         r = self._res
         return {"state": self.state, "store": jnp.copy(r.store),
                 "last_seen": jnp.copy(r.last_seen),
-                "batch_no": self._batch_no}
+                "batch_no": self._batch_no,
+                "doc_keys": np.array(self.docs.keys)}
 
     def restore_resident(self, snap: dict) -> None:
-        r = self._res
+        """Put the state of `snap` on the device, its store at its own
+        capacity; a snapshot without `doc_keys` keeps the table that
+        stands. A group staged before is dropped: its probe answered
+        another table."""
+        if "doc_keys" in snap:
+            self.docs.load(snap["doc_keys"])
+        cap, k = snap["store"].shape
+        # The host arrays at the store's capacity, as they stood then
+        # (their rows are the device's to hand back: `_pull_resident`).
+        self._gamma = np.full((cap, k), self.cfg.lda.alpha + 1.0, np.float32)
+        self._last_seen = np.zeros(cap, np.int64)
+        self._res = _Resident(jnp.copy(snap["store"]),
+                              jnp.copy(snap["last_seen"]), None, None,
+                              self.docs.n_docs)
+        self._staged = None
+        self._push_table()
         self.state = snap["state"]
-        r.store, r.last_seen = (jnp.copy(snap["store"]),
-                                jnp.copy(snap["last_seen"]))
         self._batch_no = snap["batch_no"]
 
     def _stage(self, group: list, ahead: bool = False) -> _Staged:
@@ -1538,21 +1559,24 @@ class StreamingScorer:
         batch, a table that changed since): the device is idle then,
         and the look-up against the host's own table needs no program
         (the first one's compile would stand in the stream's way)."""
-        keys = np.sort(self.docs.keys)
-        e_pad = staged.cols["sip_u32"].shape[1]
-        masks = np.zeros((len(staged.group), 2 * e_pad), bool)
-        for i, (table, cols) in enumerate(staged.group):
-            n = len(table)
-            for at, name in ((0, "sip_u32"), (e_pad, "dip_u32")):
-                addr = np.asarray(cols[name], np.uint32)
-                if len(keys):
-                    pos = np.minimum(np.searchsorted(keys, addr),
-                                     len(keys) - 1)
-                    masks[i, at:at + n] = keys[pos] != addr
-                else:
-                    masks[i, at:at + n] = True
-        staged.probe = (masks.sum(axis=1), masks)
-        staged.probe_docs = self._res.n_docs
+        with telemetry.TRACER.span("stream.probe_host",
+                                   batches=len(staged.group)):
+            keys = np.sort(self.docs.keys)
+            e_pad = staged.cols["sip_u32"].shape[1]
+            masks = np.zeros((len(staged.group), 2 * e_pad), bool)
+            for i, (table, cols) in enumerate(staged.group):
+                n = len(table)
+                for at, name in ((0, "sip_u32"), (e_pad, "dip_u32")):
+                    addr = np.asarray(cols[name], np.uint32)
+                    if len(keys):
+                        pos = np.minimum(np.searchsorted(keys, addr),
+                                         len(keys) - 1)
+                        masks[i, at:at + n] = keys[pos] != addr
+                    else:
+                        masks[i, at:at + n] = True
+            staged.probe = (masks.sum(axis=1), masks)
+            staged.probe_docs = self._res.n_docs
+        counters.inc("stream.host_probes")
 
     def _grow_docs(self, staged: _Staged) -> list[int]:
         """Insert the group's unseen addresses (the tokens the probe
@@ -1579,9 +1603,14 @@ class StreamingScorer:
                         np.asarray(cols["dip_u32"], np.uint32)[
                             mask[e_pad:e_pad + n]]]))
                 after.append(self.docs.n_docs)
-            if after[-1] >= self._res.store.shape[0]:
-                self._pull_resident()       # the rows have run out
-                self._push_resident()
+            cap = self._res.store.shape[0]
+            if after[-1] >= cap:            # the rows have run out
+                with telemetry.TRACER.span(
+                        "stream.regrow", cap_from=cap,
+                        cap_to=_next_pow2(after[-1] + 1, floor=cap)):
+                    self._pull_resident()
+                    self._push_resident()
+                counters.inc("stream.store_regrows")
             else:
                 self._push_table()
             counters.inc("stream.new_docs", after[-1] - before)

@@ -294,6 +294,164 @@ def test_resident_eviction_bounds_the_store():
     assert np.isfinite(sc._gamma[:n]).all() and (sc._gamma[:n] > 0).all()
 
 
+def _churn_chunks(n_chunks: int, fresh: int, seed: int = 33):
+    """`_chunks`, with `fresh` events of every chunk sent from an address
+    no other chunk holds (11.<chunk>.x.y): a table that grows by about
+    `fresh` documents a batch while the 60 known hosts stay recent."""
+    out = []
+    for b, c in enumerate(_chunks(n_chunks, seed=seed)):
+        c = c.copy()
+        c.loc[:fresh - 1, "sip"] = [f"11.{b}.{i // 256}.{i % 256}"
+                                    for i in range(fresh)]
+        out.append(c)
+    return out
+
+
+def _evictions(sc: StreamingScorer) -> list:
+    """The table's keys, stamps and rows each time an eviction fires,
+    read where it compacts the table (the state pulled to the host)."""
+    seen, compact = [], sc.docs.compact
+
+    def spy(keep_mask):
+        n = sc.docs.n_docs
+        seen.append((sc.docs.keys.copy(), sc._last_seen[:n].copy(),
+                     sc._gamma[:n].copy(), sc._batch_no))
+        return compact(keep_mask)
+    sc.docs.compact = spy
+    return seen
+
+
+def test_resident_eviction_keeps_the_latest_ties_to_the_lower_id():
+    """The survivors of an eviction are the 0.75 max_docs documents seen
+    last, those seen in the batch of the last survivor's sighting by
+    ascending id; they keep first-seen order under new ids, their rows
+    and stamps bit for bit, and every row past them holds the cold
+    start."""
+    import jax.numpy as jnp
+    sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS)
+    sc.process_many([(c, None) for c in _churn_chunks(4, 40)])
+    sc._pull_resident()
+    n, cap = sc.docs.n_docs, sc._res.store.shape[0]
+    stamps = np.random.default_rng(3).integers(1, 5, n)     # many ties
+    sc._res.last_seen = jnp.asarray(
+        np.pad(stamps, (0, cap - n)).astype(np.int32))
+    keys0, rows0 = sc.docs.keys.copy(), np.asarray(sc._res.store)[:n].copy()
+    sc.max_docs = n - 1
+    target = int(0.75 * (n - 1))
+    assert sc._maybe_evict() == n - target
+    keep = np.flatnonzero(np.isin(keys0, sc.docs.keys))
+    assert len(keep) == target
+    np.testing.assert_array_equal(sc.docs.keys, keys0[keep])
+    gone = np.setdiff1d(np.arange(n), keep)
+    cut = stamps[keep].min()
+    assert stamps[gone].max() <= cut
+    assert keep[stamps[keep] == cut].max() < gone[stamps[gone] == cut].min()
+    snap = sc.snapshot_resident()           # the compacted state pushed
+    store, seen = np.asarray(snap["store"]), np.asarray(snap["last_seen"])
+    np.testing.assert_array_equal(store[:target], rows0[keep])
+    np.testing.assert_array_equal(seen[:target], stamps[keep])
+    assert (store[target:] == np.float32(sc.cfg.lda.alpha + 1.0)).all()
+
+
+def test_resident_evicted_address_returns_under_a_new_id_cold():
+    """An address the eviction dropped that comes back is a new
+    document: the next id, and the cold start before its batch."""
+    chunks = _churn_chunks(6, 40)
+    sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS, max_docs=150)
+    fired = _evictions(sc)
+    sc.process_many([(c, None) for c in chunks[:4]])
+    assert fired
+    keys0 = fired[-1][0]
+    gone = np.setdiff1d(keys0, sc.docs.keys)
+    assert len(gone)
+    from onix.pipelines.words import u32_to_ips
+    back = chunks[5].copy()
+    back.loc[len(back) - 1, "sip"] = u32_to_ips(gone[:1])[0]
+    n_before = sc.docs.n_docs
+    sc.process_many([(chunks[4], None), (back, None)])
+    # Its token's store row in the group, whatever an eviction at the
+    # group's end did to the ids after it.
+    new_id = int(np.asarray(sc.before_last_batch[2])[len(back) - 1])
+    assert new_id >= n_before
+    store = np.asarray(sc.before_last_batch[1])
+    assert (store[new_id] == np.float32(sc.cfg.lda.alpha + 1.0)).all()
+
+
+def test_resident_regrow_starts_new_documents_cold():
+    """A group whose new addresses outgrow the store's rows: the store
+    regrows before the group runs, and every document first seen in its
+    last batch - those past the old capacity too - starts from alpha + 1."""
+    early = _chunks(3, n_hosts=40, seed=5)
+    late = _chunks(2, n_hosts=400, seed=6)
+    sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS)
+    sc.process_many([(c, None) for c in early[:1] + [early[1]]])
+    cap = sc._res.store.shape[0]
+    out = sc.process_many([(early[2], None), (late[0], None)])
+    assert sc._res.store.shape[0] > cap             # the rows ran out
+    first_new = sc.docs.n_docs - out[-1].n_new_docs
+    assert sc.docs.n_docs > cap and first_new < cap
+    store = np.asarray(sc.before_last_batch[1])
+    fresh = store[first_new:sc.docs.n_docs]
+    assert (fresh == np.float32(sc.cfg.lda.alpha + 1.0)).all()
+
+
+def test_resident_eviction_matches_host_path_where_boundaries_meet():
+    """A table that crosses max_docs at the last batch of a group, each
+    time: the per-batch host path evicts after the same batches, and the
+    winners, stamps, keys and rows are the same."""
+    chunks = _churn_chunks(6, 100, seed=8)
+    host = StreamingScorer(_cfg(0), "flow", n_buckets=N_BUCKETS, max_docs=450)
+    host_fired = _evictions(host)
+    res_a = [host.process(c) for c in chunks]
+    res = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS, max_docs=450)
+    res_fired = _evictions(res)
+    res_b = res.process_many([(c, None) for c in chunks])
+    assert [f[3] for f in host_fired] == [f[3] for f in res_fired] == [4, 6]
+    for a, b in zip(host_fired, res_fired):
+        np.testing.assert_array_equal(a[0], b[0])
+        np.testing.assert_array_equal(a[1], b[1])
+    _assert_same_stream(res_a, res_b)
+    _assert_same_state(host, res)
+
+
+def test_restore_resident_puts_the_table_back_with_the_state():
+    """A snapshot carries the document table: a group that grew the
+    table and evicted, run again from the snapshot taken before it,
+    gives the same winners, table, stamps and rows."""
+    chunks = _churn_chunks(6, 60, seed=12)
+    sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS, max_docs=150)
+    sc.process_many([(c, None) for c in chunks[:2]])
+    snap = sc.snapshot_resident()
+    cap, n = snap["store"].shape[0], len(snap["doc_keys"])
+    fired = _evictions(sc)
+    first = sc.process_many([(c, None) for c in chunks[2:4]])
+    once = sc.snapshot_resident()
+    assert len(fired) == 1 and len(fired[0][0]) > n
+    sc.restore_resident(snap)
+    np.testing.assert_array_equal(sc.docs.keys, snap["doc_keys"])
+    assert sc._res.store.shape[0] == cap
+    again = sc.process_many([(c, None) for c in chunks[2:4]])
+    assert [_winners(r) for r in first] == [_winners(r) for r in again]
+    twice = sc.snapshot_resident()
+    np.testing.assert_array_equal(twice["doc_keys"], once["doc_keys"])
+    for name in ("store", "last_seen"):
+        np.testing.assert_array_equal(np.asarray(twice[name]),
+                                      np.asarray(once[name]))
+
+
+def test_rows_no_document_owns_hold_the_cold_start():
+    """One fill value, alpha + 1, for every row past the table: at
+    construction, after a regrow and after an eviction."""
+    cold = np.float32(_cfg().lda.alpha + 1.0)
+    sc = StreamingScorer(_cfg(0), "flow", n_buckets=N_BUCKETS, max_docs=150)
+    assert (sc._gamma == cold).all()
+    for c in _churn_chunks(4, 60, seed=13):
+        sc.process(c)
+        n = sc.docs.n_docs
+        assert (sc._gamma[n:] == cold).all()
+    assert sc.docs.n_docs <= 150
+
+
 def test_resident_stages_the_next_group_ahead():
     """`stage_next` (what run_stream passes) is staged and probed under
     the running superstep and used by the next call; the answers are
