@@ -128,6 +128,16 @@ from onix.models.compaction import (compact_front, ladder_index,  # noqa: E402
 # its scores go over every token slot.
 _TOKEN_RUN = 1 << 17
 
+# `svi_store_step`'s E-step works a table of the batch's own documents,
+# this many rows of it (`_gamma_group(K)` documents a row: 2^18 at K = 20,
+# 2^16 where a row is one document), and not the whole store, once the
+# store holds more. A row is 128 float32 lanes on the chip, so the table
+# and its E[log theta] are 32 MiB each: the size of the catch-up cell's
+# packed `[65536, 128]` tables, which the compiler keeps in fast memory,
+# `S(1)` (PERF.md section 6), where the same tables at 2^20 or
+# 2^21 store rows fall to HBM and a row gather costs 8.6 ns for 1.8-2.0.
+_WORKING_ROWS = 1 << 16
+
 
 def _token_runs(n: int) -> int:
     """How many runs a pass over `n` tokens is made in: one (the whole
@@ -692,16 +702,23 @@ def svi_store_step(
     touches none). Padding tokens may point at any row.
 
     Returns (state, store, touched bool [cap], scores float32 [T],
-    stats), `stats` as `_run_e_step(with_stats=True)` gives it: the
+    stats, work), `stats` as `_run_e_step(with_stats=True)` gives it: the
     passes over every pair, the passes of the extended loop, the TOKENS
     of its active set (the sum of its pairs' weights), the batch's
-    pairs, the active set's pairs.
+    pairs, the active set's pairs; `work` (int32 scalars) the rounds of
+    the working table (0 where the E-step worked the store itself) and
+    the documents the batch touches.
 
     The lambda step and the scores work the rows the E-step carries
     gamma in (the comment above `_gamma_group`): the lambda step the
     E-step's own packed gamma, the scores the updated store packed once;
-    the same sums in another order."""
+    the same sums in another order.
+
+    A store of more than `_WORKING_ROWS` rows (of `_gamma_group(K)`
+    documents) is not worked whole: the E-step and the lambda step run
+    on a working table of the batch's documents, `_in_rounds`."""
     lam = state.lam
+    cap, k = store.shape
     with device_scope("onix.svi.estep"):
         with device_scope("onix.svi.estep.pairs"):
             p_doc, p_word, p_weight, n_pairs = _unique_pairs(
@@ -709,29 +726,99 @@ def svi_store_step(
         pair_runs = _runs_holding(n_pairs, p_doc.shape[0])
         touched = _in_token_runs(
             lambda acc, d, m: (acc.at[d].add(m), None),
-            jnp.zeros((store.shape[0],), jnp.float32), (p_doc, p_weight),
+            jnp.zeros((cap,), jnp.float32), (p_doc, p_weight),
             pair_runs)[0] > 0.0
         elog_beta = (jnp.log(lam / lam.sum(axis=0, keepdims=True))
                      if estep_form == "scvb0"
                      else _e_log_dirichlet(lam, axis=0))
-        gamma, stats, gamma_rows = _run_e_step(
-            jnp.where(touched[:, None], store, alpha), p_word,
-            p_doc, p_weight, alpha=alpha, local_iters=local_iters,
-            meanchange_tol=meanchange_tol, warm_iters=warm_iters,
-            estep_form=estep_form, with_stats=True, elog_beta=elog_beta,
-            n_rows=n_pairs, with_carried=True)
+        n_docs = touched.sum(dtype=jnp.int32)
+    e_kw = dict(alpha=alpha, local_iters=local_iters,
+                meanchange_tol=meanchange_tol, warm_iters=warm_iters,
+                estep_form=estep_form, with_stats=True, elog_beta=elog_beta,
+                n_rows=n_pairs, with_carried=True)
+    slots = _WORKING_ROWS * _gamma_group(k)
+    if cap <= slots:
+        with device_scope("onix.svi.estep"):
+            gamma, stats, gamma_rows = _run_e_step(
+                jnp.where(touched[:, None], store, alpha), p_word,
+                p_doc, p_weight, **e_kw)
+        with device_scope("onix.svi.lambda"):
+            sstats = _pair_sstats(gamma_rows, elog_beta, p_doc, p_word,
+                                  p_weight, pair_runs, estep_form=estep_form)
+            store = jnp.where(touched[:, None], gamma, store)
+        rounds = jnp.int32(0)
+    else:
+        store, sstats, stats, rounds = _in_rounds(
+            store, touched, n_docs, slots, p_doc, p_word, p_weight,
+            pair_runs, e_kw)
     with device_scope("onix.svi.lambda"):
-        sstats = _pair_sstats(gamma_rows, elog_beta, p_doc, p_word, p_weight,
-                              pair_runs, estep_form=estep_form)
         n_real = touched.sum().astype(jnp.float32)
         scale = corpus_docs / jnp.maximum(n_real, 1.0)
         rho = (tau0 + state.step.astype(jnp.float32)) ** (-kappa)
         lam = (1.0 - rho) * lam + rho * (eta + scale * sstats)
-        store = jnp.where(touched[:, None], gamma, store)
     with device_scope("onix.svi.score"):
         scores = _slot_scores(store, lam, doc_ids, word_ids)
     return (SVIState(lam=lam, step=state.step + 1), store, touched, scores,
-            stats)
+            stats, (rounds, n_docs))
+
+
+def _in_rounds(store, touched, n_docs, slots: int, p_doc, p_word, p_weight,
+               pair_runs, e_kw):
+    """`svi_store_step`'s E-step and lambda statistics on a working table
+    of `slots` rows (static) in place of the store's `cap`: a document's
+    slot is its rank among the rows the batch touches, round j works the
+    documents of rank [j slots, (j + 1) slots), with the pairs of the
+    other rounds at weight 0, and writes their rows back into the store.
+    A round is the store-sized E-step run on its documents alone (its own
+    warm passes and extended loop: each document still meets the
+    per-document rule); the batches of the stream cells hold one round.
+    Slots no document holds start from alpha, as an untouched row does.
+
+    Returns (store, lambda statistics [V, K] summed over the rounds,
+    stats summed over the rounds as `_run_e_step` gives them (the pairs
+    once), the rounds run)."""
+    from onix.pipelines.device_words import _running_sum
+
+    cap = store.shape[0]
+    elog_beta, alpha = e_kw["elog_beta"], e_kw["alpha"]
+    with device_scope("onix.svi.estep"):
+        rank = _running_sum(touched.astype(jnp.int32)) - 1
+        ranks = -(-cap // slots) * slots
+        # Each rank's store row; `cap` past the last document.
+        row_of = jnp.full((ranks,), cap, jnp.int32).at[
+            jnp.where(touched, rank, ranks)].set(
+                jax.lax.iota(jnp.int32, cap), mode="drop")
+        p_rank = _in_token_runs(lambda _, d: (None, rank[d]), None,
+                                (p_doc,), pair_runs)[1]
+        n_rounds = jnp.maximum(-(-n_docs // slots), 1)
+
+    def round_(carry):
+        j, store, sstats, stats = carry
+        with device_scope("onix.svi.estep"):
+            local = p_rank - j * slots
+            mine = (local >= 0) & (local < slots)
+            slot = jnp.where(mine, local, 0)
+            weight = jnp.where(mine, p_weight, 0.0)
+            rows = jax.lax.dynamic_slice_in_dim(row_of, j * slots, slots)
+            held = rows < cap
+            gamma0 = jnp.where(held[:, None],
+                               store[jnp.minimum(rows, cap - 1)], alpha)
+            gamma, st, carried = _run_e_step(gamma0, p_word, slot, weight,
+                                             **e_kw)
+            store = store.at[rows].set(gamma, mode="drop")
+        with device_scope("onix.svi.lambda"):
+            sstats = sstats + _pair_sstats(
+                carried, elog_beta, slot, p_word, weight, pair_runs,
+                estep_form=e_kw["estep_form"])
+        return (j + 1, store, sstats,
+                tuple(a + b for a, b in zip(stats, st)))
+
+    with device_scope("onix.svi.estep"):
+        stats0 = tuple(jnp.int32(0) for _ in range(5))
+        j, store, sstats, stats = jax.lax.while_loop(
+            lambda c: c[0] < n_rounds, round_,
+            (jnp.int32(0), store, jnp.zeros_like(elog_beta), stats0))
+    return store, sstats, stats[:3] + (e_kw["n_rows"], stats[4]), j
 
 
 def _pair_sstats(gamma_rows, elog_beta, p_doc, p_word, p_weight,

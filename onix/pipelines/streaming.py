@@ -45,7 +45,9 @@ Streaming-specific design (vs the batch path in pipelines/run.py):
   the document look-up in the device copy of the sorted document table
   (`device_words._lookup_sorted`), the E-step from the documents' rows
   of the WHOLE gamma store (`lda_svi.svi_store_step`: Hoffman's update,
-  the warm/cold compacted split of `_run_e_step`), the natural-gradient
+  the warm/cold compacted split of `_run_e_step`; a store past
+  `lda_svi._WORKING_ROWS` rows lends the batch's documents' rows to a
+  working table and takes them back), the natural-gradient
   lambda step, the incremental scores under the updated model, the
   pair-min of an event's two tokens, the tolerance filter and the exact
   bottom `max_results` (`scoring._scan_bottom_k`). The store, the
@@ -239,8 +241,11 @@ def stream_svi_step(state: SVIState, store, last_seen, doc_keys, doc_ids,
     starts from); `out` per batch the winners
     (`scores`, `indices` [S, max_results], ascending, -1 where fewer
     qualified), every event's score `events` [S, E], the E-step's
-    `stats` [S, 5] (lda_svi.svi_store_step) and `misses` [S], the tokens
-    whose address the table lacked (0 when the host has done its part).
+    `stats` [S, 5] (lda_svi.svi_store_step), the rounds of its working
+    table `estep_rounds` [S] (0 where it worked the store itself) and
+    the documents it worked `estep_docs` [S], and `misses` [S], the
+    tokens whose address the table lacked (0 when the host has done its
+    part).
     """
     n_pad = cols["sip_u32"].shape[1]
 
@@ -255,7 +260,7 @@ def stream_svi_step(state: SVIState, store, last_seen, doc_keys, doc_ids,
                 salt=salt, n_buckets=n_buckets)
         did, valid2, missed = _batch_doc_ids(
             doc_keys, doc_ids, c["sip_u32"], c["dip_u32"], n)
-        st, store, touched, tok, stats = svi_store_step(
+        st, store, touched, tok, stats, (rounds, docs) = svi_store_step(
             st, store, did, jnp.concatenate([wid, wid]),
             valid2.astype(jnp.float32), cdocs, **svi)
         with device_scope("onix.svi.score"):
@@ -269,7 +274,8 @@ def stream_svi_step(state: SVIState, store, last_seen, doc_keys, doc_ids,
         seen = jnp.where(touched, bno, seen)
         return (st, store, seen, (lam0, store0, did)), {
             "scores": top.scores, "indices": top.indices, "events": ev,
-            "stats": jnp.stack(stats), "misses": jnp.sum(missed)}
+            "stats": jnp.stack(stats), "estep_rounds": rounds,
+            "estep_docs": docs, "misses": jnp.sum(missed)}
 
     (state, store, last_seen, prev), out = jax.lax.scan(
         batch, (state, store, last_seen,
@@ -1655,9 +1661,9 @@ class StreamingScorer:
         # THE one fetch per superstep: the winners, and the counters
         # the program returns beside them.
         with telemetry.TRACER.span("stream.fetch", batches=s):
-            top_s, top_i, stats, missed = jax.device_get(
+            top_s, top_i, stats, rounds, docs, missed = jax.device_get(
                 (out["scores"], out["indices"], out["stats"],
-                 out["misses"]))
+                 out["estep_rounds"], out["estep_docs"], out["misses"]))
         self.stage_walls["svi_update"] += time.perf_counter() - t0
         if missed.any():
             raise RuntimeError(
@@ -1685,6 +1691,8 @@ class StreamingScorer:
         pairs = int(stats[:, 3].sum())
         counters.inc("stream.pair_rows", pairs)
         counters.inc("stream.active_pairs", int(stats[:, 4].sum()))
+        counters.inc("stream.estep_rounds", int(rounds.sum()))
+        counters.inc("stream.estep_docs", int(docs.sum()))
         self.pair_rows += pairs
         self._batch_no += s
         self.stage_walls["emit"] += time.perf_counter() - t0

@@ -6,6 +6,8 @@ benchmark's `flow-stream-catchup` cell. `--mode` picks the pass:
     python3 scripts/exp_stream_pack.py --out <dir>                  # E-step
     python3 scripts/exp_stream_pack.py --mode lambda --out <dir>
     python3 scripts/exp_stream_pack.py --mode score --out <dir>
+    python3 scripts/exp_stream_pack.py --forms packed,local \
+        --cap 262144,1048576,2097152 --out <dir>     # the working table
 
 A batch is made as the cell's program sees it after the dedupe: 2^23 pair
 slots, 2,340,000 weighted (document, word) pairs in front of them, word by
@@ -21,6 +23,13 @@ each and 24 server documents with the rest; 32768 words, K = 20.
   freeze). A pass costs the difference of the two programs' device times
   over the difference of the pair rows they worked, which takes the
   pack, the unpack, the compaction's sort and the first pass out.
+  `--cap` reads it at other store capacities (a comma list: one reading
+  each), the batch's documents spread over the store's rows, as a
+  stream whose table has grown holds them; the form `local` runs the
+  passes as `svi_store_step` does past `lda_svi._WORKING_ROWS` packed
+  rows, on a working table of the batch's documents gathered from the
+  store and written back (`lda_svi._in_rounds`, its lambda statistics
+  with it), packed; `packed` works the whole store.
 * `lambda`: `lda_svi._pair_sstats` over the pairs, from the store packed
   as the E-step hands gamma over: one program a form, run over the
   first `--short-pairs` pairs and over all of them (the pass stops after
@@ -59,11 +68,13 @@ N_PAIRS, CLIENTS, SERVERS, SERVER_PAIRS = 2_340_000, 196_103, 24, 30_000
 EVENTS = 3_472_222
 
 
-def batch(seed: int, scale: float):
+def batch(seed: int, scale: float, cap: int = CAP):
     """(store [cap, K], lam [V, K], elog_beta [V, K], docs, words,
-    weights [slots], n_pairs) at the cell's shapes times `scale`."""
+    weights [slots], n_pairs) at the cell's shapes times `scale`; past
+    `CAP` store rows the documents lie on rows drawn from the seed."""
     rng = np.random.default_rng(seed)
-    cap = max(64, int(CAP * scale))
+    spread = cap > CAP
+    cap = max(64, int(cap * scale))
     slots = max(1024, int(SLOTS * scale))
     n_pairs = int(N_PAIRS * scale)
     clients = max(8, int(CLIENTS * scale))
@@ -72,6 +83,9 @@ def batch(seed: int, scale: float):
     d = np.concatenate([
         rng.integers(0, clients, n_pairs - server_pairs),
         clients + rng.integers(0, SERVERS, server_pairs)]).astype(np.int32)
+    if spread:
+        d = rng.choice(cap - 1, clients + SERVERS,
+                       replace=False).astype(np.int32)[d]
     w = rng.integers(0, words_n, n_pairs).astype(np.int32)
     order = np.lexsort((d, w))
     pad = slots - n_pairs
@@ -108,15 +122,32 @@ def token_slots(seed: int, scale: float, cap: int, words_n: int):
 
 def program(mode: str, form: str, size: int):
     """The jitted unit of `mode` in `form` ("packed" as the chip takes it
-    at K=20, "rows" one document a row, as K over 64, and the CPU, run)
-    and the patch that traces that form; `size` the passes (estep) or
-    the token slots (score), and nothing for lambda, whose size is an
-    operand."""
+    at K=20, "rows" one document a row, as K over 64, and the CPU, run;
+    "local" packed, on the working table) and the patch that traces that
+    form; `size` the passes (estep) or the token slots (score), and
+    nothing for lambda, whose size is an operand."""
     from unittest import mock
+
+    import jax.numpy as jnp
 
     from onix.models import lda_gibbs, lda_svi
 
-    if mode == "estep":
+    if mode == "estep" and form == "local":
+        def run(store, elog_beta, docs, words, weights, n_pairs):
+            cap, k = store.shape
+            touched = jnp.zeros((cap,), jnp.float32).at[docs].add(
+                weights) > 0.0
+            new, _, stats, _ = lda_svi._in_rounds(
+                store, touched, touched.sum(dtype=jnp.int32),
+                lda_svi._WORKING_ROWS * lda_svi._gamma_group(k), docs,
+                words, weights, lda_svi._runs_holding(n_pairs, docs.shape[0]),
+                dict(alpha=1.2, local_iters=size, meanchange_tol=1e-30,
+                     warm_iters=1, estep_form="svi", with_stats=True,
+                     elog_beta=elog_beta, n_rows=n_pairs, with_carried=True))
+            # Untouched rows as `_run_e_step` hands them back.
+            return jnp.where(touched[:, None], new, 1.2), stats
+        run.__name__ = f"unit_local_{size}"
+    elif mode == "estep":
         def run(store, elog_beta, docs, words, weights, n_pairs):
             return lda_svi._run_e_step(
                 store, words, docs, weights, alpha=1.2, local_iters=size,
@@ -151,35 +182,20 @@ def fast_memory(hlo: str) -> list[str]:
     return sorted(shapes, key=lambda s: -size(s))
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=("estep", "lambda", "score"),
-                    default="estep")
-    ap.add_argument("--out", default=".bench_trace/stream_pack")
-    ap.add_argument("--forms", default="rows,packed")
-    ap.add_argument("--short", type=int, default=3)
-    ap.add_argument("--long", type=int, default=9)
-    ap.add_argument("--short-pairs", type=int, default=N_PAIRS - 8 * (1 << 17),
-                    help="lambda: the pairs of the shorter reading")
-    ap.add_argument("--reps", type=int, default=2)
-    ap.add_argument("--seed", type=int, default=4000100101)
-    ap.add_argument("--scale", type=float, default=1.0,
-                    help="shrink the batch (a CPU rehearsal)")
-    args = ap.parse_args()
-
+def reading(args, cap: int, seed: int) -> dict:
+    """One reading of `args.mode` at a store of `cap` rows: every form
+    at both sizes, and the per-unit costs from their differences."""
     import jax
     import jax.numpy as jnp
     import jax.profiler
 
-    from benchmark import harness, tracered
+    from benchmark import tracered
 
-    os.makedirs(args.out, exist_ok=True)
     dev = jax.devices()[0]
     on_chip = dev.platform == "tpu"
     t0 = time.monotonic()
-    seed = harness.fold_seed(args.seed)
     store, lam, elog_beta, docs, words, weights, n_pairs = batch(
-        seed, args.scale)
+        seed, args.scale, cap)
     forms = args.forms.split(",")
     if args.mode == "estep":
         unit = "pair_row_pass"
@@ -202,12 +218,6 @@ def main() -> int:
               "store_rows": store.shape[0], "slots": docs.shape[0],
               "pairs": n_pairs, "batch_s": time.monotonic() - t0,
               "programs": {}}
-    # A profiler session's first program may come back without its
-    # module's events: one session ahead of the readings.
-    jax.profiler.start_trace(os.path.join(args.out, "trace.warm"))
-    jax.block_until_ready(jnp.zeros(8) + 1)
-    jax.profiler.stop_trace()
-    shutil.rmtree(os.path.join(args.out, "trace.warm"), ignore_errors=True)
     outs, compiled_by_name = {}, {}
     for form in forms:
         for size in sizes:
@@ -223,7 +233,7 @@ def main() -> int:
                     compiled = jax.jit(fn).lower(*ops).compile()
                 rec["compile_s"] = time.monotonic() - t0
                 hlo = compiled.as_text()
-                with open(os.path.join(args.out, f"{name}.hlo.txt"),
+                with open(os.path.join(args.out, f"{name}.{cap}.hlo.txt"),
                           "w") as fh:
                     fh.write(hlo)
                 rec["fast_memory"] = fast_memory(hlo)
@@ -261,7 +271,7 @@ def main() -> int:
             key = f"{form}_{size}"
             outs[key] = np.asarray(out)
             result["programs"][key] = rec
-            print(json.dumps({key: rec}), flush=True)
+            print(json.dumps({f"{cap}.{key}": rec}), flush=True)
     for form in forms:
         a = result["programs"][f"{form}_{sizes[0]}"]
         b = result["programs"][f"{form}_{sizes[1]}"]
@@ -275,7 +285,47 @@ def main() -> int:
         result["max_abs_diff"] = float(np.abs(a - b).max())
         result["max_rel_diff"] = float(
             (np.abs(a - b) / np.maximum(np.abs(b), 1e-30)).max())
-    with open(os.path.join(args.out, f"stream_pack.{args.mode}.json"),
+    return result
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--mode", choices=("estep", "lambda", "score"),
+                    default="estep")
+    ap.add_argument("--out", default=".bench_trace/stream_pack")
+    ap.add_argument("--forms", default="rows,packed")
+    ap.add_argument("--cap", default=str(CAP),
+                    help="the store's rows, a comma list: a reading each")
+    ap.add_argument("--short", type=int, default=3)
+    ap.add_argument("--long", type=int, default=9)
+    ap.add_argument("--short-pairs", type=int, default=N_PAIRS - 8 * (1 << 17),
+                    help="lambda: the pairs of the shorter reading")
+    ap.add_argument("--reps", type=int, default=2)
+    ap.add_argument("--seed", type=int, default=4000100101)
+    ap.add_argument("--scale", type=float, default=1.0,
+                    help="shrink the batch (a CPU rehearsal)")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import jax.profiler
+
+    from benchmark import harness
+
+    os.makedirs(args.out, exist_ok=True)
+    seed = harness.fold_seed(args.seed)
+    # A profiler session's first program may come back without its
+    # module's events: one session ahead of the readings.
+    jax.profiler.start_trace(os.path.join(args.out, "trace.warm"))
+    jax.block_until_ready(jnp.zeros(8) + 1)
+    jax.profiler.stop_trace()
+    shutil.rmtree(os.path.join(args.out, "trace.warm"), ignore_errors=True)
+    caps = [int(c) for c in args.cap.split(",")]
+    results = {cap: reading(args, cap, seed) for cap in caps}
+    result = results[caps[0]] if len(caps) == 1 else {
+        str(cap): r for cap, r in results.items()}
+    suffix = "" if caps == [CAP] else ".cap"
+    with open(os.path.join(args.out, f"stream_pack.{args.mode}{suffix}.json"),
               "w") as fh:
         json.dump(result, fh, indent=1)
     print(json.dumps(result))

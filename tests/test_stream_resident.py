@@ -178,6 +178,46 @@ def test_resident_packed_matches_rows_form(monkeypatch, estep):
     _assert_same_state(packed, rows)
 
 
+@pytest.mark.parametrize("table", ["store", "one_round", "rounds"])
+def test_resident_counts_the_working_table(monkeypatch, table):
+    """The counters `stream.estep_rounds` and `stream.estep_docs` read
+    each resident batch's rounds of the E-step's working table and its
+    documents (its distinct addresses): no round where the store is
+    within `lda_svi._WORKING_ROWS` rows, one where the batch's documents
+    fit the table and the store does not - the store path's answers and
+    state, to float32 rounding - and one per table of them past that."""
+    from onix.models import lda_svi
+    from onix.utils.obs import counters
+
+    chunks = _chunks(7)
+    docs = [len(set(c.sip) | set(c.dip)) for c in chunks[1:]]
+    store = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)
+    res_store = store.process_many([(c, None) for c in chunks])
+    cap = store._res.store.shape[0]
+    assert max(docs) < cap <= lda_svi._WORKING_ROWS
+    rounds = [0] * len(docs)
+    res = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)
+    if table != "store":
+        slots = max(docs) if table == "one_round" else 32
+        assert slots < cap
+        monkeypatch.setattr(lda_svi, "_WORKING_ROWS", slots)
+        rounds = [-(-n // slots) for n in docs]
+        # Another tolerance makes another program: the patched size is
+        # read when it is traced.
+        res._step_kw["tol"] = 0.25 + {"one_round": 4e-9, "rounds": 5e-9}[
+            table]
+    at = {c: counters.get(c) for c in ("stream.estep_rounds",
+                                       "stream.estep_docs")}
+    res_b = res.process_many([(c, None) for c in chunks])
+    got = {c: counters.get(c) - v for c, v in at.items()}
+    assert got["stream.estep_docs"] == sum(docs)
+    assert got["stream.estep_rounds"] == sum(rounds)
+    assert (table == "rounds") == (max(rounds) > 1)
+    if table == "one_round":
+        _assert_same_stream(res_store, res_b)
+        _assert_same_state(store, res)
+
+
 def test_resident_scores_are_fetched_when_read():
     chunks = _chunks(4)
     res = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)

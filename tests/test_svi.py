@@ -189,7 +189,7 @@ def _store_chain(cfg):
     new_state, store, scores = state, jnp.asarray(store0), []
     pad = pad_to - 200
     for d, w in zip(gds, gws):
-        new_state, store, touched, sc, stats = svi_store_step(
+        new_state, store, touched, sc, stats, _ = svi_store_step(
             new_state, store,
             jnp.asarray(np.concatenate([d, np.full(pad, cap - 1, np.int32)])),
             jnp.asarray(np.concatenate([w, np.zeros(pad, np.int32)])),
@@ -361,7 +361,7 @@ def test_store_step_dedupes_as_the_host_path_does(estep, kind):
         jnp.asarray(theta), phi, batch.doc_ids,
         batch.word_ids))[:len(uniq)][inv]
 
-    got_state, store, touched, scores, stats = _store_step(
+    got_state, store, touched, scores, stats, _ = _store_step(
         cfg, state, store0, d, w, m)
     assert set(np.flatnonzero(np.asarray(touched))) == set(np.unique(did_b))
     np.testing.assert_allclose(np.asarray(got_state.lam),
@@ -698,3 +698,100 @@ def test_store_step_packed_lambda_and_scores_keep_their_scopes(monkeypatch):
     assert ops("gather", "onix.svi.lambda", "slice_sizes={1,128}")
     assert len(ops("gather", "onix.svi.score", "slice_sizes={1,128}")) >= 1
     assert not ops("gather", "onix.svi.score", "slice_sizes={1,20}")
+
+
+# -- the E-step on a working table of the batch's documents ---------------
+
+
+def _working_case(form: str, estep: str):
+    """(cfg, state, store0, batch, rows a document) of `one_document_half`
+    over the 32-row store: K=4 one document a row, K=20 four (the chip's
+    packed form)."""
+    k = {"rows": 4, "packed": 20}[form]
+    cfg = LDAConfig(n_topics=k, svi_meanchange_tol=1e-4, svi_local_iters=30,
+                    svi_warm_iters=2, seed=3, stream_estep=estep)
+    state = SVILda(cfg, n_vocab=_PAIR_V, corpus_docs=100).init()
+    store0 = (cfg.alpha + np.random.default_rng(5).gamma(
+        2.0, 2.0, (_PAIR_CAP, k))).astype(np.float32)
+    group = 1 if form == "rows" else _chip_group(k)
+    return cfg, state, store0, _pair_batch("one_document_half"), group
+
+
+@pytest.mark.parametrize("rounds", ["one", "several"])
+@pytest.mark.parametrize("form", ["rows", "packed"])
+@pytest.mark.parametrize("estep", ["svi", "scvb0"])
+def test_store_step_on_a_working_table(monkeypatch, estep, form, rounds):
+    """A store of more rows than `_WORKING_ROWS` (patched small) is
+    worked through a table of the batch's documents. In one round the
+    step leaves the store-sized step's lambda, store, scores and stats,
+    bit for bit one document a row and to float32 rounding packed; in
+    several, each round's rows are the store-sized step's run on that
+    round's documents alone. Rows the batch does not touch keep their
+    bits; `work` reads the rounds and the batch's documents."""
+    from onix.models import lda_svi
+
+    cfg, state, store0, (d, w, m), group = _working_case(form, estep)
+    monkeypatch.setattr(lda_svi, "_gamma_group",
+                        lambda k: group)
+    docs = np.unique(d[m > 0])
+    assert len(docs) == 12
+    whole = _store_step(cfg, state, store0, d, w, m)
+    assert [int(x) for x in whole[5]] == [0, 12]
+    slots = {"one": 16, "several": 4}[rounds]
+    monkeypatch.setattr(lda_svi, "_WORKING_ROWS", slots // group)
+    got = _store_step(cfg, state, store0, d, w, m)
+    assert [int(x) for x in got[5]] == [-(-len(docs) // slots), 12]
+    store = np.asarray(got[1])
+    untouched = np.setdiff1d(np.arange(_PAIR_CAP), docs)
+    np.testing.assert_array_equal(store[untouched], store0[untouched])
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(whole[2]))
+    same = (np.testing.assert_array_equal if form == "rows" else
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
+                                                    atol=1e-6))
+    if rounds == "one":
+        same(np.asarray(got[0].lam), np.asarray(whole[0].lam))
+        same(store, np.asarray(whole[1]))
+        same(np.asarray(got[3]), np.asarray(whole[3]))
+        np.testing.assert_array_equal(np.asarray(got[4]),
+                                      np.asarray(whole[4]))
+        return
+    # Round j holds the documents of rank [4 j, 4 j + 4).
+    monkeypatch.setattr(lda_svi, "_WORKING_ROWS", 1 << 16)
+    for j in range(3):
+        mine = docs[4 * j:4 * j + 4]
+        alone = _store_step(cfg, state, store0, d, w,
+                            np.where(np.isin(d, mine), m, 0.0))
+        assert [int(x) for x in alone[5]] == [0, 4]
+        same(store[mine], np.asarray(alone[1])[mine])
+
+
+@pytest.mark.parametrize("form", ["rows", "packed"])
+def test_store_within_the_working_table_takes_the_store_path(monkeypatch,
+                                                             form):
+    """A store of at most `_WORKING_ROWS` rows (of its documents) is
+    worked whole, as before the working table: no round is read, and
+    the step leaves what the same batch forced through the working table
+    leaves, to float32 rounding; the store-sized program holds no
+    `while` of rounds."""
+    import functools
+
+    import jax
+
+    from onix.models import lda_svi
+
+    cfg, state, store0, batch, group = _working_case(form, "svi")
+    monkeypatch.setattr(lda_svi, "_gamma_group", lambda k: group)
+    monkeypatch.setattr(lda_svi, "_WORKING_ROWS", _PAIR_CAP // group)
+    whole = _store_step(cfg, state, store0, *batch)
+    assert [int(x) for x in whole[5]] == [0, 12]
+    text = jax.jit(functools.partial(_store_step, cfg)).lower(
+        state, store0, *batch).as_text()
+    monkeypatch.setattr(lda_svi, "_WORKING_ROWS", _PAIR_CAP // group - 1)
+    forced = _store_step(cfg, state, store0, *batch)
+    assert [int(x) for x in forced[5]] == [1, 12]
+    forced_text = jax.jit(functools.partial(_store_step, cfg)).lower(
+        state, store0, *batch).as_text()
+    assert forced_text.count("while") > text.count("while")
+    for a, b in zip(jax.tree.leaves(whole[:5]), jax.tree.leaves(forced[:5])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
