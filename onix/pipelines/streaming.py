@@ -59,8 +59,10 @@ Streaming-specific design (vs the batch path in pipelines/run.py):
   per unique address or rare: growth of the document table (a probe of
   the group staged ahead, `stream_docs_probe`, says which of its tokens
   carry an unseen address, and the host inserts those and nothing else;
-  a group whose turn comes unprobed is looked up against the host's own
-  table), eviction, alert rows, checkpoints. `self._gamma` and `self._last_seen` are filled from the
+  an eviction keeps the staged columns and the device probes them again
+  against the compacted table; only a group whose probe has never run
+  at its shape is looked up against the host's own table), eviction,
+  alert rows, checkpoints. `self._gamma` and `self._last_seen` are filled from the
   device at checkpoints, at eviction and when a batch takes the host
   path. The program is handed every token with weight 1 and reduces
   them itself to the batch's unique (document, bucket) pairs with their
@@ -423,8 +425,9 @@ class _Staged:
     cols: dict                # name -> device array [S, E]
     remap: jax.Array          # int32 [S, _PROTO_ROWS]
     n_valid: np.ndarray       # int32 [S] events per batch
-    probe: tuple | None = None        # stream_docs_probe's answer ...
-    probe_docs: int = -1              # ... against a table of this size
+    # stream_docs_probe's answer against the table as it stands; None
+    # where it was not asked, or the table was cut since.
+    probe: tuple | None = None
 
     def holds(self, group: list) -> bool:
         """Whether this is `group`, frame for frame."""
@@ -558,6 +561,10 @@ class StreamingScorer:
         # store) as they stood before the last batch a superstep ran.
         self._res: _Resident | None = None
         self._staged: _Staged | None = None
+        # (table rows, S, E) at which stream_docs_probe has run: a group
+        # whose turn comes unprobed is probed on the device at one of
+        # these, on the host (no compile in the stream's way) otherwise.
+        self.probe_shapes: set[tuple[int, int, int]] = set()
         self._edges_dev = None
         self.before_last_batch = None
         self.last_estep_stats = None    # int [S, 5]: svi_store_step's stats
@@ -711,18 +718,28 @@ class StreamingScorer:
         seen[:cap] = self._last_seen
         self._last_seen = seen
 
+    def _over_bound(self) -> bool:
+        """Whether the table holds more than `max_docs` documents: the
+        next `_maybe_evict` cuts it."""
+        return self.max_docs is not None and self.docs.n_docs > self.max_docs
+
     def _maybe_evict(self) -> int:
         """Keep the doc population under `max_docs`: when crossed, drop
         the least-recently-seen quarter and compact ids/gamma/last_seen
         so the stream's per-doc state (and its checkpoints) stay
-        bounded no matter how many distinct IPs it ever sees."""
-        if self.max_docs is None or self.docs.n_docs <= self.max_docs:
+        bounded no matter how many distinct IPs it ever sees. A group
+        staged ahead stays on the device (its columns hold addresses,
+        not rows); its probe answered the table before the cut."""
+        if not self._over_bound():
             return 0
         n = self.docs.n_docs
         target = max(1, int(self.max_docs * 0.75))
         with telemetry.TRACER.span("stream.evict", before=n, after=target,
                                    cap=_next_pow2(target)):
+            staged = self._staged
             self._drop_resident()   # rows move: the host arrays decide
+            if staged is not None:
+                staged.probe, self._staged = None, staged
             # Survivors = the `target` most recently seen; among the
             # documents last seen in the same batch, the lower ids (the
             # earlier first sightings) are kept.
@@ -1484,7 +1501,7 @@ class StreamingScorer:
         `_gamma`, `_last_seen` or the table's order calls this first."""
         if self._res is not None:
             self._pull_resident()
-            self._res = self._staged = None
+        self._res = self._staged = None
 
     def snapshot_resident(self) -> dict:
         """Copies of what a resident superstep changes (the program
@@ -1519,12 +1536,13 @@ class StreamingScorer:
         self.state = snap["state"]
         self._batch_no = snap["batch_no"]
 
-    def _stage(self, group: list, ahead: bool = False) -> _Staged:
+    def _stage(self, group: list, probe: bool = False) -> _Staged:
         """Cast, pad to a power of two of events and start the copies
-        of one group's raw columns, each [S, E]; a group staged ahead
-        of its turn also asks the device which of its tokens carry an
-        address the table lacks (`stream_docs_probe`, behind the
-        running superstep)."""
+        of one group's raw columns, each [S, E]; with `probe` (a group
+        staged ahead of its turn, against a table no eviction is about
+        to cut) also ask the device which of its tokens carry an
+        address the table lacks (`_probe_on_device`, behind the running
+        superstep)."""
         from onix.pipelines.words import _PROTO_UNK, proto_remap_codes
 
         t0 = time.perf_counter()
@@ -1550,21 +1568,45 @@ class StreamingScorer:
                                           _PROTO_UNK)
                 remap[i, :len(codes)] = codes
             staged = _Staged(group, dev, jax.device_put(remap), n_valid)
-            r = self._res
-            if ahead and r is not None:
-                staged.probe = stream_docs_probe(
-                    r.doc_keys, r.doc_ids, dev["sip_u32"], dev["dip_u32"],
-                    n_valid)
-                staged.probe_docs = r.n_docs
+            if probe and self._res is not None:
+                self._probe_on_device(staged)
         self.stage_walls["stage"] += time.perf_counter() - t0
         return staged
 
+    def _probe_shape(self, staged: _Staged) -> tuple[int, int, int]:
+        return (self._res.doc_keys.shape[0], *staged.cols["sip_u32"].shape)
+
+    def _probe_on_device(self, staged: _Staged) -> None:
+        """Dispatch `stream_docs_probe` of the group against the device
+        copy of the table, and note its shape."""
+        r = self._res
+        staged.probe = stream_docs_probe(
+            r.doc_keys, r.doc_ids, staged.cols["sip_u32"],
+            staged.cols["dip_u32"], staged.n_valid)
+        self.probe_shapes.add(self._probe_shape(staged))
+
+    def _probe_again(self, staged: _Staged) -> None:
+        """The probe's answer for a group whose turn has come unprobed
+        (the group an eviction kept, one staged while the table was past
+        its bound, one staged in its turn): on the device where the
+        probe has run at this shape, waited for under a span of its own;
+        on the host otherwise."""
+        if self._probe_shape(staged) not in self.probe_shapes:
+            self._probe_on_host(staged)
+            return
+        with telemetry.TRACER.span("stream.probe_device",
+                                   batches=len(staged.group)):
+            self._probe_on_device(staged)
+            jax.block_until_ready(staged.probe[0])
+        counters.inc("stream.device_probes")
+
     def _probe_on_host(self, staged: _Staged) -> None:
         """The probe's answer for a group whose turn has come unprobed
-        (the stream's first resident group, one after a host-path
-        batch, a table that changed since): the device is idle then,
-        and the look-up against the host's own table needs no program
-        (the first one's compile would stand in the stream's way)."""
+        at a shape the probe has never run at: the stream's first
+        resident group, or one after host-path batches or an eviction
+        at a table capacity or group shape never probed ahead. The
+        look-up against the host's own table needs no program (the
+        first one's compile would stand in the stream's way)."""
         with telemetry.TRACER.span("stream.probe_host",
                                    batches=len(staged.group)):
             keys = np.sort(self.docs.keys)
@@ -1581,7 +1623,6 @@ class StreamingScorer:
                     else:
                         masks[i, at:at + n] = True
             staged.probe = (masks.sum(axis=1), masks)
-            staged.probe_docs = self._res.n_docs
         counters.inc("stream.host_probes")
 
     def _grow_docs(self, staged: _Staged) -> list[int]:
@@ -1630,8 +1671,8 @@ class StreamingScorer:
             staged = self._stage(run)
         if self._res is None:
             self._push_resident()
-        if staged.probe is None or staged.probe_docs != self._res.n_docs:
-            self._probe_on_host(staged)
+        if staged.probe is None:
+            self._probe_again(staged)
         docs_before = self.docs.n_docs
         docs_after = self._grow_docs(staged)
         r = self._res
@@ -1657,7 +1698,10 @@ class StreamingScorer:
                       if c is None and len(t) else c)
                      for t, c in stage_next]
             if all(self._resident_eligible(t, c) for t, c in ahead):
-                self._staged = self._stage(ahead, ahead=True)
+                # A table past its bound is cut at this superstep's end:
+                # a probe against it now would answer the table before.
+                self._staged = self._stage(ahead,
+                                           probe=not self._over_bound())
         # THE one fetch per superstep: the winners, and the counters
         # the program returns beside them.
         with telemetry.TRACER.span("stream.fetch", batches=s):

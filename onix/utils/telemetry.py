@@ -148,7 +148,8 @@ SPAN_REGISTRY: dict[str, str] = {
     "stream.evict": "StreamingScorer._maybe_evict: one eviction that fires, the table past max_docs cut to its 0.75 * max_docs most recently seen documents: the resident state pulled, the survivors picked and compacted on the host (attributes before, after, cap)",
     "stream.fetch": "StreamingScorer._resident_superstep: one superstep's winners and counters to the host, blocked on the program (child of stream.superstep)",
     "stream.h2d_put": "StreamingScorer._stage: jax.device_put of one staged [S, E] column (the host's side of the copy; child of stream.stage)",
-    "stream.probe_host": "StreamingScorer._probe_on_host: the misses of a group whose turn came unprobed (the first resident group, one after an eviction or a host-path batch), looked up against the host's sorted table (child of stream.superstep)",
+    "stream.probe_device": "StreamingScorer._probe_again: stream_docs_probe of a group whose turn came unprobed (the group staged ahead that an eviction kept, or one staged while the table was past its bound or in its turn) against the table the device holds, dispatched and waited for where the probe has run at that shape (table rows, S, E); counter stream.device_probes, one a group (child of stream.superstep)",
+    "stream.probe_host": "StreamingScorer._probe_on_host: the misses of a group whose turn came unprobed at a shape the probe has never run at (the first resident group, or one after host-path batches or an eviction at a table capacity or group shape never probed ahead), looked up against the host's sorted table; counter stream.host_probes (child of stream.superstep)",
     "stream.regrow": "StreamingScorer._grow_docs: the rows of the resident store run out, the store and stamps pulled to the host and pushed again at the next power of two (attributes cap_from, cap_to; child of stream.doc_growth)",
     "stream.stage": "StreamingScorer._stage: one group's casts into the padded [S, E] buffers, the start of their copies, the protocol remaps and the probe's dispatch (child of the stream.superstep it is staged under)",
     "stream.superstep": "StreamingScorer.process_many: one group of S minibatches, dispatch of stream_svi_step to winners on the host (host-path batches of the group included)",

@@ -6,6 +6,7 @@ of a different summation order (the resident path dedupes the tokens
 on the device, pairs word by word; the host path with `np.unique`)."""
 
 import dataclasses as dc
+import time
 
 import numpy as np
 import pytest
@@ -53,6 +54,14 @@ def _assert_same_stream(res_a, res_b):
                                    rtol=1e-4, atol=1e-6)
         assert (a.n_events, a.step) == (b.n_events, b.step)
     assert any_alerts, "feed produced no alerts: parity was vacuous"
+
+
+def _spans_since(t: float) -> list:
+    """Names of the spans opened since `t` (`time.monotonic()`). The
+    store is left whole: clearing it would read as dropped spans to the
+    benchmark's readers later in the process."""
+    from onix.utils import telemetry
+    return [s.name for s in telemetry.TRACER.spans() if s.t0 >= t]
 
 
 def _assert_same_state(host: StreamingScorer, res: StreamingScorer):
@@ -496,14 +505,12 @@ def test_resident_stages_the_next_group_ahead():
     """`stage_next` (what run_stream passes) is staged and probed under
     the running superstep and used by the next call; the answers are
     those of a call without it."""
-    from onix.utils import telemetry
-
     chunks = _chunks(7)
     a = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)
     res_a = a.process_many([(c, None) for c in chunks])
     b = StreamingScorer(_cfg(3), "flow", n_buckets=N_BUCKETS)
     groups = [[(c, None) for c in chunks[i:i + 3]] for i in (0, 3, 6)]
-    telemetry.RECORDER.clear()
+    t0 = time.monotonic()
     res_b = b.process_many(groups[0], stage_next=groups[1])
     staged = b._staged
     assert staged is not None and staged.probe is not None
@@ -512,7 +519,7 @@ def test_resident_stages_the_next_group_ahead():
     res_b += b.process_many(groups[2])
     assert b._staged is None
     _assert_same_stream(res_a, res_b)
-    names = [s.name for s in telemetry.TRACER.spans()]
+    names = _spans_since(t0)
     assert names.count("stream.stage") == 3
     assert names.count("stream.fetch") == 3
     assert names.count("stream.h2d_put") == 3 * 8
@@ -548,3 +555,153 @@ def test_run_stream_superstep_writes_the_same_alerts(tmp_path):
     assert outs[0]["event_idx"].tolist() == outs[1]["event_idx"].tolist()
     np.testing.assert_allclose(outs[1]["score"], outs[0]["score"],
                                rtol=1e-4, atol=1e-6)
+
+
+def _churn_groups(n_groups: int = 6, fresh: int = 12, seed: int = 21):
+    """Groups of two `_churn_chunks`: with `max_docs` 150 a superstep that
+    stays under the bound, then one that crosses it and evicts."""
+    chunks = _churn_chunks(2 * n_groups, fresh, seed=seed)
+    return [[(c, None) for c in chunks[i:i + 2]]
+            for i in range(0, len(chunks), 2)]
+
+
+def _run_staged(sc: StreamingScorer, groups: list) -> list:
+    """`groups` one call each, the next named as the one to stage."""
+    out = []
+    for g, ahead in zip(groups, groups[1:] + [None]):
+        out += sc.process_many(g, stage_next=ahead)
+    return out
+
+
+def _until_evicted():
+    """A scorer run with `stage_next` up to the first superstep that
+    evicts, and the group it staged ahead."""
+    sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS, max_docs=150)
+    fired = _evictions(sc)
+    groups = _churn_groups()
+    for g, ahead in zip(groups, groups[1:]):
+        sc.process_many(g, stage_next=ahead)
+        if fired:
+            return sc, ahead
+    raise AssertionError("no eviction fired")
+
+
+class _Forgets(set):
+    """A set of probed shapes that never holds one: every group whose
+    turn comes unprobed is probed on the host."""
+
+    def add(self, item):
+        pass
+
+
+def test_device_probe_after_eviction_equals_the_host_probe():
+    """The group staged ahead of a superstep that evicts stays staged
+    with its probe cleared; probed on the device against the compacted
+    table pushed again, it reads the host probe's counts and masks bit
+    for bit."""
+    sc, ahead = _until_evicted()
+    staged = sc._staged
+    assert staged is not None and staged.holds(ahead)
+    assert staged.probe is None and sc._res is None
+    sc._push_resident()                 # the compacted table on the device
+    assert sc._probe_shape(staged) in sc.probe_shapes   # probed ahead
+    on_dev, on_host = dc.replace(staged), dc.replace(staged)
+    sc._probe_again(on_dev)
+    sc._probe_on_host(on_host)
+    counts_d, masks_d = (np.asarray(x) for x in on_dev.probe)
+    counts_h, masks_h = on_host.probe
+    np.testing.assert_array_equal(counts_d, counts_h)
+    np.testing.assert_array_equal(masks_d, masks_h)
+    assert counts_h.sum() > 0
+
+
+def test_device_probe_gives_the_stream_the_host_probe_gives(monkeypatch):
+    """A stream across evictions that probes each kept group on the
+    device gives the winners, scores, table, stamps and store rows of
+    one made to probe every unprobed group on the host."""
+    from onix.utils.obs import counters
+
+    groups = _churn_groups()
+    runs = []
+    for forced in (False, True):
+        sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS,
+                             max_docs=150)
+        if forced:
+            monkeypatch.setattr(sc, "probe_shapes", _Forgets())
+        before = counters.snapshot()
+        res = _run_staged(sc, groups)
+        after = counters.snapshot()
+        probes = {k: after.get(k, 0) - before.get(k, 0)
+                  for k in ("stream.device_probes", "stream.host_probes")}
+        sc._pull_resident()
+        runs.append((sc, res, probes))
+    (dev, res_d, probes_d), (host, res_h, probes_h) = runs
+    assert probes_d["stream.device_probes"] >= 2
+    assert probes_h["stream.device_probes"] == 0
+    assert probes_h["stream.host_probes"] == (probes_d["stream.host_probes"]
+                                              + probes_d["stream.device_probes"])
+    _assert_same_stream(res_h, res_d)
+    for a, b in zip(res_h, res_d):
+        assert _winners(a) == _winners(b)
+        np.testing.assert_array_equal(a.scores, b.scores)
+    n = host.docs.n_docs
+    np.testing.assert_array_equal(dev.docs.keys, host.docs.keys)
+    np.testing.assert_array_equal(dev._gamma[:n], host._gamma[:n])
+    np.testing.assert_array_equal(dev._last_seen[:n], host._last_seen[:n])
+
+
+def test_eviction_keeps_the_staged_group_and_probes_it_on_the_device(
+        monkeypatch):
+    """Across evictions with `stage_next`: one `stream.stage` a group
+    (the group an eviction kept is not staged again), the host probes
+    the first resident group alone, the device probes one kept group an
+    eviction, and a group staged ahead while the table is past its bound
+    carries no probe and dispatches no `stream_docs_probe`."""
+    from onix.pipelines import streaming
+    from onix.utils.obs import counters
+
+    groups = _churn_groups()
+    sc = StreamingScorer(_cfg(2), "flow", n_buckets=N_BUCKETS, max_docs=150)
+    dispatched = []
+    probe = streaming.stream_docs_probe
+
+    def spy(*args):
+        dispatched.append(args[0].shape[0])
+        return probe(*args)
+    monkeypatch.setattr(streaming, "stream_docs_probe", spy)
+    stages, stage = [], sc._stage
+
+    def staging(group, probe=False):
+        over, n = sc._over_bound(), len(dispatched)
+        staged = stage(group, probe)
+        stages.append((over, staged.probe is None, len(dispatched) - n))
+        return staged
+    monkeypatch.setattr(sc, "_stage", staging)
+    t0, before = time.monotonic(), counters.snapshot()
+    _run_staged(sc, groups)
+    after = counters.snapshot()
+    got = {k: after.get(k, 0) - before.get(k, 0)
+           for k in ("stream.evictions", "stream.device_probes",
+                     "stream.host_probes")}
+    names = _spans_since(t0)
+    assert got["stream.evictions"] >= 2
+    assert names.count("stream.stage") == len(stages) == len(groups)
+    assert got["stream.host_probes"] == names.count("stream.probe_host") == 1
+    assert (got["stream.device_probes"] == names.count("stream.probe_device")
+            == got["stream.evictions"])
+    over = [s for s in stages if s[0]]
+    assert len(over) == got["stream.evictions"]
+    assert all(none and n == 0 for _, none, n in over)
+    ahead = [s for s in stages[1:] if not s[0]]
+    assert all(not none and n == 1 for _, none, n in ahead)
+    assert len(dispatched) == len(ahead) + got["stream.device_probes"]
+
+
+def test_a_host_path_batch_drops_the_group_an_eviction_kept():
+    """The kept group's columns leave the device once a batch takes the
+    host path: the next group may take it too, and its staging would
+    then sit on the chip unused."""
+    sc, ahead = _until_evicted()
+    assert sc._res is None and sc._staged is not None
+    sc.process(ahead[0][0])
+    assert sc._staged is None
